@@ -132,7 +132,7 @@ def _lloyd_fit_fn(cloud, shard_mode: str, n_shards: int, k: int):
             rep = P()
             inner = cloudlib.shard_call(
                 inner, cloud, in_specs=(rspec, rspec) + (rep,) * 7,
-                out_specs=(rep, rep, rep, rep), check_rep=False)
+                out_specs=(rep, rep, rep, rep), check_vma=False)
         return jax.jit(inner)
 
     return _est.cached_program(cloud, key, build)

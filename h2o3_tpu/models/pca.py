@@ -55,7 +55,7 @@ def _gram_fn(cloud, shard_mode: str, n_shards: int):
             rspec = P(cloudlib.ROWS_AXIS)
             inner = cloudlib.shard_call(
                 inner, cloud, in_specs=(rspec, rspec, P()),
-                out_specs=P(), check_rep=False)
+                out_specs=P(), check_vma=False)
         return jax.jit(inner)
 
     return _est.cached_program(cloud, key, build)

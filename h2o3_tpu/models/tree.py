@@ -147,6 +147,26 @@ def _leaf_totals(ids, vals3, nseg: int, axis_name, n_shard_blocks: int,
     return tot
 
 
+def _node_totals(hist):
+    """(L,) node totals {Σw, Σg, Σh}: feature 0's bins (every feature sums
+    to the same totals) folded by an EXPLICIT pairwise halving tree. A
+    plain `sum(axis=bins)` leaves the association to the compiler, and the
+    installed XLA picks a different one for the shard_map program than for
+    the one-device blocks program (observed: 1 ulp apart on the root of
+    bit-identical histograms) — the same reason `ordered_axis_fold` pins
+    the cross-block merge by its expression tree. Zero-padding to a power
+    of two is exact."""
+    x = hist[:, 0]                                  # (L, B, 3)
+    nb = x.shape[1]
+    p2 = 1 << max(nb - 1, 0).bit_length()
+    if p2 != nb:
+        x = jnp.pad(x, ((0, 0), (0, p2 - nb), (0, 0)))
+    while x.shape[1] > 1:
+        half = x.shape[1] // 2
+        x = x[:, :half] + x[:, half:]
+    return x[:, 0, 0], x[:, 0, 1], x[:, 0, 2]
+
+
 def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
                       reg_lambda, reg_alpha, gsum, hsum, wsum,
                       monotone=None, lo_lvl=None, hi_lvl=None):
@@ -375,9 +395,7 @@ def build_tree(
             )
         hist_prev = hist
 
-        wsum = hist[..., 0].sum(axis=2)[:, 0]   # (L,) totals (same for all F)
-        gsum = hist[..., 1].sum(axis=2)[:, 0]
-        hsum = hist[..., 2].sum(axis=2)[:, 0]
+        wsum, gsum, hsum = _node_totals(hist)
         # Newton leaf value with elastic-net regularization (xgboost's
         # CalcWeight: soft-threshold G by alpha, shrink by lambda)
         gthr = jnp.sign(gsum) * jnp.maximum(jnp.abs(gsum) - reg_alpha, 0.0)
@@ -574,9 +592,7 @@ def build_tree(
     for d in range(d_switch, max_depth):
         base = 2 ** d - 1
         valid = (slot_node >= 0) & (slot_iota < CAP)
-        wsum = slot_hist[..., 0].sum(axis=2)[:, 0]
-        gsum = slot_hist[..., 1].sum(axis=2)[:, 0]
-        hsum = slot_hist[..., 2].sum(axis=2)[:, 0]
+        wsum, gsum, hsum = _node_totals(slot_hist)
         gthr = jnp.sign(gsum) * jnp.maximum(jnp.abs(gsum) - reg_alpha, 0.0)
         node_val = (-gthr / (hsum + reg_lambda + 1e-12)).astype(jnp.float32)
         if max_abs_leaf is not None:
@@ -715,9 +731,7 @@ def _search_splits(hist, feat_mask, nbins, min_rows, reg_lambda, reg_alpha):
     the split search of `build_tree` without the level-wise bookkeeping
     (`hex/tree/DTree.Split.findBestSplitPoint`; xgboost EvaluateSplits)."""
     L, F = hist.shape[0], hist.shape[1]
-    wsum = hist[..., 0].sum(axis=2)[:, 0]
-    gsum = hist[..., 1].sum(axis=2)[:, 0]
-    hsum = hist[..., 2].sum(axis=2)[:, 0]
+    wsum, gsum, hsum = _node_totals(hist)
     GL = jnp.cumsum(hist[..., 1], axis=2)
     HL = jnp.cumsum(hist[..., 2], axis=2)
     WL = jnp.cumsum(hist[..., 0], axis=2)

@@ -191,9 +191,7 @@ def _level_decide_jit(hist, active, feat_mask, keep, edges, hp, gain_pf,
     """Merged-histogram level decision: node values, fused split search,
     varimp fold and raw thresholds — build_tree's dense-level body."""
     F = edges.shape[0]
-    wsum = hist[..., 0].sum(axis=2)[:, 0]
-    gsum = hist[..., 1].sum(axis=2)[:, 0]
-    hsum = hist[..., 2].sum(axis=2)[:, 0]
+    wsum, gsum, hsum = treelib._node_totals(hist)
     gthr = jnp.sign(gsum) * jnp.maximum(jnp.abs(gsum) - hp[3], 0.0)
     node_val = (-gthr / (hsum + hp[2] + 1e-12)).astype(jnp.float32)
     node_val = jnp.clip(node_val, -hp[7], hp[7])

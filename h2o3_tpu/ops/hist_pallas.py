@@ -20,7 +20,7 @@ Packed-code input (ISSUE 7): the device-RESIDENT matrix is the 4/5/6-bit
 `ops.packing` word matrix; `build_histograms` widens it IN-GRAPH before
 these kernels, once per compiled tree program (XLA CSEs the widen across
 every level's pass — only a program-lifetime transient is full-width, the
-resident/cached/tunnelled artifact stays packed). In-KERNEL sub-byte
+resident/cached/uploaded artifact stays packed). In-KERNEL sub-byte
 decode was evaluated and deferred: the factored kernel reads codes as
 8-sublane f32 feature blocks, while Mosaic's int8 minimum tile is
 (32, 128) — a u8 packed operand would force a 32-feature block
@@ -38,16 +38,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is only importable on TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_ROW_CHUNK = 2048
 FACTORED_ROW_CHUNK = 8192
+# The scoped-VMEM budget both kernels are compiled under, STATED in the
+# pallas_call instead of left to the compiler's per-generation default (it
+# equals the v5e default). `histogram._factored_row_chunk` sizes the row
+# chunk against this number; tests/test_chip_compile.py holds the pair to
+# what the v5e compiler accepts.
+VMEM_LIMIT_BYTES = 16 << 20
 
 
 _FB = 8  # features per block (TPU sublane granule)
@@ -110,8 +110,6 @@ def build_histograms_pallas_factored(
 ) -> jax.Array:
     """(n_nodes, F, nbins, 3) histogram; the TPU fast path for L·R fitting
     VMEM (the scratch is (3L, R) f32)."""
-    if not _HAVE_PLTPU:
-        raise RuntimeError("pallas TPU backend unavailable")
     F, N = codes_t_bf.shape
     L, B = n_nodes, nbins
     R = row_chunk
@@ -137,6 +135,8 @@ def build_histograms_pallas_factored(
         ],
         out_specs=pl.BlockSpec((1, 3 * L, _FB * B), lambda i, f: (f, 0, 0)),
         scratch_shapes=[pltpu.VMEM((3 * L, R), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(codes_t_bf, node2, vals)
     # (Fpad/8, 3L, 8B) → (Fpad, 3L, B) → (L, F, B, 3)
     out = out.reshape(Fpad // _FB, 3 * L, _FB, B).transpose(0, 2, 1, 3)
@@ -180,8 +180,6 @@ def build_histograms_pallas(
     row_chunk: int = DEFAULT_ROW_CHUNK,
 ) -> jax.Array:
     """(n_nodes, F, nbins, 3) histogram via the fused pallas kernel."""
-    if not _HAVE_PLTPU:
-        raise RuntimeError("pallas TPU backend unavailable")
     N, F = codes.shape
     LB = n_nodes * nbins
     R = row_chunk
@@ -206,6 +204,8 @@ def build_histograms_pallas(
             pl.BlockSpec((3, R), lambda i: (0, i)),      # vals chunk
         ],
         out_specs=pl.BlockSpec((F, 3, LB), lambda i: (0, 0, 0)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(codes_t, cid_base, vals)
     # (F, 3, LB) → (n_nodes, F, nbins, 3)
     return out.reshape(F, 3, n_nodes, nbins).transpose(2, 0, 3, 1)

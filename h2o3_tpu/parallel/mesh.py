@@ -24,14 +24,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # 0.4.x keeps it experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the ONE version-compat import — graft/test callers re-use this instead
-# of duplicating the try/except (a future jax rename is a one-line fix)
-shard_map = _shard_map
+from jax import shard_map
 
 ROWS_AXIS = "hosts"  # the one inter-node axis H2O has: row/data parallelism
 
@@ -143,8 +136,8 @@ def reset() -> None:
         _cloud = None
 
 
-def shard_call(fn, cloud: "Cloud", in_specs, out_specs, check_rep=True):
-    """t5x-style cpu-fallback-to-jit wrapper (SNIPPETS.md [1], `t5x
+def shard_call(fn, cloud: "Cloud", in_specs, out_specs, check_vma=True):
+    """t5x-style fall-through-to-jit wrapper (SNIPPETS.md [1], `t5x
     partitioning.pjit`): on a multi-device cloud, wrap `fn` in `shard_map`
     over the 1-D ``hosts`` mesh; on a 1-device cloud return `fn` UNCHANGED
     so the caller's plain `jit` runs the IDENTICAL function body — the
@@ -152,13 +145,13 @@ def shard_call(fn, cloud: "Cloud", in_specs, out_specs, check_rep=True):
     histogram reduction included) without a mesh, and a parity pin between
     the two lanes compares one implementation against itself.
 
-    `check_rep=False` is required for bodies whose replicated outputs come
+    `check_vma=False` is required for bodies whose replicated outputs come
     from an `all_gather` + explicit fold (the deterministic histogram
     merge) rather than a `psum` — shard_map cannot statically infer the
     replication there, but the fold IS replicated by construction."""
     if cloud.size > 1:
-        return _shard_map(fn, mesh=cloud.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
+        return shard_map(fn, mesh=cloud.mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=check_vma)
     return fn
 
 

@@ -1609,7 +1609,7 @@ class _Handler(BaseHTTPRequestHandler):
                                    for i in self._grid_model_ids(gs)]))
 
 
-    # -- round-4 route tier (VERDICT r03 #9) --------------------------------
+    # -- round-4 route tier --------------------------------
     def h_validate_params(self, algo):
         """`POST /3/ModelBuilders/{algo}/parameters` — validate WITHOUT
         training (ModelBuilderHandler validate_parameters)."""

@@ -200,9 +200,10 @@ def evict_threshold() -> float:
 
 
 def device_capacity_bytes() -> int:
-    """Device byte capacity as the ledger sees it: ``memory_stats()``
-    limit where the backend reports one, else the census fallback's cap
-    (``H2O3_DEVICE_BUDGET_MB`` / host budget). The out-of-core streaming
+    """Device byte capacity as the ledger sees it: the ``memory_stats()``
+    limit on an accelerator (one that reports none is an error, see
+    `_probe_device`), the census cap (``H2O3_DEVICE_BUDGET_MB`` / host
+    budget) on the CPU backend. The out-of-core streaming
     layer derives its resident budget from this — one authoritative
     number instead of a guessed HBM cap (ISSUE 14)."""
     cap = int(_probe_device().get("capacity_bytes", 0))
@@ -221,26 +222,25 @@ def _probe_device() -> Dict:
                     devices=[])
     devices = []
     in_use = limit = 0
-    try:
-        for d in jx.devices():
-            stats = None
-            try:
-                stats = d.memory_stats()
-            except Exception:
-                stats = None
-            if stats and "bytes_in_use" in stats:
-                devices.append(dict(id=str(d.id), platform=d.platform,
-                                    bytes_in_use=int(stats["bytes_in_use"]),
-                                    bytes_limit=int(stats.get("bytes_limit",
-                                                              0))))
-                in_use += int(stats["bytes_in_use"])
-                limit += int(stats.get("bytes_limit", 0))
-    except Exception:
-        pass
+    for d in jx.local_devices():     # only addressable devices answer
+        stats = d.memory_stats()
+        if stats and "bytes_in_use" in stats:
+            devices.append(dict(id=str(d.id), platform=d.platform,
+                                bytes_in_use=int(stats["bytes_in_use"]),
+                                bytes_limit=int(stats.get("bytes_limit",
+                                                          0))))
+            in_use += int(stats["bytes_in_use"])
+            limit += int(stats.get("bytes_limit", 0))
+        elif d.platform != "cpu":
+            # an accelerator that cannot say what it holds is a fault, not
+            # a reason to budget its HBM by the host's RAM
+            raise RuntimeError(
+                f"{d.platform} device {d.id} reports no memory_stats(): "
+                "the ledger cannot budget device memory")
     if devices:
         return dict(probe="memory_stats", in_use_bytes=in_use,
                     capacity_bytes=limit, devices=devices)
-    # census fallback (forced-CPU lanes, backends without memory_stats)
+    # census (the CPU backend reports no memory_stats)
     census = n = 0
     try:
         for a in jx.live_arrays():

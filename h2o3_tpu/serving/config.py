@@ -4,7 +4,7 @@ All knobs are env-overridable (`H2O3_SERVING_*`) so a deployment can tune
 the batcher/admission behavior without code changes, the same way the REST
 layer reads `H2O3_MAX_BODY_MB`. Defaults are chosen for a loopback CPU
 deployment; a real TPU serving pod wants a larger `max_batch_rows` (amortize
-the tunnel round-trip) and a tighter `max_wait_ms` (the device is fast, the
+the dispatch round-trip) and a tighter `max_wait_ms` (the device is fast, the
 queue should not be the latency floor).
 """
 

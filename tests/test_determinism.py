@@ -1,9 +1,8 @@
 """Seed determinism + padded-shape invariance of trained models.
 
-VERDICT r02 weak #2: the flagship fixed-seed AUC moved 0.85226 → 0.85022
-between rounds. The r03 bisect (BASELINE.md round-3 notes) pinned it to the
-r02 histogram-method default change (onehot → pallas_factored): different
-f32 accumulation order at 1M rows flips near-tie splits. These tests lock
+The flagship fixed-seed AUC once moved 0.85226 → 0.85022 between rounds;
+a bisect pinned it to a histogram-method default change (onehot →
+pallas_factored): different f32 accumulation order at 1M rows flips near-tie splits. These tests lock
 the invariants that SHOULD hold: same seed ⇒ identical model (across runs,
 and across padded row-count changes such as `_bucket_rows` bucketing), per
 histogram method.
@@ -66,8 +65,8 @@ def test_padded_shape_invariance(cloud1):
     exactly 0.0 to every histogram sum, but a different array SHAPE changes
     XLA's f32 reduction order (machine-dependent SIMD regrouping), and a
     dust-level histogram delta can flip ONE near-tie split whose rerouting
-    then cascades through later boosting rounds — the r03 bisect mechanism
-    (BASELINE.md round-3: a method change moved flagship AUC 0.002).
+    then cascades through later boosting rounds — the bisect mechanism above
+    (a method change moved flagship AUC 0.002).
     Measured on this 1-core box: dAUC ≈ 6e-3 with most per-row
     probabilities moving, from exactly such a flip. The invariant that
     HOLDS everywhere is model QUALITY: AUC agrees to ~1e-2 and both

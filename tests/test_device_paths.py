@@ -150,7 +150,7 @@ def test_glm_device_lambda_path_matches_host():
 def test_device_design_sharded_mesh_matches_dense(cloud8):
     """Single-process multi-device mesh: device_design(cloud=) produces the
     row-sharded byte-compressed design, equal to the dense f32 path, with
-    zero-padded quota rows at the tail (VERDICT r04 #4)."""
+    zero-padded quota rows at the tail."""
     import numpy as np
 
     import h2o3_tpu as h2o

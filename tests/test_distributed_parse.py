@@ -1,6 +1,6 @@
 """Distributed ingest: N processes × byte ranges must reproduce the
 single-process parse bit-identically (ParseDataset.MultiFileParseTask +
-Categorical merge semantics — VERDICT r01 item 4)."""
+Categorical merge semantics)."""
 
 import csv
 import os

@@ -296,3 +296,30 @@ def test_sample_rate_per_class(cloud1):
     with pytest.raises(ValueError):
         H2ORandomForestEstimator(ntrees=2, sample_rate_per_class=[0.5]).train(
             y="y", training_frame=fr)
+
+
+@pytest.mark.parametrize("scorer", ["fused", "walk"])
+def test_predict_pages_equal_one_dispatch(cloud1, monkeypatch, scorer):
+    """A frame past one scorer page is scored page by page through ONE
+    compiled program (the fused walk's gather transients at 1M rows × 100
+    trees do not fit a chip): the paged result equals the one-dispatch
+    result bit for bit, multinomial forests included."""
+    from h2o3_tpu.models import shared_tree
+
+    monkeypatch.setenv("H2O3_FOREST_SCORER", scorer)
+    rng = np.random.default_rng(5)
+    n, f = 2300, 6
+    X = rng.normal(size=(n, f))
+    y = (X[:, 0] > 0.5).astype(int) + (X[:, 1] > 0).astype(int)
+    fr = Frame.from_numpy(np.column_stack([X, y]),
+                          names=[f"x{i}" for i in range(f)] + ["y"]
+                          ).asfactor("y")
+    gbm = H2OGradientBoostingEstimator(ntrees=5, max_depth=3, seed=1)
+    gbm.train(y="y", training_frame=fr)
+    whole = gbm.predict(fr)
+    monkeypatch.setattr(shared_tree, "_SCORE_PAGE_BYTES", 1)  # 512-row pages
+    assert shared_tree._score_page_rows(8) == 512
+    paged = gbm.predict(fr)
+    for c in whole.names:
+        assert np.array_equal(whole.vec(c).numeric_np(),
+                              paged.vec(c).numeric_np()), c

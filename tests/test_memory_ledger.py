@@ -12,6 +12,7 @@ surfaces can never disagree), and the loadgen sustained-mode leak canary.
 import gc
 import json
 import os
+import time
 import urllib.request
 
 import numpy as np
@@ -196,7 +197,14 @@ def test_frame_death_cleans_cache_owners_without_leak(cloud1):
     base0 = ml.snapshot()["totals"]["leaked_bytes"]
     DKV.remove("ml_clean_fr")
     del fr
-    gc.collect()
+    # the fit's helper threads (program warm-up, overlapped scoring) may
+    # hold the frame a moment past train() on a loaded host: the contract
+    # is that its death cleans the owners, not that one gc pass is enough
+    for _ in range(50):
+        gc.collect()
+        if not ml.owners("dataset_cache:"):
+            break
+        time.sleep(0.1)
     snap = ml.snapshot()
     assert ml.owners("dataset_cache:") == []
     assert snap["totals"]["leaked_bytes"] <= base0

@@ -1,4 +1,4 @@
-"""MOJO export across the model zoo (VERDICT r03 #5): every new artifact
+"""MOJO export across the model zoo: every new artifact
 kind round-trips save → load → predict with row-level parity against the
 in-cluster model. Reference: `hex/genmodel/algos/**` scorers +
 `EasyPredictModelWrapper` (in-cluster ≡ MOJO parity is upstream's
@@ -220,7 +220,7 @@ def test_mojo_unexportable_raises_documented(tmp_path, cloud1):
 
 
 def test_mojo_gam_carries_spline_basis(tmp_path, cloud1):
-    """VERDICT r04 #6: the GAM artifact scores NEW data offline with the
+    """the GAM artifact scores NEW data offline with the
     same spline basis (knots + centering) the cluster fit — not just the
     inner GLM."""
     from h2o3_tpu.models.gam import H2OGeneralizedAdditiveEstimator

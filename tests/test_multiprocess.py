@@ -1,16 +1,11 @@
 """Multi-process training correctness: N local processes under
 jax.distributed (the reference's multi-JVM loopback cloud, SURVEY.md §4)
-must reproduce the single-process model within tolerance — VERDICT r01
-item 5. Ingest is per-process byte ranges (distributed_parse), so these
+must reproduce the single-process model within tolerance. Ingest is per-process byte ranges (distributed_parse), so these
 tests exercise the full distributed path: parse → global domains → global
 row-sharded arrays → collective training math.
 
-SLOW LANE (ISSUE 13 triage): this whole module runs `slow`. The suite
-was the tier-1 baseline's 18-failure block — a jax-version skew in the
-worker prelude (`jax_num_cpu_devices` does not exist on jax < 0.5) made
-every spawn die at import; the prelude now falls back to XLA_FLAGS
-(multiproc_util.WORKER_PRELUDE) and the tests pass again. They stay out
-of tier-1 because each spawns 2-4 fresh interpreters that pay a full
+SLOW LANE (ISSUE 13 triage): this whole module runs `slow`. The tests
+stay out of tier-1 because each spawns 2-4 fresh interpreters that pay a full
 jax + platform import and an end-to-end train (~40-150 s per test on
 the 1-core CI box, ~3.5 min for the module) against a tier-1 budget
 that is already ~826 s of the 870 s timeout. The spawn machinery itself
@@ -20,10 +15,9 @@ test_two_process_bit_identical runs run_workers in ~1.5 s), the
 collective lowering, and the fleet-aggregation tests
 (tests/test_fleet.py) cover real multi-process scraping; full
 cross-process training parity runs here in the slow lane and in the
-MULTICHIP dryrun. Two fixes made the suite green again: gloo CPU
-collectives selected explicitly (jax 0.4.x default "none" cannot run
-multiprocess programs) and check_rep=False on the mesh_psum tree step
-(the 0.4.x replication checker rejects the level loop's psum carry)."""
+MULTICHIP dryrun. The mesh_psum tree step runs with check_vma=False
+(its outputs are replicated by construction; the static check cannot
+follow the level loop's psum carry)."""
 
 import csv
 
@@ -688,7 +682,7 @@ print("rank", jax.process_index(), "ok")
 
 @pytest.mark.parametrize("nproc", [2, 4])
 def test_lambdarank_multiprocess_matches_single(tmp_path, cloud1, nproc):
-    """The custom-objective acid test (VERDICT r03 #4): lambdarank's
+    """The custom-objective acid test: lambdarank's
     per-query pass sees whole queries even when they span ingest shards —
     the global-gather contract. NDCG@10 must match the single-process
     model closely (identical global inputs; f32 drift only)."""
@@ -741,7 +735,7 @@ print("rank", jax.process_index(), "ok")
 
 
 def test_dl_compressed_sharded_ingest_two_process(tmp_path, cloud1):
-    """VERDICT r04 #4: on a multi-process cloud the design matrix arrives
+    """on a multi-process cloud the design matrix arrives
     as byte-compressed packs (uint8/int16 integer columns) expanded on
     device, and equals the dense f32 fit_transform path row-for-row."""
     rng = np.random.default_rng(8)

@@ -286,8 +286,8 @@ def test_xla_signature_is_program_identity_not_span_name(cloud1):
     """Two different shape-bucket programs of one function, traced under
     ONE span, are distinct first traces (no fabricated retrace); the same
     program genuinely re-traced is counted no matter which span is open.
-    Signatures come from jax's own emission-site locals (fun_name +
-    input-avals digest), not from whatever span happens to be open."""
+    Signatures are the compilation-cache keys jax reports for each compile
+    request (phases._CompileTap), not whatever span happens to be open."""
     import jax
     import jax.numpy as jnp
 
@@ -306,7 +306,7 @@ def test_xla_signature_is_program_identity_not_span_name(cloud1):
     assert mid["retraces"] == before["retraces"], \
         "cold shape buckets under one span fabricated a retrace"
     sigs = [s for s in phases.xla_snapshot()["signatures"]
-            if s.startswith("obs_sig_probe")]
+            if s.startswith("jit_obs_sig_probe-")]
     assert len(sigs) >= 2                   # per-avals identity
     # a genuine retrace (cache dropped, same program+shape) IS counted,
     # under a differently-named span

@@ -1,7 +1,7 @@
 """Round-3 Rapids final-tail parity (`water/rapids/ast/prims/**`):
 digamma/trigamma, moment/asDate/timezones, string distance/title/
 substring-count, rank_within_groupby, relevel.by.freq, distance, isax,
-setproperty/setLevel/append — VERDICT r02 missing #6."""
+setproperty/setLevel/append."""
 
 import datetime
 

@@ -1,6 +1,6 @@
 """Round-2 Rapids prim-tail parity (`water/rapids/ast/prims/**` long tail):
 NA-propagating reducers, time construction, string metrics, reshapers, fold
-columns, sequences, 2-column table — VERDICT r01 item 7."""
+columns, sequences, 2-column table."""
 
 import numpy as np
 import pytest

@@ -535,7 +535,7 @@ def test_rapids_rows_param_returns_all_hist_bins(server):
 
 
 def test_round5_functional_routes(server):
-    """VERDICT r04 #3 follow-on: builders list, frame paging, column
+    """builders list, frame paging, column
     routes, Tabulate, JStack, PartialDependence, Metadata/endpoints,
     UnlockKeys."""
     srv, csv = server

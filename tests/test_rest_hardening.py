@@ -1,4 +1,4 @@
-"""Round-4 REST hardening (VERDICT r03 #9): TLS, request-size caps, and
+"""Round-4 REST hardening: TLS, request-size caps, and
 the next route tier (validate-parameters, MOJO download, DownloadDataset,
 SplitFrame, sessions, DKV removal, capabilities). Reference:
 `water/api/RequestServer.java`, `water/network/SocketChannelFactory`."""

@@ -309,9 +309,15 @@ def test_router_routes_and_fails_over_in_process(router_server, cloud1,
     url = f"http://127.0.0.1:{router_server.port}"
     fleet.register_peer("r1", url)
     fleet.register_peer("r2", url)
-    reset_router(RouterConfig(refresh_s=60.0, max_attempts=3,
-                              drain_errors=100))
-    faults.arm("router.forward", error="conn", rate=1.0, match="r1:")
+    router = reset_router(RouterConfig(refresh_s=60.0, max_attempts=3,
+                                       drain_errors=100))
+    # fault whichever member the ring will try FIRST: both names point at
+    # one server, but their scraped pressure/p99 come from two scrapes a
+    # moment apart, so the dispatch order is not always (r1, r2). The
+    # scrape is fresh for refresh_s, so the request sees this same order.
+    router.refresh(force=True)
+    sick = router._candidates()[0].name
+    faults.arm("router.forward", error="conn", rate=1.0, match=f"{sick}:")
     doc = _post(router_server.port,
                 f"/3/Router/models/{mid}/frames/{fkey}")
     assert doc["predictions_frame"]["name"]
@@ -320,11 +326,11 @@ def test_router_routes_and_fails_over_in_process(router_server, cloud1,
     assert snap["totals"]["errors"] == 0
     assert snap["totals"]["failovers"] >= 1
     assert snap["totals"]["retries"] >= 1
-    r1 = [r for r in snap["ring"] if r["name"] == "r1"][0]
+    r1 = [r for r in snap["ring"] if r["name"] == sick][0]
     assert r1["consecutive_errors"] >= 1
     with urllib.request.urlopen(f"{url}/3/Metrics") as r:
         text = r.read().decode()
-    assert 'h2o3_router_failovers_total{replica="r1"}' in text
+    assert f'h2o3_router_failovers_total{{replica="{sick}"}}' in text
     # the faulted replica exhausted on every lane → caller-visible 500
     faults.arm("router.forward", error="conn", rate=1.0)
     with pytest.raises(urllib.error.HTTPError) as ei:

@@ -129,7 +129,7 @@ def test_blocked_histograms_shard_invariant(cloud8, _shard_env):
 
         fn8 = jax.jit(cloudlib.shard_call(
             inner_mesh, cloud8, in_specs=(rspec,) * 5, out_specs=P(),
-            check_rep=False))
+            check_vma=False))
         rs = cloud8.row_sharding()
         h8 = np.asarray(fn8(
             jax.device_put(jnp.asarray(codes_in), rs),
@@ -190,7 +190,7 @@ def test_build_tree_sharded_parity_combined(cloud8, _shard_env):
         builder(cloudlib.ROWS_AXIS, 1), cloud8,
         in_specs=(rspec,) * 4 + (P(),),
         out_specs=(treelib.Tree(P(), P(), P(), P(), P()), rspec, P(), P()),
-        check_rep=False))
+        check_vma=False))
     rs = cloud8.row_sharding()
     out8 = fn8(jax.device_put(jnp.asarray(pk), rs),
                jax.device_put(jnp.asarray(g), rs),

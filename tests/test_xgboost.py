@@ -182,7 +182,7 @@ def test_max_delta_step_clamps_xgb():
         assert np.abs(vals).max() <= 0.05 * 0.3 * (1 + 1e-5)
 
 
-# ---- gblinear booster (updater_shotgun.cc CoordinateDelta; VERDICT r04 #5)
+# ---- gblinear booster (updater_shotgun.cc CoordinateDelta)
 
 
 def test_gblinear_gaussian_matches_glm():
