@@ -38,13 +38,8 @@ class StackedEnsembleModel(H2OModel):
         self.x = base_models[0].model.x if base_models else []
 
     def _level_one(self, frame: Frame) -> Frame:
-        import os
-        import time
-
-        prof = os.environ.get("H2O3_PROFILE")
         cols = {}
         for i, bm in enumerate(self.base_models):
-            t0 = time.time()
             # one base-model prediction per FRAME, shared across ensembles
             # (BestOfFamily ⊆ AllModels would otherwise re-predict every
             # model). Living on the frame object, the cache dies with the
@@ -56,9 +51,6 @@ class StackedEnsembleModel(H2OModel):
             if mid not in preds:
                 preds[mid] = bm._cv_predict(bm.model, frame)
             p = preds[mid]
-            if prof:
-                print(f"[h2o3-profile] SE level-one {bm.algo} "
-                      f"({bm.model_id}): {time.time()-t0:.2f}s", flush=True)
             if self.problem == "multinomial":
                 for k in range(p.shape[1]):
                     cols[f"m{i}_p{k}"] = p[:, k]

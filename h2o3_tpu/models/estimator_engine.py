@@ -143,6 +143,55 @@ def _expansion_key(frame, x, use_all: bool) -> bool:
     return any(frame.vec(c).type == "enum" for c in x)
 
 
+@contextmanager
+def _design_span():
+    """The fit's ``fit.design`` span, as a decorator of the three matrix
+    resolvers: from the frame's columns to the matrix the fit iterates on
+    (the cache look-up with its frame fingerprint; on a miss the DataInfo
+    statistics, packing, H2D and the expand program's dispatch). Attrs:
+    ``cache`` (hit/miss/off, set by `_resolve`) and ``bytes_h2d`` (what
+    `phases.accounted_h2d` counted meanwhile, process-wide).
+    `device_matrix` resolves its host layer inside its own span: the two
+    layers are one span."""
+    from ..runtime import phases as _phases
+    from ..runtime import tracing as _tracing
+
+    outer = _tracing.current()
+    if outer is not None and outer.name == "fit.design":
+        yield
+        return
+    h2d0 = _phases.snapshot().get("bytes_h2d", 0)
+    with _tracing.span("fit.design", kind="fit") as sp:
+        try:
+            yield
+        finally:
+            sp.annotate(
+                bytes_h2d=_phases.snapshot().get("bytes_h2d", 0) - h2d0)
+
+
+def _resolve(frame, x, skey: tuple, build):
+    """One artifact through the dataset cache's std layer, or built afresh
+    when the cache is off; the open ``fit.design`` span is told which."""
+    from ..runtime import tracing as _tracing
+
+    sp = _tracing.current()
+    if not cache_enabled():
+        sp.annotate(cache="off")
+        return build()[0]
+    from . import dataset_cache
+
+    built = []
+
+    def counted():
+        built.append(True)
+        return build()
+
+    out = dataset_cache.std_artifact(frame, x, skey, counted)
+    sp.annotate(cache="miss" if built else "hit")
+    return out
+
+
+@_design_span()
 def host_matrix(frame, x, *, standardize: bool, use_all: bool = False,
                 impute: bool = True):
     """(DataInfo, standardized float32 host matrix) for (frame, x) —
@@ -158,14 +207,11 @@ def host_matrix(frame, x, *, standardize: bool, use_all: bool = False,
         X = dinfo.fit_transform(frame)
         return (dinfo, X), int(X.nbytes), "host"
 
-    if not cache_enabled():
-        return build()[0]
-    from . import dataset_cache
-
-    return dataset_cache.std_artifact(
-        frame, x, ("host", bool(standardize), ua, bool(impute)), build)
+    return _resolve(frame, x, ("host", bool(standardize), ua, bool(impute)),
+                    build)
 
 
+@_design_span()
 def device_matrix(frame, x, *, standardize: bool, use_all: bool = False,
                   impute: bool = True, n_shards: int = 0, n_devices: int = 1):
     """(DataInfo, device design matrix) — the cached host matrix uploaded
@@ -203,15 +249,11 @@ def device_matrix(frame, x, *, standardize: bool, use_all: bool = False,
         Xd = _phases.accounted_h2d(_put, int(Xp.nbytes))
         return (dinfo, Xd), int(Xp.nbytes), "device"
 
-    if not cache_enabled():
-        return build()[0]
-    from . import dataset_cache
-
-    return dataset_cache.std_artifact(
-        frame, x, ("dev", bool(standardize), ua, bool(impute),
-                   int(npad), int(n_devices)), build)
+    return _resolve(frame, x, ("dev", bool(standardize), ua, bool(impute),
+                               int(npad), int(n_devices)), build)
 
 
+@_design_span()
 def design_matrix(frame, x, *, standardize: bool, use_all: bool = False,
                   add_intercept: bool = False, n_shards: int = 0,
                   n_devices: int = 1):
@@ -249,13 +291,9 @@ def design_matrix(frame, x, *, standardize: bool, use_all: bool = False,
         nbytes = int(np.prod(Xd.shape)) * Xd.dtype.itemsize
         return (dinfo, Xd), nbytes, "device"
 
-    if not cache_enabled():
-        return build()[0]
-    from . import dataset_cache
-
-    return dataset_cache.std_artifact(
-        frame, x, ("design", bool(standardize), ua, bool(add_intercept),
-                   int(npad), int(n_devices)), build)
+    return _resolve(frame, x, ("design", bool(standardize), ua,
+                               bool(add_intercept), int(npad),
+                               int(n_devices)), build)
 
 
 # -- per-cloud fused-program cache --------------------------------------------
@@ -493,17 +531,22 @@ def segment_carry_restore(algo: str, fingerprint):
 
 @contextmanager
 def iter_phase():
-    """Book a fused iteration loop's wall into the ``est_iter`` phase
-    bucket (compile/trace time the first call triggers is subtracted —
-    it is already accounted by the monitoring listener)."""
+    """The fit's ``fit.iterate`` span: the dispatch of a fused iteration
+    loop up to the read of its final state. ONE context manager for every
+    engine estimator (GLM, K-Means, PCA, GLRM); it yields the span, so
+    a call site may annotate ``iterations`` / ``segments``. The span's
+    wall is also booked into the ``est_iter`` phase bucket (compile/trace
+    time the first call triggers is subtracted — it is already accounted
+    by the monitoring listener)."""
     from ..runtime import phases as _phases
+    from ..runtime import tracing as _tracing
 
     _phases.install_listener()
     comp0 = _phases.totals(_phases.COMPILE_KEYS)
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        el = (time.perf_counter() - t0
-              - (_phases.totals(_phases.COMPILE_KEYS) - comp0))
-        _phases.add("est_iter", max(el, 0.0))
+    with _tracing.span("fit.iterate", kind="fit") as sp:
+        try:
+            yield sp
+        finally:
+            el = (time.perf_counter() - sp.t0
+                  - (_phases.totals(_phases.COMPILE_KEYS) - comp0))
+            _phases.add("est_iter", max(el, 0.0))

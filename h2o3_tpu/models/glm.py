@@ -34,6 +34,7 @@ from ..frame.frame import Frame
 from ..parallel import distdata
 from ..parallel import mesh as cloudlib
 from ..runtime import qos as _qos
+from ..runtime import tracing
 from . import estimator_engine as _est
 from .metrics import (
     ModelMetricsBinomial,
@@ -297,6 +298,10 @@ def _irls_device_fn(cloud, shard_mode: str, n_shards: int, family: str,
             pdim = X.shape[1]
             pen_mask = jnp.ones(pdim, jnp.float32).at[pdim - 1].set(0.0)
 
+            # named scopes: the op metadata a trace viewer shows says which
+            # fusion is the Gram and which the solve; the PROGRAM's name
+            # (`jit_inner`) is what the benchmark's readers key on
+            @jax.named_scope("irls.gram")
             def gram_xy(b):
                 eta = jnp.matmul(X, b, precision=_HI)
                 mu = _linkinv(family, eta)
@@ -320,6 +325,7 @@ def _irls_device_fn(cloud, shard_mode: str, n_shards: int, family: str,
                 return (jnp.einsum("np,n,nq->pq", X, Ww, X, precision=_HI),
                         jnp.einsum("np,n->p", X, Ww * z, precision=_HI))
 
+            @jax.named_scope("irls.solve")
             def solve(gram, xy, bprev):
                 return _solve_pen_device(gram, xy, lam, alpha, n_obs,
                                          pen_mask, bprev, non_negative)
@@ -401,21 +407,23 @@ def attach_linear_artifacts(model: "GLMModel", train, valid, Xd,
     Reuses the training design matrix already in HBM for training metrics —
     single-device only: a row-sharded Xd may span non-addressable devices
     (multi-host mesh) and padded tail rows would corrupt metrics."""
-    model.training_metrics = model._make_metrics(
-        train, Xd=Xd if (cloud_size == 1 and int(Xd.shape[0]) == n) else None)
-    if valid is not None:
-        model.validation_metrics = model._make_metrics(valid)
-    # GLM varimp = |standardized coefficient| magnitudes
-    beta = model.beta
-    b = np.asarray(beta if model.family != "multinomial"
-                   else np.abs(beta).mean(axis=0))
-    mags = np.abs(b[:-1])
-    if mags.sum() > 0:
-        order = np.argsort(-mags)
-        model.varimp_table = [
-            (model.dinfo.coef_names[i], float(mags[i]),
-             float(mags[i] / mags.max()), float(mags[i] / mags.sum()))
-            for i in order if mags[i] > 0]
+    with tracing.span("fit.metrics", kind="fit"):
+        model.training_metrics = model._make_metrics(
+            train,
+            Xd=Xd if (cloud_size == 1 and int(Xd.shape[0]) == n) else None)
+        if valid is not None:
+            model.validation_metrics = model._make_metrics(valid)
+        # GLM varimp = |standardized coefficient| magnitudes
+        beta = model.beta
+        b = np.asarray(beta if model.family != "multinomial"
+                       else np.abs(beta).mean(axis=0))
+        mags = np.abs(b[:-1])
+        if mags.sum() > 0:
+            order = np.argsort(-mags)
+            model.varimp_table = [
+                (model.dinfo.coef_names[i], float(mags[i]),
+                 float(mags[i] / mags.max()), float(mags[i] / mags.sum()))
+                for i in order if mags[i] > 0]
     return model
 
 
@@ -536,14 +544,18 @@ class GLMModel(H2OModel):
         return np.asarray(self._eta_dev(frame, Xd=Xd),
                           np.float64)[: frame.nrow]
 
-    def _score(self, frame: Frame, Xd=None) -> np.ndarray:
-        # link inverse applied on device: ONE n-sized transfer per scoring;
-        # the host-side slice drops any row-bucket pad (see _eta_dev)
+    def _score_dev(self, frame: Frame, Xd=None):
+        """Fitted means as a DEVICE array (link inverse applied on device;
+        may carry row-bucket pad rows, see _eta_dev)."""
         eta = self._eta_dev(frame, Xd=Xd)
         if self.family == "multinomial":
-            return np.asarray(jax.nn.softmax(eta, axis=1),
-                              np.float64)[: frame.nrow]
-        return np.asarray(_linkinv(self.family, eta),
+            return jax.nn.softmax(eta, axis=1)
+        return _linkinv(self.family, eta)
+
+    def _score(self, frame: Frame, Xd=None) -> np.ndarray:
+        # ONE n-sized transfer per scoring; the host-side slice drops any
+        # row-bucket pad (see _eta_dev)
+        return np.asarray(self._score_dev(frame, Xd=Xd),
                           np.float64)[: frame.nrow]
 
     def predict(self, test_data: Frame) -> Frame:
@@ -562,7 +574,10 @@ class GLMModel(H2OModel):
         return Frame.from_dict({"predict": out})
 
     def _make_metrics(self, frame: Frame, Xd=None):
-        out = self._score(frame, Xd=Xd)
+        mu_dev = self._score_dev(frame, Xd=Xd)
+        with tracing.span("metrics.d2h", kind="fit"):
+            # the wait for the scoring program and the n-sized transfer
+            out = np.asarray(mu_dev, np.float64)[: frame.nrow]
         yv = frame.vec(self.y)
         if self.family in ("binomial", "quasibinomial"):
             return ModelMetricsBinomial.make(np.asarray(yv.data), out)
@@ -627,18 +642,21 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
             )
         std_flag = bool(p.get("standardize", True))
         n = train.nrow
-        w = (
-            train.vec(p["weights_column"]).numeric_np()
-            if p.get("weights_column")
-            else np.ones(n)
-        ).astype(np.float32)
+        with tracing.span("fit.response", kind="fit"):
+            w = (
+                train.vec(p["weights_column"]).numeric_np()
+                if p.get("weights_column")
+                else np.ones(n)
+            ).astype(np.float32)
 
-        if family in ("binomial", "quasibinomial", "fractionalbinomial"):
-            yarr = np.asarray(yvec.data, np.float32) if yvec.type == "enum" else yvec.numeric_np().astype(np.float32)
-        elif family == "multinomial":
-            yarr = np.asarray(yvec.data, np.int32)
-        else:
-            yarr = yvec.numeric_np().astype(np.float32)
+            if family in ("binomial", "quasibinomial", "fractionalbinomial"):
+                yarr = np.asarray(yvec.data, np.float32) if yvec.type == "enum" else yvec.numeric_np().astype(np.float32)
+            elif family == "multinomial":
+                yarr = np.asarray(yvec.data, np.int32)
+            else:
+                yarr = yvec.numeric_np().astype(np.float32)
+            yd = jnp.asarray(yarr if family != "multinomial" else yarr.astype(np.float32))
+            wd = jnp.asarray(w)
 
         alpha = p.get("alpha")
         alpha = float(alpha[0] if isinstance(alpha, (list, tuple)) else (alpha if alpha is not None else 0.5))
@@ -681,51 +699,50 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
             from . import dataset_cache as _dc
 
             cache0 = _dc.snapshot()
-        yd = jnp.asarray(yarr if family != "multinomial" else yarr.astype(np.float32))
-        wd = jnp.asarray(w)
         if multiproc:
-            dinfo = DataInfo(train, x, standardize=std_flag)
-            # multi-host cloud: this process holds only its ingest shard —
-            # assemble global row-sharded arrays homed where the data was
-            # parsed (MRTask compute-where-the-chunks-live), zero-weight
-            # padding balancing unequal byte ranges
-            X = dinfo.fit_transform(train)      # standardization stats are
-            #                                     global (DataInfo collective)
-            Xi = np.concatenate([X, np.ones((n, 1), np.float32)], axis=1)
-            y_f32 = np.asarray(yarr, np.float32)
-            if pod:
-                # ISSUE 18 pod lane: relayout the ingest shards onto the
-                # CANONICAL padded grid the 1-device forced-shard
-                # comparator uses (pad_rows(n_global, S), all pad at the
-                # global tail), so the blocked Gram fold groups identical
-                # f32 partials in the identical order — bit-identical β.
-                # Rows move only at slice boundaries (exchange_rows); no
-                # rank ever materializes the global design matrix.
-                _counts = distdata.row_counts(n)
-                npad = _est.pad_rows(n_glob, n_shards)
-                quota = npad // jax.process_count()
-                Xd = distdata.global_row_array(
-                    distdata.to_canonical(Xi.astype(np.float32), npad,
-                                          counts=_counts), quota, cloud)
-                yd = distdata.global_row_array(
-                    distdata.to_canonical(y_f32, npad, counts=_counts),
-                    quota, cloud)
-                wd = distdata.global_row_array(
-                    distdata.to_canonical(w, npad, counts=_counts),
-                    quota, cloud)
-                # exact global response/weight columns (rank order =
-                # global ingest order) for the host f64 β₀ init sums — a
-                # psum of per-rank partials would not be bitwise the
-                # comparator's single np.sum
-                y_host_fit = distdata.allgather_rows(y_f32)
-                w_host_fit = distdata.allgather_rows(w)
-            else:
-                quota = distdata.local_quota(n)
-                Xd = distdata.global_row_array(
-                    Xi.astype(np.float32), quota, cloud)
-                yd = distdata.global_row_array(y_f32, quota, cloud)
-                wd = distdata.global_row_array(w, quota, cloud)
-            n = n_glob
+            with tracing.span("fit.design", kind="fit", cache="off"):
+                dinfo = DataInfo(train, x, standardize=std_flag)
+                # multi-host cloud: this process holds only its ingest shard —
+                # assemble global row-sharded arrays homed where the data was
+                # parsed (MRTask compute-where-the-chunks-live), zero-weight
+                # padding balancing unequal byte ranges
+                X = dinfo.fit_transform(train)      # standardization stats are
+                #                                     global (DataInfo collective)
+                Xi = np.concatenate([X, np.ones((n, 1), np.float32)], axis=1)
+                y_f32 = np.asarray(yarr, np.float32)
+                if pod:
+                    # ISSUE 18 pod lane: relayout the ingest shards onto the
+                    # CANONICAL padded grid the 1-device forced-shard
+                    # comparator uses (pad_rows(n_global, S), all pad at the
+                    # global tail), so the blocked Gram fold groups identical
+                    # f32 partials in the identical order — bit-identical β.
+                    # Rows move only at slice boundaries (exchange_rows); no
+                    # rank ever materializes the global design matrix.
+                    _counts = distdata.row_counts(n)
+                    npad = _est.pad_rows(n_glob, n_shards)
+                    quota = npad // jax.process_count()
+                    Xd = distdata.global_row_array(
+                        distdata.to_canonical(Xi.astype(np.float32), npad,
+                                              counts=_counts), quota, cloud)
+                    yd = distdata.global_row_array(
+                        distdata.to_canonical(y_f32, npad, counts=_counts),
+                        quota, cloud)
+                    wd = distdata.global_row_array(
+                        distdata.to_canonical(w, npad, counts=_counts),
+                        quota, cloud)
+                    # exact global response/weight columns (rank order =
+                    # global ingest order) for the host f64 β₀ init sums — a
+                    # psum of per-rank partials would not be bitwise the
+                    # comparator's single np.sum
+                    y_host_fit = distdata.allgather_rows(y_f32)
+                    w_host_fit = distdata.allgather_rows(w)
+                else:
+                    quota = distdata.local_quota(n)
+                    Xd = distdata.global_row_array(
+                        Xi.astype(np.float32), quota, cloud)
+                    yd = distdata.global_row_array(y_f32, quota, cloud)
+                    wd = distdata.global_row_array(w, quota, cloud)
+                n = n_glob
         elif use_cached_design:
             ndev_eff = cloud.size if shard_mode == "mesh" else 1
             dinfo, Xd = _est.design_matrix(
@@ -744,22 +761,24 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
                     wd = jax.device_put(jnp.asarray(wpad), rs)
                 else:
                     yd, wd = jnp.asarray(ypad), jnp.asarray(wpad)
-        elif cloud.size > 1 and n >= cloud.size:
-            dinfo = DataInfo(train, x, standardize=std_flag)
-            X = dinfo.fit_transform(train)
-            Xi = np.concatenate([X, np.ones((n, 1), np.float32)], axis=1)
-            npad = cloudlib.pad_to_multiple(n, cloud.size)
-            padn = npad - n
-            Xd = jnp.asarray(np.concatenate([Xi, np.zeros((padn, Xi.shape[1]), np.float32)]))
-            yd = jnp.asarray(np.concatenate([np.asarray(yd), np.zeros(padn, np.float32)]))
-            wd = jnp.asarray(np.concatenate([w, np.zeros(padn, np.float32)]))
-            rs = cloud.row_sharding()
-            Xd, yd, wd = jax.device_put(Xd, rs), jax.device_put(yd, rs), jax.device_put(wd, rs)
         else:
-            # compact upload + on-device one-hot expansion (the dense design
-            # matrix never crosses the host↔device link)
-            dinfo = DataInfo(train, x, standardize=std_flag)
-            Xd = dinfo.device_design(train, fit=True, add_intercept=True)
+            with tracing.span("fit.design", kind="fit", cache="off"):
+                if cloud.size > 1 and n >= cloud.size:
+                    dinfo = DataInfo(train, x, standardize=std_flag)
+                    X = dinfo.fit_transform(train)
+                    Xi = np.concatenate([X, np.ones((n, 1), np.float32)], axis=1)
+                    npad = cloudlib.pad_to_multiple(n, cloud.size)
+                    padn = npad - n
+                    Xd = jnp.asarray(np.concatenate([Xi, np.zeros((padn, Xi.shape[1]), np.float32)]))
+                    yd = jnp.asarray(np.concatenate([np.asarray(yd), np.zeros(padn, np.float32)]))
+                    wd = jnp.asarray(np.concatenate([w, np.zeros(padn, np.float32)]))
+                    rs = cloud.row_sharding()
+                    Xd, yd, wd = jax.device_put(Xd, rs), jax.device_put(yd, rs), jax.device_put(wd, rs)
+                else:
+                    # compact upload + on-device one-hot expansion (the dense design
+                    # matrix never crosses the host↔device link)
+                    dinfo = DataInfo(train, x, standardize=std_flag)
+                    Xd = dinfo.device_design(train, fit=True, add_intercept=True)
         nfeat = len(dinfo.coef_names)
         fitplan: Dict[str, object] = dict(path="legacy")
 
@@ -906,20 +925,23 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
         on device, host reads final state only. Falls back to the f64 host
         loop if the f32 program diverged (separation-shaped data)."""
         pdim = int(Xd.shape[1])
-        if y_host is not None and w_host is not None:
-            # HOST init sums: a device jnp.sum over a row-sharded array
-            # reduces in psum order, which would break the blocks==mesh
-            # bit-identity contract at the very first β
-            wts = np.asarray(w_host, np.float64)
-            n_obs = float(wts.sum())
-            wy = float((wts * np.asarray(y_host, np.float64)).sum())
-            beta0 = self._beta_from_sums(wy, n_obs, family, pdim)
-        else:
-            beta0, n_obs = self._beta_init(yd, wd, family, pdim)
-        one_step = (family == "gaussian" and lam >= 0 and alpha * lam == 0)
-        fn = _irls_device_fn(cloud, shard_mode, n_shards, family,
-                             bool(self._parms.get("non_negative")), one_step)
-        with _est.iter_phase():
+        with tracing.span("fit.init", kind="fit"):
+            if y_host is not None and w_host is not None:
+                # HOST init sums: a device jnp.sum over a row-sharded array
+                # reduces in psum order, which would break the blocks==mesh
+                # bit-identity contract at the very first β
+                wts = np.asarray(w_host, np.float64)
+                n_obs = float(wts.sum())
+                wy = float((wts * np.asarray(y_host, np.float64)).sum())
+                beta0 = self._beta_from_sums(wy, n_obs, family, pdim)
+            else:
+                beta0, n_obs = self._beta_init(yd, wd, family, pdim)
+            one_step = (family == "gaussian" and lam >= 0
+                        and alpha * lam == 0)
+            fn = _irls_device_fn(cloud, shard_mode, n_shards, family,
+                                 bool(self._parms.get("non_negative")),
+                                 one_step)
+        with _est.iter_phase() as sp:
             # segmented dispatch under QoS (one_step stays a single solve);
             # the β carry round-trips on device between bounded segments
             beta_d = jnp.asarray(beta0, jnp.float32)
@@ -956,13 +978,14 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
                     _qos.yield_point("est_segment", compensate="est_iter")
             cloudlib.collective_fence(beta_d)
             beta = np.asarray(beta_d, np.float64)
+            iters = int(it_d)
+            sp.annotate(iterations=iters, segments=len(stops))
         if not np.isfinite(beta).all():
             # f32 divergence — the robust host loop is the answer, and the
             # plan records that the fused program did not stick
             fitplan.update(path="host_fallback")
             return self._irls(Xd, yd, wd, family, lam, alpha, max_iter,
                               beta_eps, tweedie_p)
-        iters = int(it_d)
         fitplan.update(
             path={"mesh": "fused_mesh", "blocks": "fused_blocks"}.get(
                 shard_mode, "fused"),

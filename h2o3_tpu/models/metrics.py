@@ -18,6 +18,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..runtime import tracing
+
 MAX_AUC_BINS = 400  # AUC2.NBINS
 
 
@@ -182,13 +184,18 @@ class ModelMetricsBinomial(ModelMetricsBase):
 
     @staticmethod
     def make(y: np.ndarray, p: np.ndarray) -> "ModelMetricsBinomial":
+        # three sorts over all rows, each a child span of the fit's
+        # `fit.metrics` (docs/observability.md): the exact AUC's rank sort,
+        # the binned threshold sweep, the gains/lift table
         y = np.asarray(y, np.float64)
         p = np.clip(np.asarray(p, np.float64), 1e-15, 1 - 1e-15)
-        auc = auc_exact(y, p)
+        with tracing.span("metrics.auc", kind="fit"):
+            auc = auc_exact(y, p)
         logloss = float(-np.mean(y * np.log(p) + (1 - y) * np.log(1 - p)))
         mse = float(np.mean((p - y) ** 2))
         # max-F1 threshold via the AUC2-style binned sweep
-        qs, tpr, fpr, tp, fp, P, Ntot = roc_curve_binned(y, p)
+        with tracing.span("metrics.roc", kind="fit"):
+            qs, tpr, fpr, tp, fp, P, Ntot = roc_curve_binned(y, p)
         fn = P - tp
         prec = tp / np.maximum(tp + fp, 1e-12)
         rec = tp / max(P, 1e-12)
@@ -204,12 +211,14 @@ class ModelMetricsBinomial(ModelMetricsBase):
         # pr_auc by trapezoid over recall
         order = np.argsort(rec)
         pr_auc = float(np.trapezoid(prec[order], rec[order])) if len(rec) > 1 else float("nan")
+        with tracing.span("metrics.gains", kind="fit"):
+            gains = gains_lift_table(y, p)
         return ModelMetricsBinomial(
             mse=mse, rmse=float(np.sqrt(mse)), nobs=len(y),
             auc=auc, pr_auc=pr_auc, logloss=logloss, gini=2 * auc - 1,
             mean_per_class_error=(err0 + err1) / 2, f1=float(f1s[bi]),
             accuracy=float((yhat == y).mean()), confusion_matrix=cm, threshold=thr,
-            gains_lift_table=gains_lift_table(y, p),
+            gains_lift_table=gains,
             _roc=(fpr, tpr),
         )
 

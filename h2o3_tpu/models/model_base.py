@@ -290,202 +290,213 @@ class DataInfo:
         import jax
         import jax.numpy as jnp
 
-        n = frame.nrow
-        nums, cats = [], []
-        means, stds = [], []
-        # wide-numeric fast pre-pass: per-column nanmean/nanstd/isnan calls
-        # cost ~2 s of host time at MNIST width (784 × 60k); batching them
-        # as axis-0 reductions over one stacked matrix is ~10× cheaper and
-        # numerically identical
-        _num_cols = [nm for k, nm, _ in self._spec if k == "num"]
-        _pre = {}
-        if len(_num_cols) > 8:
-            mat = np.stack([frame.vec(nm).numeric_np()
-                            for nm in _num_cols], axis=1)
-            nan_mask = np.isnan(mat)
-            has_nan_vec = nan_mask.any(axis=0)
-            if fit:
-                has_valid = ~nan_mask.all(axis=0)
-                with np.errstate(all="ignore"):
-                    mvec = np.where(has_valid, np.nanmean(mat, axis=0), 0.0)
-                    svec = np.where(has_valid, np.nanstd(mat, axis=0), 0.0)
-                nvalid = (~nan_mask).sum(axis=0)
-                # isfinite-else-0.0, matching the narrow per-column path:
-                # nan_to_num would map an infinite column mean to ±1.8e308
-                # and diverge the standardization stats by frame width
-                _pre = {nm: (mat[:, j], bool(has_nan_vec[j]),
-                             float(mvec[j]) if np.isfinite(mvec[j]) else 0.0,
-                             float(svec[j]) if np.isfinite(svec[j]) else 0.0,
-                             int(nvalid[j]))
-                        for j, nm in enumerate(_num_cols)}
-            else:
-                # scoring path: stats come from the stored fit-time values;
-                # only the column data + NaN flags are needed
-                _pre = {nm: (mat[:, j], bool(has_nan_vec[j]), 0.0, 0.0, 0)
-                        for j, nm in enumerate(_num_cols)}
-        pos = 0  # expanded-column position (for stored-stat lookups)
-        for kind, name, dom in self._spec:
-            v = frame.vec(name)
-            if kind == "num":
-                if name in _pre:
-                    c, has_nan, pre_m, pre_s, n_ok = _pre[name]
+        from ..runtime import tracing
+
+        # children of the fit's `fit.design` span (also opened when a
+        # frame is scored): column statistics and imputation from the
+        # codes, stacking the categorical codes, the transfer dtype of each
+        # numeric column, packing, the upload with the expand program's
+        # dispatch
+        with tracing.span("design.stats", kind="fit"):
+            n = frame.nrow
+            nums, cats = [], []
+            means, stds = [], []
+            # wide-numeric fast pre-pass: per-column nanmean/nanstd/isnan calls
+            # cost ~2 s of host time at MNIST width (784 × 60k); batching them
+            # as axis-0 reductions over one stacked matrix is ~10× cheaper and
+            # numerically identical
+            _num_cols = [nm for k, nm, _ in self._spec if k == "num"]
+            _pre = {}
+            if len(_num_cols) > 8:
+                mat = np.stack([frame.vec(nm).numeric_np()
+                                for nm in _num_cols], axis=1)
+                nan_mask = np.isnan(mat)
+                has_nan_vec = nan_mask.any(axis=0)
+                if fit:
+                    has_valid = ~nan_mask.all(axis=0)
+                    with np.errstate(all="ignore"):
+                        mvec = np.where(has_valid, np.nanmean(mat, axis=0), 0.0)
+                        svec = np.where(has_valid, np.nanstd(mat, axis=0), 0.0)
+                    nvalid = (~nan_mask).sum(axis=0)
+                    # isfinite-else-0.0, matching the narrow per-column path:
+                    # nan_to_num would map an infinite column mean to ±1.8e308
+                    # and diverge the standardization stats by frame width
+                    _pre = {nm: (mat[:, j], bool(has_nan_vec[j]),
+                                 float(mvec[j]) if np.isfinite(mvec[j]) else 0.0,
+                                 float(svec[j]) if np.isfinite(svec[j]) else 0.0,
+                                 int(nvalid[j]))
+                            for j, nm in enumerate(_num_cols)}
                 else:
-                    c = v.numeric_np()
-                    has_nan = bool(np.isnan(c).any())
-                    n_ok = int((~np.isnan(c)).sum()) if fit else 0
-                    pre_m = pre_s = 0.0
-                    if fit:
-                        with np.errstate(all="ignore"):
-                            pre_m = (float(np.nanmean(c)) if n_ok else 0.0)
-                            pre_s = (float(np.nanstd(c)) if n_ok else 0.0)
-                        pre_m = pre_m if np.isfinite(pre_m) else 0.0
-                        pre_s = pre_s if np.isfinite(pre_s) else 0.0
-                if self.impute_missing:
-                    if fit:
-                        self.col_means[name] = pre_m
-                    if has_nan:
-                        c = np.where(np.isnan(c),
-                                     self.col_means.get(name, 0.0), c)
-                        # post-impute plain std: mean-filling leaves the
-                        # mean unchanged and shrinks the variance by the
-                        # valid-row fraction (exactly, analytically)
-                        pre_s = pre_s * float(np.sqrt(n_ok / max(n, 1)))
-                if fit and self.standardize:
-                    # stats over valid rows only (nanmean/nanstd), exactly
-                    # like fit_transform. All-NaN columns get (0, 1) so
-                    # they standardize to the zeros fit_transform's
-                    # trailing nan_to_num produces.
-                    means.append([pre_m])
-                    stds.append([pre_s if pre_s >= 1e-10 else 1.0])
-                if not self.impute_missing and has_nan:
-                    if self.standardize:
-                        # fit_transform zeroes missing AFTER scaling, so the
-                        # raw fill that standardizes to 0 is the column mean
-                        mm = (means[-1][0] if fit
-                              else float(self.means[pos])
-                              if self.means is not None else 0.0)
-                        c = np.where(np.isnan(c), mm, c)
+                    # scoring path: stats come from the stored fit-time values;
+                    # only the column data + NaN flags are needed
+                    _pre = {nm: (mat[:, j], bool(has_nan_vec[j]), 0.0, 0.0, 0)
+                            for j, nm in enumerate(_num_cols)}
+            pos = 0  # expanded-column position (for stored-stat lookups)
+            for kind, name, dom in self._spec:
+                v = frame.vec(name)
+                if kind == "num":
+                    if name in _pre:
+                        c, has_nan, pre_m, pre_s, n_ok = _pre[name]
                     else:
-                        c = np.nan_to_num(c, nan=0.0)
-                nums.append(c.astype(np.float32))
-                pos += 1
-            else:
-                codes = np.asarray(v.data)
-                if v.domain != dom and v.domain:
-                    remap = np.asarray(
-                        [dom.index(d) if d in dom else -1 for d in v.domain],
-                        np.int64)
-                    codes = np.where(codes >= 0, remap[np.maximum(codes, 0)], -1)
-                cats.append(codes.astype(np.int32))
-                if fit and self.standardize:
-                    K = len(dom)
-                    cnt = np.bincount(codes[codes >= 0], minlength=K)[:K]
-                    p_lvl = cnt / max(n, 1)
-                    lv = p_lvl if self.use_all else p_lvl[1:]
-                    means.append(lv.tolist())
-                    stds.append([float(s) if (s := np.sqrt(pl * (1 - pl))) >= 1e-10
-                                 else 1.0 for pl in lv])
-                pos += len(dom) if self.use_all else max(len(dom) - 1, 0)
-        if fit and self.standardize:
-            self.means = np.asarray(
-                [m for grp in means for m in grp], np.float64)
-            self.stds = np.asarray(
-                [s for grp in stds for s in grp], np.float64)
+                        c = v.numeric_np()
+                        has_nan = bool(np.isnan(c).any())
+                        n_ok = int((~np.isnan(c)).sum()) if fit else 0
+                        pre_m = pre_s = 0.0
+                        if fit:
+                            with np.errstate(all="ignore"):
+                                pre_m = (float(np.nanmean(c)) if n_ok else 0.0)
+                                pre_s = (float(np.nanstd(c)) if n_ok else 0.0)
+                            pre_m = pre_m if np.isfinite(pre_m) else 0.0
+                            pre_s = pre_s if np.isfinite(pre_s) else 0.0
+                    if self.impute_missing:
+                        if fit:
+                            self.col_means[name] = pre_m
+                        if has_nan:
+                            c = np.where(np.isnan(c),
+                                         self.col_means.get(name, 0.0), c)
+                            # post-impute plain std: mean-filling leaves the
+                            # mean unchanged and shrinks the variance by the
+                            # valid-row fraction (exactly, analytically)
+                            pre_s = pre_s * float(np.sqrt(n_ok / max(n, 1)))
+                    if fit and self.standardize:
+                        # stats over valid rows only (nanmean/nanstd), exactly
+                        # like fit_transform. All-NaN columns get (0, 1) so
+                        # they standardize to the zeros fit_transform's
+                        # trailing nan_to_num produces.
+                        means.append([pre_m])
+                        stds.append([pre_s if pre_s >= 1e-10 else 1.0])
+                    if not self.impute_missing and has_nan:
+                        if self.standardize:
+                            # fit_transform zeroes missing AFTER scaling, so the
+                            # raw fill that standardizes to 0 is the column mean
+                            mm = (means[-1][0] if fit
+                                  else float(self.means[pos])
+                                  if self.means is not None else 0.0)
+                            c = np.where(np.isnan(c), mm, c)
+                        else:
+                            c = np.nan_to_num(c, nan=0.0)
+                    nums.append(c.astype(np.float32))
+                    pos += 1
+                else:
+                    codes = np.asarray(v.data)
+                    if v.domain != dom and v.domain:
+                        remap = np.asarray(
+                            [dom.index(d) if d in dom else -1 for d in v.domain],
+                            np.int64)
+                        codes = np.where(codes >= 0, remap[np.maximum(codes, 0)], -1)
+                    cats.append(codes.astype(np.int32))
+                    if fit and self.standardize:
+                        K = len(dom)
+                        cnt = np.bincount(codes[codes >= 0], minlength=K)[:K]
+                        p_lvl = cnt / max(n, 1)
+                        lv = p_lvl if self.use_all else p_lvl[1:]
+                        means.append(lv.tolist())
+                        stds.append([float(s) if (s := np.sqrt(pl * (1 - pl))) >= 1e-10
+                                     else 1.0 for pl in lv])
+                    pos += len(dom) if self.use_all else max(len(dom) - 1, 0)
+            if fit and self.standardize:
+                self.means = np.asarray(
+                    [m for grp in means for m in grp], np.float64)
+                self.stds = np.asarray(
+                    [s for grp in stds for s in grp], np.float64)
 
-        cats_a = (np.stack(cats, axis=1) if cats
-                  else np.zeros((n, 0), np.int32))
-        # per-column transfer dtype: integer-valued small-range columns
-        # ship as 1–2 bytes/value (LOSSLESS — C1Chunk/C2Chunk parity);
-        # everything else as f32. Group id rides the spec signature, so the
-        # layout is FROZEN at fit: scoring frames reuse the training
-        # program when their values still fit the stored dtypes, and fall
-        # back to ONE stable all-f32 program otherwise (per-frame
-        # re-derivation would churn fresh XLA compiles on every frame
-        # whose integrality/range differs).
-        def _fits_group(c, g):
-            if g == 2:
-                return True
-            if not c.size:
-                return False
-            with np.errstate(invalid="ignore"):
-                if not bool(np.all(np.mod(c, 1.0) == 0.0)):
+        with tracing.span("design.codes", kind="fit"):
+            cats_a = (np.stack(cats, axis=1) if cats
+                      else np.zeros((n, 0), np.int32))
+        with tracing.span("design.groups", kind="fit"):
+            # per-column transfer dtype: integer-valued small-range columns
+            # ship as 1–2 bytes/value (LOSSLESS — C1Chunk/C2Chunk parity);
+            # everything else as f32. Group id rides the spec signature, so the
+            # layout is FROZEN at fit: scoring frames reuse the training
+            # program when their values still fit the stored dtypes, and fall
+            # back to ONE stable all-f32 program otherwise (per-frame
+            # re-derivation would churn fresh XLA compiles on every frame
+            # whose integrality/range differs).
+            def _fits_group(c, g):
+                if g == 2:
+                    return True
+                if not c.size:
                     return False
-            lo, hi = (0.0, 255.0) if g == 0 else (-32768.0, 32767.0)
-            return bool(lo <= c.min() and c.max() <= hi)
+                with np.errstate(invalid="ignore"):
+                    if not bool(np.all(np.mod(c, 1.0) == 0.0)):
+                        return False
+                lo, hi = (0.0, 255.0) if g == 0 else (-32768.0, 32767.0)
+                return bool(lo <= c.min() and c.max() <= hi)
 
-        def _local_groups():
-            out = []
-            for c in nums:
-                out.append(0 if _fits_group(c, 0)
-                           else 1 if _fits_group(c, 1) else 2)
-            return out
+            def _local_groups():
+                out = []
+                for c in nums:
+                    out.append(0 if _fits_group(c, 0)
+                               else 1 if _fits_group(c, 1) else 2)
+                return out
 
-        from ..parallel import distdata
+            from ..parallel import distdata
 
-        multiproc = cloud is not None and distdata.multiprocess()
-        if fit:
-            num_group = _local_groups()
-            self._transfer_groups = list(num_group)
-        else:
-            stored = getattr(self, "_transfer_groups", None)
-            ok = bool(stored is not None and len(stored) == len(nums) and all(
-                _fits_group(c, g) for c, g in zip(nums, stored)))
-            if multiproc:
-                # pack layout is part of the compiled program: every rank
-                # must make the SAME stored-vs-fallback decision
-                ok = bool(distdata.allgather_host(
-                    np.asarray([ok], np.int32)).all())
-            if ok:
-                num_group = stored
-            elif cloud is not None:
-                # sharded ingest with no (usable) fit-time decision: decide
-                # now, globally — per-rank data ranges differ, so take the
-                # widest group each column needs anywhere
+            multiproc = cloud is not None and distdata.multiprocess()
+            if fit:
                 num_group = _local_groups()
-                if multiproc:
-                    num_group = list(distdata.allgather_host(
-                        np.asarray(num_group, np.int32)
-                    ).reshape(-1, len(num_group)).max(axis=0)) if nums else []
-                    num_group = [int(g) for g in num_group]
-                if stored is None:
-                    self._transfer_groups = list(num_group)
+                self._transfer_groups = list(num_group)
             else:
-                num_group = [2] * len(nums)
-        groups = ([], [], [])                 # uint8, int16, f32
-        for c, g in zip(nums, num_group):
-            groups[g].append(c)
-        dts = (np.uint8, np.int16, np.float32)
-        packs = [
-            (np.stack(g, axis=1).astype(dt) if g
-             else np.zeros((n, 0), dt))
-            for g, dt in zip(groups, dts)
-        ]
-        gi = iter(num_group)
-        sig = (tuple((k, next(gi) if k == "num" else (len(d) if d else 0))
-                     for k, _, d in self._spec),
-               self.use_all, self.standardize and self.means is not None,
-               add_intercept)
-        fn = _device_expand_fn(sig)
-        m_h = (np.asarray(self.means, np.float32)
-               if self.standardize and self.means is not None
-               else np.zeros(0, np.float32))
-        s_h = (np.asarray(self.stds, np.float32)
-               if self.standardize and self.stds is not None
-               else np.ones(0, np.float32))
-        if row_bucket and cloud is None:
-            from ..parallel.mesh import pad_to_multiple
+                stored = getattr(self, "_transfer_groups", None)
+                ok = bool(stored is not None and len(stored) == len(nums) and all(
+                    _fits_group(c, g) for c, g in zip(nums, stored)))
+                if multiproc:
+                    # pack layout is part of the compiled program: every rank
+                    # must make the SAME stored-vs-fallback decision
+                    ok = bool(distdata.allgather_host(
+                        np.asarray([ok], np.int32)).all())
+                if ok:
+                    num_group = stored
+                elif cloud is not None:
+                    # sharded ingest with no (usable) fit-time decision: decide
+                    # now, globally — per-rank data ranges differ, so take the
+                    # widest group each column needs anywhere
+                    num_group = _local_groups()
+                    if multiproc:
+                        num_group = list(distdata.allgather_host(
+                            np.asarray(num_group, np.int32)
+                        ).reshape(-1, len(num_group)).max(axis=0)) if nums else []
+                        num_group = [int(g) for g in num_group]
+                    if stored is None:
+                        self._transfer_groups = list(num_group)
+                else:
+                    num_group = [2] * len(nums)
+        with tracing.span("design.pack", kind="fit"):
+            groups = ([], [], [])                 # uint8, int16, f32
+            for c, g in zip(nums, num_group):
+                groups[g].append(c)
+            dts = (np.uint8, np.int16, np.float32)
+            packs = [
+                (np.stack(g, axis=1).astype(dt) if g
+                 else np.zeros((n, 0), dt))
+                for g, dt in zip(groups, dts)
+            ]
+            gi = iter(num_group)
+            sig = (tuple((k, next(gi) if k == "num" else (len(d) if d else 0))
+                         for k, _, d in self._spec),
+                   self.use_all, self.standardize and self.means is not None,
+                   add_intercept)
+            fn = _device_expand_fn(sig)
+            m_h = (np.asarray(self.means, np.float32)
+                   if self.standardize and self.means is not None
+                   else np.zeros(0, np.float32))
+            s_h = (np.asarray(self.stds, np.float32)
+                   if self.standardize and self.stds is not None
+                   else np.ones(0, np.float32))
+            if row_bucket and cloud is None:
+                from ..parallel.mesh import pad_to_multiple
 
-            # quantize the expand program's row dimension: nearby scoring
-            # frame sizes (CV folds, pages) reuse ONE compiled program; the
-            # zero-filled pad rows expand to garbage the CALLER slices off
-            npad_b = pad_to_multiple(n, row_bucket)
-            if npad_b != n:
-                packs = [np.concatenate(
-                    [p, np.zeros((npad_b - n,) + p.shape[1:], p.dtype)])
-                    for p in packs]
-                cats_a = np.concatenate(
-                    [cats_a, np.zeros((npad_b - n, cats_a.shape[1]),
-                                      cats_a.dtype)])
+                # quantize the expand program's row dimension: nearby scoring
+                # frame sizes (CV folds, pages) reuse ONE compiled program; the
+                # zero-filled pad rows expand to garbage the CALLER slices off
+                npad_b = pad_to_multiple(n, row_bucket)
+                if npad_b != n:
+                    packs = [np.concatenate(
+                        [p, np.zeros((npad_b - n,) + p.shape[1:], p.dtype)])
+                        for p in packs]
+                    cats_a = np.concatenate(
+                        [cats_a, np.zeros((npad_b - n, cats_a.shape[1]),
+                                          cats_a.dtype)])
 
         from ..runtime import phases as _phases
 
@@ -506,12 +517,14 @@ class DataInfo:
                 gc = distdata.global_row_array(cats_a, quota, cloud)
                 return fn(gp[0], gp[1], gp[2], gc, m_r, s_r)
 
-            return _phases.accounted_h2d(_sharded, nbytes)
-        return _phases.accounted_h2d(
-            lambda: fn(jnp.asarray(packs[0]), jnp.asarray(packs[1]),
-                       jnp.asarray(packs[2]), jnp.asarray(cats_a),
-                       jnp.asarray(m_h), jnp.asarray(s_h)),
-            nbytes)
+            with tracing.span("design.upload", kind="fit"):
+                return _phases.accounted_h2d(_sharded, nbytes)
+        with tracing.span("design.upload", kind="fit"):
+            return _phases.accounted_h2d(
+                lambda: fn(jnp.asarray(packs[0]), jnp.asarray(packs[1]),
+                           jnp.asarray(packs[2]), jnp.asarray(cats_a),
+                           jnp.asarray(m_h), jnp.asarray(s_h)),
+                nbytes)
 
     def _expand(self, frame: Frame, fit: bool) -> np.ndarray:
         cols = []
@@ -873,6 +886,43 @@ class H2OEstimator:
             from ..client import remote_train
 
             return remote_train(self, x, y, training_frame, validation_frame)
+        from ..runtime import tracing
+
+        # the fit's span tree (docs/observability.md): the same names for
+        # every estimator, under the trace id of the REST request / job /
+        # candidate that called, or one minted here
+        with tracing.span("train", kind="fit", algo=self.algo,
+                          rows=int(training_frame.nrow)) as sp:
+            with tracing.span("train.resolve", kind="fit"):
+                x, training_frame, validation_frame, nfolds = self._resolve(
+                    x, y, training_frame, validation_frame)
+            sp.annotate(predictors=len(x))
+            t0 = time.time()
+            with tracing.span("train.fit", kind="fit"):
+                model = self._fit(x, y, training_frame, validation_frame)
+            # a fold_column triggers CV by itself (its folds are the
+            # column's distinct values) — but only for estimators that CAN
+            # cross-validate: TargetEncoder-style builders consume
+            # fold_column for their own leakage handling inside _fit and
+            # define no _cv_predict
+            supports_cv = (type(self)._cv_predict
+                           is not H2OEstimator._cv_predict)
+            if ((nfolds >= 2
+                 or (self._parms.get("fold_column") and supports_cv))
+                    and self._is_supervised()):
+                with tracing.span("train.cv", kind="fit"):
+                    self._run_cv(model, x, y, training_frame, nfolds)
+            model.run_time = time.time() - t0
+            with tracing.span("train.publish", kind="fit"):
+                self._publish(model)
+        return self
+
+    def _resolve(self, x, y, training_frame: Frame,
+                 validation_frame: Optional[Frame]):
+        """What `train` settles before the fit: the predictor list (the
+        constant-column screen reads every column), the rows with a
+        response, the Job, the seed and the fold plan. Returns
+        (x, training_frame, validation_frame, nfolds)."""
         ignored = set(self._parms.get("ignored_columns") or [])
         if x is None:
             x = [
@@ -907,7 +957,6 @@ class H2OEstimator:
             description=f"{self.algo} train")
         if self.job.status == "CREATED":
             self.job.start()
-        t0 = time.time()
         seed = int(self._parms.get("seed", -1))
         if seed in (-1, None):
             self._parms["_actual_seed"] = 1234
@@ -925,16 +974,11 @@ class H2OEstimator:
                 "(hex/ModelBuilder cv_init)")
         if fold_col and fold_col not in training_frame.names:
             raise ValueError(f"fold_column {fold_col!r} not in frame")
-        model = self._fit(x, y, training_frame, validation_frame)
-        # a fold_column triggers CV by itself (its folds are the column's
-        # distinct values) — but only for estimators that CAN cross-
-        # validate: TargetEncoder-style builders consume fold_column for
-        # their own leakage handling inside _fit and define no _cv_predict
-        supports_cv = type(self)._cv_predict is not H2OEstimator._cv_predict
-        if ((nfolds >= 2 or (fold_col and supports_cv))
-                and self._is_supervised()):
-            self._run_cv(model, x, y, training_frame, nfolds)
-        model.run_time = time.time() - t0
+        return x, training_frame, validation_frame, nfolds
+
+    def _publish(self, model: "H2OModel") -> None:
+        """Hand the fitted model over: the DKV, the Job's result, the
+        checkpoint export."""
         self._model = model
         from ..runtime.dkv import DKV
 
@@ -953,7 +997,6 @@ class H2OEstimator:
                 save_model(model, ckpt_dir, force=True)
             except TypeError:
                 pass  # artifact format doesn't cover this algo yet
-        return self
 
     # -- n-fold CV (ModelBuilder.computeCrossValidation) --------------------
     def _run_cv(self, model: H2OModel, x, y, train: Frame, nfolds: int):

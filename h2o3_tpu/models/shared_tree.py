@@ -22,32 +22,48 @@ import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
-_PROFILE = bool(os.environ.get("H2O3_PROFILE"))
-
 from ..runtime import phases as _phases_acct
 from ..runtime import qos as _qos
+from ..runtime import tracing as _tracing
 
 
 class _Phase:
-    """Env-gated phase timer (H2O3_PROFILE=1) — the `water.util.Timer`
-    per-stage logging analog for the training driver."""
+    """The tree fit's phase boundaries — the `water.util.Timer` per-stage
+    logging analog for the training driver — and, from the same marks, its
+    part of the fit's span tree (docs/observability.md): `_fit` runs inside
+    ``with _Phase()``, which holds ONE ``fit.*`` span open at a time,
+    ``fit.design`` from the start; a mark with ``then=`` ends it there and
+    opens the next."""
 
     _SUBTRACT_KEYS = _phases_acct.COMPILE_KEYS + ("collective",)
 
     def __init__(self):
         self.t = time.time()
         self._comp0 = _phases_acct.totals(self._SUBTRACT_KEYS)
+        self._span = None
 
-    def mark(self, name, sync=None):
+    def __enter__(self):
+        self._open("fit.design")
+        return self
+
+    def __exit__(self, *exc):
+        return self._span.__exit__(*exc)
+
+    def _open(self, name):
+        self._span = _tracing.span(name, kind="fit")
+        self._span.__enter__()
+
+    def mark(self, name, sync=None, then=None):
         """Record a phase boundary into /3/Timeline (always); under
-        H2O3_PROFILE=1 or H2O3_PHASE_ACCOUNTING=1 additionally device-sync
-        first, so the recorded seconds are execution (not dispatch) time.
+        H2O3_PHASE_ACCOUNTING=1 additionally device-sync first, so the
+        recorded seconds are execution (not dispatch) time.
         Boundaries also feed runtime.phases so bench.py can decompose
-        wall-clock into {h2d, compute, d2h, ...} buckets."""
+        wall-clock into {h2d, compute, d2h, ...} buckets. `then` names the
+        ``fit.*`` span that begins at this boundary."""
         from ..runtime.timeline import Timeline
 
         _phases = _phases_acct
-        synced = (_PROFILE or _phases.ENABLED) and sync is not None
+        synced = _phases.ENABLED and sync is not None
         if synced:
             # fetch one element: on some backends
             # block_until_ready can return before the computation lands —
@@ -59,8 +75,6 @@ class _Phase:
             except Exception:
                 jax.block_until_ready(sync)
         now = time.time()
-        if _PROFILE:
-            print(f"[h2o3-profile] {name}: {now - self.t:.3f}s", flush=True)
         Timeline.record("train_phase", name, secs=round(now - self.t, 4),
                         synced=synced)
         # compile/trace time inside this interval is already accounted by
@@ -72,6 +86,9 @@ class _Phase:
         _phases.add_mark(name, max(now - self.t - (comp - self._comp0), 0.0))
         self._comp0 = comp
         self.t = now
+        if then is not None:
+            self._span.__exit__(None, None, None)
+            self._open(then)
 
 import jax
 import jax.numpy as jnp
@@ -1583,7 +1600,11 @@ class H2OSharedTreeEstimator(H2OEstimator):
         return out[:, 0]
 
     def _fit(self, x, y, train: Frame, valid: Optional[Frame]) -> SharedTreeModel:
-        _ph = _Phase()
+        with _Phase() as _ph:
+            return self._fit_phases(x, y, train, valid, _ph)
+
+    def _fit_phases(self, x, y, train: Frame, valid: Optional[Frame],
+                    _ph: _Phase) -> SharedTreeModel:
         tp = self._tree_params()
         self._validate_tree_params(tp)
         seed = self._parms["_actual_seed"]
@@ -2363,7 +2384,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             # superseded by the device y_dev_v (slot 4); indices are stable
             valid_state = [codes_v, None, margins_v, n_v, y_dev_v, vmask_d]
 
-        _ph.mark("device_put", sync=codes_d)
+        _ph.mark("device_put", sync=codes_d, then="fit.iterate")
         key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
         ntrees_target = max(int(tp["ntrees"]) - n_prior, 0)
         gain_total = np.zeros(F, np.float64)
@@ -2808,8 +2829,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             # "compute"), so it is compensated out of that bucket.
             _qos.yield_point(
                 "tree_chunk",
-                compensate=("compute" if (_PROFILE or _phases_acct.ENABLED)
-                            else None))
+                compensate="compute" if _phases_acct.ENABLED else None)
             # in-process candidate-crash injection (kill-and-resume pins)
             # + supervisor heartbeat: liveness at every chunk boundary
             _rfaults.check("supervisor.fit_abort", detail=f"m={m}")
@@ -2935,7 +2955,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     dart_scales.append(fn)
                 else:
                     dart_scales.append(1.0)
-            if _PROFILE or _phases_acct.ENABLED:
+            if _phases_acct.ENABLED:
                 # synced boundary: without it the compute bucket would time
                 # async dispatch, not execution, and overstate throughput
                 _ph.mark(f"chunk_{m}_{nsteps}trees", sync=margins)
@@ -3168,7 +3188,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             ]
         # training metrics straight from the final margins (already on device)
         # instead of a fresh forest re-predict — saves transfers + a compile
-        _ph.mark("forest_unpack")
+        _ph.mark("forest_unpack", then="fit.metrics")
         # sharded fits take the host metrics path: the binned-AUC reduction
         # is a whole-array scatter whose sharded lowering is not bit-stable
         # across device counts, and the margins D2H is local on a CPU mesh

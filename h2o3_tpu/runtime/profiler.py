@@ -4,7 +4,8 @@ Reference parity: `/3/Profiler` (`water/api/ProfilerHandler.java` +
 `water/util/JProfile.java`) collects stack-trace samples from every node —
 here `stack_samples()` snapshots all Python threads of this process (one
 process per TPU host). `trace()` wraps `jax.profiler` (perfetto/tensorboard
-capture) — strictly stronger than the reference's sampler for device time.
+capture, with the program's spans on the same clock) — strictly stronger
+than the reference's sampler for device time.
 """
 
 from __future__ import annotations
@@ -215,11 +216,23 @@ def tracing_stats(n: int = 20) -> Dict:
 
 @contextlib.contextmanager
 def trace(log_dir: str):
-    """`with profiler.trace('/tmp/tb'):` — device + host trace via
-    jax.profiler (viewable in tensorboard/perfetto)."""
+    """`with profiler.trace(dir):` around a `train()` / `predict()` — one
+    `jax.profiler` capture of the device planes AND the program's own
+    spans (`runtime/tracing.py`: every `tracing.span` is a
+    `TraceAnnotation`, so `train`, `fit.design`, `fit.iterate`, ... are
+    events on `/host:CPU`, on the clock of the device planes).
+
+    Started with the options the benchmark uses: the python tracer OFF (a
+    whole fit of python frames is GBs), host tracer level 2. The capture
+    lands at ``<log_dir>/plugins/profile/<timestamp>/<host>.xplane.pb``;
+    open it in Perfetto / TensorBoard's profile plugin, or reduce it with
+    `benchmark/reduce_trace.py` `load(path)`."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
         yield
     finally:

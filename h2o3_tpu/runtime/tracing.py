@@ -24,6 +24,15 @@ Design:
 - ops whose instrumentation already measures wall-clock (ingest/munge
   stats modules) register retroactively via ``record_span`` instead of
   wrapping their hot paths twice.
+- ONE primitive, two sinks: `span()` also holds a
+  ``jax.profiler.TraceAnnotation(name)`` open for its lifetime. With no
+  profiler session that is a flag test; under one (`profiler.trace()`, the
+  benchmark's ``--trace 1``) the span is an event on ``/host:CPU`` on the
+  SAME clock as the device planes, so a device-idle gap is named by the
+  innermost span that covers it with no offset arithmetic. Readers match
+  names exactly: a span NAME is a static string, what varies (ids, algo,
+  rows) goes into ``attrs``. The fit's span tree (`train`, `train.fit`,
+  `fit.design`, `fit.iterate`, ...) is listed in docs/observability.md.
 
 Metric fold: ``h2o3_trace_spans_total{kind}`` counts completed spans per
 kind in the central registry, so span volume itself is scrapable.
@@ -38,6 +47,8 @@ import uuid
 from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import env_int
 
@@ -147,7 +158,9 @@ def span(name: str, kind: str = "span", trace_id: Optional[str] = None,
          parent_id: Optional[str] = None, **attrs):
     """Open a span as a child of this thread's current span (or of the
     explicit trace_id/parent_id for cross-thread hops); record it on exit.
-    Exceptions mark the span ``error`` and propagate."""
+    Exceptions mark the span ``error`` and propagate. For its lifetime the
+    span is also a `TraceAnnotation` of the same name: an event on the
+    profiler's host plane whenever a profiler session is running."""
     cur = current()
     if trace_id is None and cur is not None:
         trace_id = cur.trace_id
@@ -158,7 +171,8 @@ def span(name: str, kind: str = "span", trace_id: Optional[str] = None,
     st = _stack()
     st.append(sp)
     try:
-        yield sp
+        with TraceAnnotation(name):
+            yield sp
     except BaseException as e:
         sp.attrs["error"] = f"{type(e).__name__}: {e}"
         raise

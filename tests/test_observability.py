@@ -354,6 +354,130 @@ def test_cached_sweep_fit_records_zero_new_traces(cloud1):
     assert warm1["retraces"] == warm0["retraces"]
 
 
+# -- the fit's span tree (ISSUE 24) -------------------------------------------
+
+# children of `train.fit`, in order, for the two fit paths; `train` itself
+# holds train.resolve, train.fit, train.publish (train.cv only when it runs)
+FIT_CHILDREN = {
+    "glm": ["fit.response", "fit.design", "fit.init", "fit.iterate",
+            "fit.metrics"],
+    "gbm": ["fit.design", "fit.iterate", "fit.metrics"],
+}
+
+
+def _fit_frame(n=20000, seed=11):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, 3))
+    k = rng.integers(0, 5, n)
+    y = (X[:, 0] + X[:, 1] + 0.3 * k + rng.normal(size=n) > 0.6).astype(int)
+    return Frame.from_dict(
+        {"a": X[:, 0], "b": X[:, 1], "c": X[:, 2],
+         "k": np.asarray(list("vwxyz"), dtype=object)[k],
+         "y": np.asarray(["n", "p"], dtype=object)[y]},
+        column_types={"k": "enum", "y": "enum"})
+
+
+def _fit_estimator(algo):
+    if algo == "glm":
+        from h2o3_tpu.models.glm import H2OGeneralizedLinearEstimator
+
+        return H2OGeneralizedLinearEstimator(family="binomial", lambda_=0)
+    from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+
+    return H2OGradientBoostingEstimator(ntrees=3, max_depth=3, seed=1)
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s["parent_id"] == parent["span_id"]),
+                  key=lambda s: s["ts"])
+
+
+def _check_fit_tree(spans, algo):
+    """One `train` root with the table's children in order, one trace id,
+    `train.fit`'s children inside its interval; returns the root."""
+    (root,) = [s for s in spans if s["name"] == "train"]
+    tree = [s for s in spans if s["kind"] == "fit"]
+    assert {s["trace_id"] for s in tree} == {root["trace_id"]}
+    assert root["attrs"]["algo"] == algo and root["attrs"]["rows"] == 20000
+    assert root["attrs"]["predictors"] == 4
+    top = _children(spans, root)
+    assert [s["name"] for s in top] == ["train.resolve", "train.fit",
+                                        "train.publish"]
+    fit = top[1]
+    kids = _children(spans, fit)
+    assert [s["name"] for s in kids] == FIT_CHILDREN[algo]
+    eps = 5e-3      # `ts` is the wall clock, durations the monotonic one
+    for k in kids:
+        assert k["ts"] >= fit["ts"] - eps
+        assert k["ts"] + k["duration_s"] <= fit["ts"] + fit["duration_s"] + eps
+    # what only the two containers cover (their self time) is a sliver
+    self_s = (root["duration_s"] - sum(s["duration_s"] for s in top)
+              + fit["duration_s"] - sum(s["duration_s"] for s in kids))
+    assert 0 <= self_s < 0.10 * root["duration_s"]
+    return root
+
+
+@pytest.mark.parametrize("algo", ["glm", "gbm"])
+def test_fit_leaves_one_span_tree(cloud1, algo):
+    tracing.clear()
+    _fit_estimator(algo).train(y="y", training_frame=_fit_frame())
+    spans = tracing.spans()
+    _check_fit_tree(spans, algo)
+    by_name = {s["name"]: s for s in spans}
+    if algo == "glm":
+        assert by_name["fit.design"]["attrs"]["cache"] in ("hit", "miss")
+        assert by_name["fit.design"]["attrs"]["bytes_h2d"] > 0
+        assert by_name["fit.iterate"]["attrs"]["iterations"] >= 1
+        assert by_name["fit.iterate"]["attrs"]["segments"] == 1
+        assert (by_name["metrics.d2h"]["parent_id"]
+                == by_name["fit.metrics"]["span_id"])
+    # no span per iteration, level or tree: a fit is a handful of spans
+    assert len([s for s in spans if s["kind"] == "fit"]) <= 20
+
+
+def test_fit_spans_land_on_the_profilers_host_plane(cloud1, tmp_path):
+    """The same spans, written by the same primitive, are events on
+    /host:CPU of a `profiler.trace()` capture (python tracer off), nested
+    in time — what the benchmark's reduction names idle gaps with."""
+    import glob
+    import os
+    import sys
+
+    from h2o3_tpu.runtime import profiler
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import reduce_trace
+
+    est, fr = _fit_estimator("glm"), _fit_frame()
+    with profiler.trace(str(tmp_path)):
+        est.train(y="y", training_frame=fr)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    assert os.path.getsize(path) < 8 << 20       # python tracer off
+    trace = reduce_trace.load(path)
+    ours = {n: (s, s + d) for _, n, s, d in trace.host
+            if n.startswith(("train", "fit.", "metrics.", "design."))}
+    assert set(ours) >= {"train", "train.resolve", "train.fit",
+                         "train.publish", "metrics.d2h",
+                         *FIT_CHILDREN["glm"]}
+
+    def inside(a, b):
+        return ours[b][0] <= ours[a][0] and ours[a][1] <= ours[b][1]
+
+    assert inside("train.fit", "train") and inside("train.resolve", "train")
+    assert all(inside(n, "train.fit") for n in FIT_CHILDREN["glm"])
+    assert inside("metrics.d2h", "fit.metrics")
+    assert inside("metrics.auc", "fit.metrics")
+    assert inside("design.upload", "fit.design")
+    # and a gap inside the design build is named by it, not by a container
+    lo, hi = ours["fit.design"]
+    assert trace.host_doing(lo + 0.1 * (hi - lo), hi - 0.1 * (hi - lo)) \
+        not in ("train", "train.fit")
+
+
 # -- REST surfaces ------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -528,6 +652,43 @@ def test_trace_id_propagation_client_job_candidate_batch(obs_server, cloud1):
     non_roots = [e for e in evs if e["args"]["parent_id"] is not None]
     assert roots and non_roots
     assert all(e["args"]["parent_id"] in ids for e in non_roots)
+
+
+def test_rest_train_hangs_fit_tree_under_job_span(obs_server, cloud1):
+    """ISSUE 24: a `POST /3/ModelBuilders/glm` leaves ONE tree at
+    /3/Trace — request and job spans, and the fit's `train` under the
+    `job:` span with its whole span tree, all on the client's trace id."""
+    import time as _time
+
+    from h2o3_tpu.client import H2OConnection
+
+    fr = _fit_frame()
+    fr.key = "obs_fit_tree_fr"
+    DKV.put(fr.key, fr)
+    conn = H2OConnection(f"http://127.0.0.1:{obs_server.port}")
+    with conn.trace() as tid:
+        r = conn.post("/3/ModelBuilders/glm", training_frame=fr.key,
+                      response_column="y", family="binomial", lambda_=0)
+        conn.wait_for_job(r["job"]["key"]["name"], timeout=300.0)
+    # the job reads DONE inside train.publish, before `train` and `job:`
+    # close: poll until the job span has landed in the ring
+    deadline = _time.time() + 5.0
+    while True:
+        out, _ = _http("GET", obs_server.port, f"/3/Trace?trace_id={tid}")
+        evs = [e for e in out["traceEvents"] if e.get("ph") == "X"]
+        if any(e["cat"] == "job" for e in evs) or _time.time() > deadline:
+            break
+        _time.sleep(0.05)
+    spans = [dict(name=e["name"], kind=e["cat"], ts=e["ts"] / 1e6,
+                  duration_s=e["dur"] / 1e6, trace_id=e["args"]["trace_id"],
+                  span_id=e["args"]["span_id"],
+                  parent_id=e["args"]["parent_id"], attrs=e["args"])
+             for e in evs]
+    assert {s["trace_id"] for s in spans} == {tid}
+    root = _check_fit_tree(spans, "glm")
+    (job,) = [s for s in spans if s["kind"] == "job"]
+    assert job["name"].startswith("job:glm_rest_")
+    assert root["parent_id"] == job["span_id"]
 
 
 def test_rest_warm_predict_zero_new_traces_pin(obs_server, cloud1):
