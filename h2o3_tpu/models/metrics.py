@@ -7,14 +7,21 @@ score histogram → AUC / pr-AUC / max-F1 and friends), `hex/ConfusionMatrix.jav
 
 The reference computes these inside scoring MRTasks via
 `ModelMetrics.MetricBuilder` map/reduce; here the reductions are numpy on
-gathered predictions (cheap relative to training) with the same binned-AUC
-design available for the distributed path. Gini = 2·AUC−1 as in AUC2.
+gathered predictions, on one host core, with the same binned-AUC design
+available for the distributed path. Gini = 2·AUC−1 as in AUC2.
+
+That is not cheap relative to training: at 1,812,500 rows the binomial
+metrics were 0.98 s of a 2.1 s GLM fit on a v5e host while they sorted the
+scores three times (ledger and `PERF.md` §5, PR 24). So
+`ModelMetricsBinomial.make` orders the scores ONCE (`order_scores`) and the
+exact AUC, the threshold sweep and the gains/lift table all read that one
+ordering; what it costs now is in `PERF.md` §5.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -23,43 +30,86 @@ from ..runtime import tracing
 MAX_AUC_BINS = 400  # AUC2.NBINS
 
 
-def roc_curve_binned(y: np.ndarray, p: np.ndarray, nbins: int = MAX_AUC_BINS):
-    """AUC2's design: histogram scores into <=400 threshold bins, then sweep."""
-    y = np.asarray(y).astype(np.float64)
-    p = np.asarray(p).astype(np.float64)
-    qs = np.unique(np.quantile(p, np.linspace(0, 1, nbins)))
-    bins = np.searchsorted(qs, p, side="left")
-    npos = np.bincount(bins, weights=y, minlength=len(qs) + 1)
-    nneg = np.bincount(bins, weights=1 - y, minlength=len(qs) + 1)
+class ScoreOrder(NamedTuple):
+    """The scores of one `(y, p)` in ascending order, ties in row order (the
+    permutation of `np.argsort(p, kind="stable")`), and what every order
+    statistic below reads of `y` in that order."""
+    ps: np.ndarray   # p[order], float64
+    cum: np.ndarray  # n + 1 running sums: cum[i] = y[order][:i].sum()
+    path: str        # how the permutation was made: "packed32" | "argsort"
+
+
+def _stable_order(p: np.ndarray):
+    """`(order, p[order], path)` with `order` the stable ascending argsort
+    of float64 `p`. Scores that float32 holds exactly and that carry no sign
+    bit order like their bit patterns, so there the permutation comes from
+    one sort of the uint64 keys `bits << 32 | row` (the row breaks ties in
+    row order): the same permutation, several times sooner."""
+    n = len(p)
+    with np.errstate(over="ignore"):
+        p32 = p.astype(np.float32)
+    if (0 < n < 2 ** 32 and p32.view(np.int32).min() >= 0
+            and np.array_equal(p32, p)):
+        key = p32.view(np.uint32).astype(np.uint64)
+        key <<= np.uint64(32)
+        key |= np.arange(n, dtype=np.uint64)
+        key.sort()
+        order = (key & np.uint64(0xFFFFFFFF)).view(np.int64)
+        key >>= np.uint64(32)
+        ps = key.astype(np.uint32).view(np.float32).astype(np.float64)
+        return order, ps, "packed32"
+    order = np.argsort(p, kind="stable")
+    return order, p[order], "argsort"
+
+
+def order_scores(y: np.ndarray, p: np.ndarray) -> ScoreOrder:
+    """The one O(n log n) step of the binomial metrics."""
+    order, ps, path = _stable_order(np.asarray(p, np.float64))
+    cum = np.zeros(len(ps) + 1)
+    np.cumsum(np.asarray(y, np.float64)[order], out=cum[1:])
+    return ScoreOrder(ps, cum, path)
+
+
+def roc_curve_binned(y: np.ndarray, p: np.ndarray, nbins: int = MAX_AUC_BINS,
+                     ordering: Optional[ScoreOrder] = None):
+    """AUC2's design: histogram scores into <=400 threshold bins, then sweep.
+    `ordering`, where the caller has one, is `order_scores(y, p)`."""
+    ps, cum, _ = ordering or order_scores(y, p)
+    n = len(ps)
+    qs = np.unique(np.quantile(ps, np.linspace(0, 1, nbins)))
+    # bin b holds the scores in (qs[b-1], qs[b]] — searchsorted(qs, p,
+    # "left") — and is counted between the places of its two edges in the
+    # sorted scores; the last bin, above the maximum, stays empty
+    at = np.append(np.searchsorted(ps, qs, side="right"), n)
+    npos = np.diff(cum[at], prepend=0.0)
+    nneg = np.diff(at, prepend=0) - npos
     # descending threshold sweep
     tp = np.cumsum(npos[::-1])[::-1]
     fp = np.cumsum(nneg[::-1])[::-1]
-    P, Ntot = y.sum(), (1 - y).sum()
+    P, Ntot = cum[-1], n - cum[-1]
     tpr = tp / max(P, 1e-12)
     fpr = fp / max(Ntot, 1e-12)
     return qs, tpr, fpr, tp, fp, P, Ntot
 
 
-def auc_exact(y: np.ndarray, p: np.ndarray) -> float:
+def auc_exact(y: np.ndarray, p: np.ndarray,
+              ordering: Optional[ScoreOrder] = None) -> float:
     """Exact rank AUC (ties handled) — matches AUC2 in the limit of one bin
-    per distinct score."""
-    y = np.asarray(y).astype(np.float64)
-    order = np.argsort(p, kind="mergesort")
-    ranks = np.empty_like(order, dtype=np.float64)
-    ranks[order] = np.arange(1, len(p) + 1)
-    # average ranks over ties (vectorized run-length expansion)
-    ps = np.asarray(p)[order]
-    uniq, start = np.unique(ps, return_index=True)
-    end = np.append(start[1:], len(ps))
-    avg = (start + 1 + end) / 2.0
-    tie_rank = np.repeat(avg, end - start)
-    r = np.empty_like(tie_rank)
-    r[order] = tie_rank
-    npos = y.sum()
-    nneg = len(y) - npos
+    per distinct score. `ordering` as for `roc_curve_binned`."""
+    ps, cum, _ = ordering or order_scores(y, p)
+    n = len(ps)
+    npos = cum[-1]
+    nneg = n - npos
     if npos == 0 or nneg == 0:
         return float("nan")
-    return float((r[y == 1].sum() - npos * (npos + 1) / 2) / (npos * nneg))
+    # a run of tied scores [start, end) shares the average of its ranks
+    # start+1 .. end, so the positives' rank sum is a sum over runs, not
+    # over rows: `edge` holds every run's start and, one on, its end
+    edge = np.flatnonzero(np.concatenate(([True], ps[1:] != ps[:-1], [True])))
+    ranks = np.diff(cum[edge])          # each run's positives, times ...
+    ranks *= edge[:-1] + edge[1:]       # ... twice its average rank, less 1
+    rank_sum = (ranks.sum() + npos) / 2
+    return float((rank_sum - npos * (npos + 1) / 2) / (npos * nneg))
 
 
 class MetricValue(float):
@@ -124,15 +174,15 @@ class ModelMetricsRegression(ModelMetricsBase):
         )
 
 
-def gains_lift_table(y: np.ndarray, p: np.ndarray, groups: int = 16):
+def gains_lift_table(y: np.ndarray, p: np.ndarray, groups: int = 16,
+                     ordering: Optional[ScoreOrder] = None):
     """Quantile gains/lift table — `hex/GainsLift.java` (16 groups default):
-    per group cumulative capture rate, lift, response rate."""
-    y = np.asarray(y, np.float64)
-    order = np.argsort(-np.asarray(p), kind="mergesort")
-    ys = y[order]
-    ps = np.asarray(p)[order]
-    n = len(ys)
-    total_pos = max(ys.sum(), 1e-12)
+    per group cumulative capture rate, lift, response rate. Rows go by
+    descending score, ties in row order. `ordering` as for
+    `roc_curve_binned`."""
+    ps, cum, _ = ordering or order_scores(y, p)
+    n = len(ps)
+    total_pos = max(cum[-1], 1e-12)
     bounds = np.unique((np.arange(1, groups + 1) * n) // groups)
     bounds = bounds[bounds > 0]  # n < groups would emit an empty first group
     rows = []
@@ -140,14 +190,19 @@ def gains_lift_table(y: np.ndarray, p: np.ndarray, groups: int = 16):
     cum_pos = 0.0
     overall_rate = total_pos / n
     for b in bounds:
-        grp = ys[prev:b]
-        s = grp.sum()
+        # the b highest scores are every score above v = ps[n - b] and, of
+        # v's tie run [lo, hi), the first hi - (n - b) rows: the descending
+        # order is the ascending one reversed run by run
+        v = ps[n - b]
+        lo = np.searchsorted(ps, v)
+        hi = np.searchsorted(ps, v, side="right")
+        s = cum[-1] - cum[hi] + cum[lo + hi - (n - b)] - cum[lo] - cum_pos
         cum_pos += s
-        rate = s / max(len(grp), 1)
+        rate = s / max(b - prev, 1)
         rows.append(dict(
             group=len(rows) + 1,
             cumulative_data_fraction=b / n,
-            lower_threshold=float(ps[b - 1]),
+            lower_threshold=float(v),
             lift=float(rate / overall_rate),
             cumulative_lift=float((cum_pos / b) / overall_rate),
             response_rate=float(rate),
@@ -184,18 +239,23 @@ class ModelMetricsBinomial(ModelMetricsBase):
 
     @staticmethod
     def make(y: np.ndarray, p: np.ndarray) -> "ModelMetricsBinomial":
-        # three sorts over all rows, each a child span of the fit's
-        # `fit.metrics` (docs/observability.md): the exact AUC's rank sort,
-        # the binned threshold sweep, the gains/lift table
+        # ONE ordering of the scores (`metrics.order`), then the three order
+        # statistics read off it, each a child span of the fit's
+        # `fit.metrics` (docs/observability.md): the exact AUC, the binned
+        # threshold sweep, the gains/lift table
         y = np.asarray(y, np.float64)
         p = np.clip(np.asarray(p, np.float64), 1e-15, 1 - 1e-15)
+        with tracing.span("metrics.order", kind="fit") as sp:
+            ordering = order_scores(y, p)
+            sp.annotate(path=ordering.path)
         with tracing.span("metrics.auc", kind="fit"):
-            auc = auc_exact(y, p)
+            auc = auc_exact(y, p, ordering=ordering)
         logloss = float(-np.mean(y * np.log(p) + (1 - y) * np.log(1 - p)))
         mse = float(np.mean((p - y) ** 2))
         # max-F1 threshold via the AUC2-style binned sweep
         with tracing.span("metrics.roc", kind="fit"):
-            qs, tpr, fpr, tp, fp, P, Ntot = roc_curve_binned(y, p)
+            qs, tpr, fpr, tp, fp, P, Ntot = roc_curve_binned(
+                y, p, ordering=ordering)
         fn = P - tp
         prec = tp / np.maximum(tp + fp, 1e-12)
         rec = tp / max(P, 1e-12)
@@ -212,7 +272,7 @@ class ModelMetricsBinomial(ModelMetricsBase):
         order = np.argsort(rec)
         pr_auc = float(np.trapezoid(prec[order], rec[order])) if len(rec) > 1 else float("nan")
         with tracing.span("metrics.gains", kind="fit"):
-            gains = gains_lift_table(y, p)
+            gains = gains_lift_table(y, p, ordering=ordering)
         return ModelMetricsBinomial(
             mse=mse, rmse=float(np.sqrt(mse)), nobs=len(y),
             auc=auc, pr_auc=pr_auc, logloss=logloss, gini=2 * auc - 1,

@@ -431,6 +431,13 @@ def test_fit_leaves_one_span_tree(cloud1, algo):
         assert by_name["fit.iterate"]["attrs"]["segments"] == 1
         assert (by_name["metrics.d2h"]["parent_id"]
                 == by_name["fit.metrics"]["span_id"])
+        # the scores are ordered once, inside `fit.metrics` and before what
+        # reads the ordering; a device scorer's float32 takes the packed key
+        in_metrics = [s["name"] for s in
+                      _children(spans, by_name["fit.metrics"])]
+        assert in_metrics == ["metrics.d2h", "metrics.order", "metrics.auc",
+                              "metrics.roc", "metrics.gains"]
+        assert by_name["metrics.order"]["attrs"]["path"] == "packed32"
     # no span per iteration, level or tree: a fit is a handful of spans
     assert len([s for s in spans if s["kind"] == "fit"]) <= 20
 
@@ -461,7 +468,7 @@ def test_fit_spans_land_on_the_profilers_host_plane(cloud1, tmp_path):
     ours = {n: (s, s + d) for _, n, s, d in trace.host
             if n.startswith(("train", "fit.", "metrics.", "design."))}
     assert set(ours) >= {"train", "train.resolve", "train.fit",
-                         "train.publish", "metrics.d2h",
+                         "train.publish", "metrics.d2h", "metrics.order",
                          *FIT_CHILDREN["glm"]}
 
     def inside(a, b):
@@ -470,6 +477,7 @@ def test_fit_spans_land_on_the_profilers_host_plane(cloud1, tmp_path):
     assert inside("train.fit", "train") and inside("train.resolve", "train")
     assert all(inside(n, "train.fit") for n in FIT_CHILDREN["glm"])
     assert inside("metrics.d2h", "fit.metrics")
+    assert inside("metrics.order", "fit.metrics")
     assert inside("metrics.auc", "fit.metrics")
     assert inside("design.upload", "fit.design")
     # and a gap inside the design build is named by it, not by a container

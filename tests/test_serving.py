@@ -264,7 +264,10 @@ def test_batcher_retires_expired_pendings_unscored():
     gate = threading.Event()
     blocker = StubModel(gate=gate)
     metrics = ServingMetrics()
-    cfg = _cfg(request_timeout_s=0.15, max_wait_ms=1.0)
+    # the timeout is also what the FRESH request below may wait in the queue:
+    # wide enough that a worker thread starved by the other test processes
+    # does not expire it (0.15 s did, now and then, under six xdist workers)
+    cfg = _cfg(request_timeout_s=0.6, max_wait_ms=1.0)
     batcher = MicroBatcher(ScorerCache(4), metrics, cfg)
 
     # a caller that will give up (its model blocks past the timeout)
@@ -285,7 +288,7 @@ def test_batcher_retires_expired_pendings_unscored():
             w.q.extend(stale)
             w.cond.notify_all()
     t.join(timeout=10)
-    time.sleep(0.3)            # let every stale entry pass its timeout
+    time.sleep(0.2)            # let every stale entry pass its timeout
     gate.set()                 # unblock the in-flight batch
     # a FRESH live request is still served promptly...
     out = batcher.submit("m", model, _frame(3, base=9.0))
