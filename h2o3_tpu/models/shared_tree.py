@@ -33,25 +33,41 @@ class _Phase:
     part of the fit's span tree (docs/observability.md): `_fit` runs inside
     ``with _Phase()``, which holds ONE ``fit.*`` span open at a time,
     ``fit.design`` from the start; a mark with ``then=`` ends it there and
-    opens the next."""
+    opens the next. Under it `stage()` holds one child span open at a time
+    (``design.matrix``, ``iterate.setup``, ...): the stages tile their
+    ``fit.*`` span, so no stretch of a tree fit is without a name."""
 
     _SUBTRACT_KEYS = _phases_acct.COMPILE_KEYS + ("collective",)
 
     def __init__(self):
         self.t = time.time()
         self._comp0 = _phases_acct.totals(self._SUBTRACT_KEYS)
-        self._span = None
+        self._span = self._stage = None
+        self.fit_span = self.stage_span = None   # the open Spans, for attrs
 
     def __enter__(self):
         self._open("fit.design")
         return self
 
     def __exit__(self, *exc):
+        self._end_stage(*exc)
         return self._span.__exit__(*exc)
 
     def _open(self, name):
         self._span = _tracing.span(name, kind="fit")
-        self._span.__enter__()
+        self.fit_span = self._span.__enter__()
+
+    def _end_stage(self, *exc):
+        if self._stage is not None:
+            self._stage.__exit__(*(exc or (None, None, None)))
+            self._stage = self.stage_span = None
+
+    def stage(self, name):
+        """End the open stage, if any, and open `name` as a child of the
+        open ``fit.*`` span."""
+        self._end_stage()
+        self._stage = _tracing.span(name, kind="fit")
+        self.stage_span = self._stage.__enter__()
 
     def mark(self, name, sync=None, then=None):
         """Record a phase boundary into /3/Timeline (always); under
@@ -87,8 +103,23 @@ class _Phase:
         self._comp0 = comp
         self.t = now
         if then is not None:
+            self._end_stage()
             self._span.__exit__(None, None, None)
             self._open(then)
+
+
+def _noting_miss(ph: _Phase, builder):
+    """`builder` for a dataset-cache look-up made under stage `ph` holds
+    open: called only on a miss, it says so on the stage's span (attr
+    ``cache``, ``hit`` until then)."""
+    sp = ph.stage_span
+
+    def run():
+        sp.annotate(cache="miss")
+        return builder()
+
+    return run
+
 
 import jax
 import jax.numpy as jnp
@@ -1605,6 +1636,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
 
     def _fit_phases(self, x, y, train: Frame, valid: Optional[Frame],
                     _ph: _Phase) -> SharedTreeModel:
+        _ph.stage("design.matrix")
         tp = self._tree_params()
         self._validate_tree_params(tp)
         seed = self._parms["_actual_seed"]
@@ -1650,9 +1682,11 @@ class H2OSharedTreeEstimator(H2OEstimator):
             n, F = int(len(cv_rows)), int(pbm.codes.shape[1])
             nbins = int(pbm.nbins)
         else:
+            _ph.stage_span.annotate(cache="hit" if use_cache else "off")
             if use_cache:
                 X, is_cat, doms = _dsc.matrix(
-                    train, x, builder=lambda: frame_to_matrix(train, x))
+                    train, x, builder=_noting_miss(
+                        _ph, lambda: frame_to_matrix(train, x)))
             else:
                 X, is_cat, doms = frame_to_matrix(train, x)
             n, F = X.shape
@@ -1693,6 +1727,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     f"({hbm_budget >> 30} GiB)")
                 tp["max_depth"] = feas
         _ph.mark("frame_to_matrix")
+        _ph.stage("design.bins")
+        _ph.stage_span.annotate(cache="hit" if use_cache else "off")
         col_ranges = None
         if multiproc:
             # multi-host cloud: this process holds its ingest shard; global
@@ -1731,11 +1767,11 @@ class H2OSharedTreeEstimator(H2OEstimator):
         elif use_cache:
             bm = _dsc.bins(
                 train, x, nbins, tp["histogram_type"], seed,
-                builder=lambda: build_bins(
+                builder=_noting_miss(_ph, lambda: build_bins(
                     X, nbins=nbins, histogram_type=tp["histogram_type"],
                     names=list(x), is_categorical=is_cat, domains=doms,
                     seed=seed, col_ranges=col_ranges,
-                    col_quantile_edges=col_qedges))
+                    col_quantile_edges=col_qedges)))
         else:
             bm = build_bins(
                 X, nbins=nbins, histogram_type=tp["histogram_type"], names=list(x),
@@ -1743,6 +1779,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 col_ranges=col_ranges, col_quantile_edges=col_qedges,
             )
 
+        _ph.stage("design.vectors")
         w = (
             train.vec(self._parms["weights_column"]).numeric_np()
             if self._parms.get("weights_column")
@@ -1923,6 +1960,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             return np.asarray(a)[:n]
 
         _ph.mark("build_bins")
+        _ph.stage("design.codes")
 
         # ---- resident sub-byte code packing (ISSUE 7 tentpole) -----------
         # The device-resident code matrix stays PACKED for the whole fit:
@@ -2109,6 +2147,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             else:
                 codes_d = distdata.global_row_array(padr(bm.codes), quota,
                                                     cloud)
+            _ph.stage("design.state")
             y_d = distdata.global_row_array(
                 padr(yk).astype(np.float32), quota, cloud)
             w_d = distdata.global_row_array(padr(w), quota, cloud)
@@ -2126,41 +2165,36 @@ class H2OSharedTreeEstimator(H2OEstimator):
             from ..runtime import phases as _phases_mod
 
             def _build_codes_dev():
-                codes_p = padr(bm.codes)
+                # the bin-code matrix is the biggest fixed H2D cost: it
+                # ships as 4/5/6-bit packed words wherever it can. Resident
+                # packing (fused path) KEEPS it packed in HBM, 2-4x smaller,
+                # and the tree kernels consume the words directly; the
+                # legacy/ungated path packs for the transfer only and widens
+                # on device. On a mesh the upload is ROW-SHARDED straight
+                # from HOST memory (packed word groups align with the 8-row
+                # shard grid): each chip receives only its slice — staging
+                # the whole matrix on one device and resharding would make
+                # per-chip HBM peak equal the GLOBAL matrix. A full-width
+                # sharded upload (rare: nbins>256 / dart / checkpoint on a
+                # mesh) ships unpacked: pack-for-transfer targets the single
+                # host↔device link and would stage everything on one chip.
                 rs_codes = (cloud.row_sharding() if ndev_eff > 1 else None)
-                if resident_bits:
-                    # fused path: ship packed AND keep it packed in HBM —
-                    # the resident matrix is 2-4× smaller and the tree
-                    # kernels consume the packed words directly. On a mesh
-                    # the artifact is ROW-SHARDED at build time, straight
-                    # from HOST memory (packed word groups align with the
-                    # 8-row shard grid): each chip receives only its
-                    # slice — staging the whole matrix on one device and
-                    # resharding would make per-chip HBM peak equal the
-                    # GLOBAL matrix, defeating the scale-out win.
-                    packed = _pack_host(codes_p, resident_bits)
-                    _phases_mod.add("h2d", 0.0, packed.nbytes)
+                widen = 0
+                with _tracing.span("design.pack", kind="fit"):
+                    host = padr(bm.codes)
+                    if resident_bits:
+                        host = _pack_host(host, resident_bits)
+                    elif rs_codes is None and host.dtype == np.uint8:
+                        widen = _pack_bits_for(nbins, host.shape[0])
+                        if widen:
+                            host = _pack_host(host, widen)
+                _phases_mod.add("h2d", 0.0, host.nbytes)
+                with _tracing.span("design.upload", kind="fit",
+                                   bytes_h2d=int(host.nbytes)):
                     if rs_codes is not None:
-                        return jax.device_put(packed, rs_codes)
-                    return jnp.asarray(packed)
-                if rs_codes is not None:
-                    # full-width sharded upload (rare: nbins>256 / dart /
-                    # checkpoint on a mesh): per-shard host→chip transfers;
-                    # the pack-for-transfer trick below targets the single
-                    # host↔device link and would stage everything on one chip
-                    _phases_mod.add("h2d", 0.0, codes_p.nbytes)
-                    return jax.device_put(codes_p, rs_codes)
-                pack_bits = (_pack_bits_for(nbins, codes_p.shape[0])
-                             if codes_p.dtype == np.uint8 else 0)
-                if pack_bits:
-                    # legacy/ungated path: the bin-code matrix is still the
-                    # biggest fixed H2D cost (host↔device link) — ship 4/5/6-
-                    # bit codes (half to 3/4 of the bytes), widen on device
-                    packed = _pack_host(codes_p, pack_bits)
-                    _phases_mod.add("h2d", 0.0, packed.nbytes)
-                    return _unpack_device(jnp.asarray(packed), pack_bits)
-                _phases_mod.add("h2d", 0.0, codes_p.nbytes)
-                return jnp.asarray(codes_p)
+                        return jax.device_put(host, rs_codes)
+                    dev = jnp.asarray(host)
+                    return _unpack_device(dev, widen) if widen else dev
 
             ooc_store = None
             if ooc_blocks:
@@ -2191,12 +2225,15 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 # packing mode AND the shard layout key the cache entry: a
                 # packed and a full-width consumer (or a 1-device and an
                 # 8-shard consumer) never share an artifact.
+                _ph.stage_span.annotate(cache="hit")
                 codes_d = _dsc.device_codes(
                     train, x, nbins, tp["histogram_type"], seed, npad,
-                    builder=_build_codes_dev, pack_bits=resident_bits,
-                    n_devices=ndev_eff)
+                    builder=_noting_miss(_ph, _build_codes_dev),
+                    pack_bits=resident_bits, n_devices=ndev_eff)
             else:
+                _ph.stage_span.annotate(cache="off")
                 codes_d = _build_codes_dev()
+            _ph.stage("design.state")
             if yk.size and bool(np.all((yk >= 0) & (yk <= 255)
                                        & (yk == np.floor(yk)))):
                 # integer-ish response (class indicators, counts): ship uint8
@@ -2385,6 +2422,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             valid_state = [codes_v, None, margins_v, n_v, y_dev_v, vmask_d]
 
         _ph.mark("device_put", sync=codes_d, then="fit.iterate")
+        _ph.stage("iterate.setup")
         key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
         ntrees_target = max(int(tp["ntrees"]) - n_prior, 0)
         gain_total = np.zeros(F, np.float64)
@@ -2445,16 +2483,9 @@ class H2OSharedTreeEstimator(H2OEstimator):
         lane_seq0 = cloudlib.lane_seq()
         # fit trace span: a dashboard reading /3/Trace sees how many chips
         # (and reduction blocks) this fit actually spanned
-        try:
-            from ..runtime import tracing as _tracing
-
-            _sp = _tracing.current()
-            if _sp is not None:
-                _sp.annotate(n_devices=ndev_eff, n_shards=cfg.n_shards,
-                             pack_bits=cfg.pack_bits,
-                             shard_mode=cfg.shard_mode)
-        except Exception:
-            pass
+        _ph.fit_span.annotate(n_devices=ndev_eff, n_shards=cfg.n_shards,
+                              pack_bits=cfg.pack_bits,
+                              shard_mode=cfg.shard_mode)
         # sharded fits score through the blocked deterministic loss (the
         # early-stop decision must be bit-stable across device counts);
         # unsharded fits keep the historical whole-array reduction
@@ -2822,6 +2853,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 margins, oob_sum, oob_cnt = spec_snap
                 spec = None
 
+        _ph.stage("iterate.dispatch")
         while m < ntrees_target:
             # QoS chunk-boundary yield: while a serving dispatch is in
             # flight the next chunk's programs hold back here. The wait
@@ -3053,6 +3085,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 ckpt_last_m = built
 
         _sup.fit_finished("tree")
+        _ph.stage("iterate.forest")
         if dart:
             # bake the per-round DART scales into the stored leaf values so
             # scoring / MOJO / TreeSHAP see ordinary trees (xgboost keeps a
@@ -3124,6 +3157,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         else:
             all_packed = np.zeros((0, K, treelib.heap_size(tp["max_depth"]), 6),
                                   np.float32)
+        _ph.stage("iterate.model")
         forest = None
         covers_by_class = None
         if packed_dev is None:
@@ -3299,8 +3333,6 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     from ..ops.histogram import attach_fit_skew
 
                     attach_fit_skew(plan_tag, skew)
-                    from ..runtime import tracing as _tracing
-
                     _tracing.event(
                         "collective_skew", fences=skew["fences"],
                         skew_p50_ms=skew["skew_p50_ms"],

@@ -112,6 +112,7 @@ def _row_feature_value(codes: jax.Array, rf: jax.Array) -> jax.Array:
     return jnp.where(feat_oh, codes.astype(jnp.int32), 0).sum(axis=1)
 
 
+@jax.named_scope("tree.leaf")
 def _leaf_totals(ids, vals3, nseg: int, axis_name, n_shard_blocks: int,
                  onehot_ok: bool):
     """Exact per-cell {Σw, Σg·w, Σh·w} totals of the final tree level —
@@ -167,8 +168,38 @@ def _node_totals(hist):
     return x[:, 0, 0], x[:, 0, 1], x[:, 0, 2]
 
 
+def _split_sums(hist):
+    """(WL, GL, HL, WR, GR, HR) of every candidate split of a histogram whose
+    last two axes are (bins, {w, g, h}): left of a split at bin b is the
+    forward cumsum through b, right of it the bins ABOVE b (the NA bin
+    included) folded from the right end — a reverse cumsum shifted by one.
+    The right-hand sums are never `total - left`: at 11M rows a node's
+    H ~ 2.75e6 has a float32 ulp of 0.25, so a tail child of 5-15 rows
+    (HR ~ 1-4) taken by subtraction is lost in the rounding of two sums
+    six orders larger, its gain GR^2/(HR+lambda) explodes or turns NaN and
+    the search splits 11 rows off the root. Folded from the right, a tail
+    child's sums are made of the few small bins it holds and carry their own
+    relative precision whatever the node's total is. Every split search
+    (`_fused_level_best`; `_flat_level_best`, which both `fused_split=False`
+    levels of `build_tree` call; `_search_splits`) reads this one function,
+    so they stay bit-identical."""
+    ax = hist.ndim - 2
+
+    def right(x):
+        above = jax.lax.cumsum(x, axis=ax, reverse=True)     # bins >= b
+        return jnp.concatenate(
+            [jax.lax.slice_in_dim(above, 1, None, axis=ax),
+             jnp.zeros_like(jax.lax.slice_in_dim(above, 0, 1, axis=ax))],
+            axis=ax)
+
+    w, g, h = hist[..., 0], hist[..., 1], hist[..., 2]
+    return (jnp.cumsum(w, axis=ax), jnp.cumsum(g, axis=ax),
+            jnp.cumsum(h, axis=ax), right(w), right(g), right(h))
+
+
+@jax.named_scope("tree.split")
 def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
-                      reg_lambda, reg_alpha, gsum, hsum, wsum,
+                      reg_lambda, reg_alpha, gsum, hsum,
                       monotone=None, lo_lvl=None, hi_lvl=None):
     """Single-pass split search (ISSUE 7 tentpole): ONE sequential pass
     over features computes each feature's (L, B) gain tile and folds it
@@ -191,7 +222,7 @@ def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
     admissible split exists, which the caller neutralizes via the
     do_split gate."""
     L, F, B = hist.shape[0], hist.shape[1], hist.shape[2]
-    G, H, W = gsum[:, None], hsum[:, None], wsum[:, None]   # (L, 1)
+    G, H = gsum[:, None], hsum[:, None]                      # (L, 1)
     tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
     Gt = tl1(G)
     base = Gt * Gt / (H + reg_lambda)                        # (L, 1)
@@ -201,10 +232,7 @@ def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
     def body(f, carry):
         best_g, best_f, best_b, vl_b, vr_b = carry
         hf = jax.lax.dynamic_index_in_dim(hist, f, axis=1, keepdims=False)
-        WL = jnp.cumsum(hf[..., 0], axis=1)                  # (L, B)
-        GL = jnp.cumsum(hf[..., 1], axis=1)
-        HL = jnp.cumsum(hf[..., 2], axis=1)
-        GR, HR, WR = G - GL, H - HL, W - WL
+        WL, GL, HL, WR, GR, HR = _split_sums(hf)             # (L, B) each
         GLt, GRt = tl1(GL), tl1(GR)
         gain = (GLt * GLt / (HL + reg_lambda)
                 + GRt * GRt / (HR + reg_lambda) - base)
@@ -240,6 +268,65 @@ def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
             jnp.zeros(L, jnp.int32), jnp.zeros(L, jnp.float32),
             jnp.zeros(L, jnp.float32))
     return jax.lax.fori_loop(0, F, body, init)
+
+
+@jax.named_scope("tree.split")
+def _flat_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
+                     reg_lambda, reg_alpha, gsum, hsum,
+                     monotone=None, lo_lvl=None, hi_lvl=None):
+    """The seed split search (``fused_split=False``, the
+    ``H2O3_TREE_LEGACY=1`` comparator) of a dense or a compact level: gain
+    per (L, F, B) from `_split_sums`, one flat argmax. Same arguments and
+    results as `_fused_level_best`, except that the child values are None
+    without `monotone`."""
+    L, F = hist.shape[0], hist.shape[1]
+    WL, GL, HL, WR, GR, HR = _split_sums(hist)
+    G = gsum[:, None, None]
+    H = hsum[:, None, None]
+    # xgboost CalcSplitGain: L1 soft-threshold the gradient sums
+    # before squaring (ThresholdL1); exact no-op at reg_alpha=0
+    tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
+    GLt, GRt, Gt = tl1(GL), tl1(GR), tl1(G)
+    gain = (
+        GLt * GLt / (HL + reg_lambda)
+        + GRt * GRt / (HR + reg_lambda)
+        - Gt * Gt / (H + reg_lambda)
+    )
+    ok = (WL >= min_rows) & (WR >= min_rows)
+    ok = ok & (jnp.arange(nbins)[None, None, :] < nbins - 1)   # no split at NA bin
+    ok = ok & (feat_mask[None, :, None] > 0)
+    ok = ok & node_ok[:, None, None]
+    if monotone is not None:
+        # monotone_constraints (hex/tree Constraints / LightGBM): a
+        # split on feature f with constraint c is admissible only
+        # when c·(value_right − value_left) ≥ 0, where the child
+        # values use the SAME soft-thresholded formula as
+        # materialized node values and are clamped into the node's
+        # inherited bounds. Bound propagation (in `build_tree`) then
+        # guarantees zero violations.
+        vL = jnp.clip(-GLt / (HL + reg_lambda + 1e-12),
+                      lo_lvl[:, None, None], hi_lvl[:, None, None])
+        vR = jnp.clip(-GRt / (HR + reg_lambda + 1e-12),
+                      lo_lvl[:, None, None], hi_lvl[:, None, None])
+        mc = monotone[None, :, None]
+        ok = ok & ((mc == 0) | (mc * (vR - vL) >= 0))
+    if keep is not None:
+        ok = ok & keep[:, :, None]
+    gain = jnp.where(ok, gain, -jnp.inf)
+
+    flat = gain.reshape(L, F * nbins)
+    best = jnp.argmax(flat, axis=1)
+    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+    bf = (best // nbins).astype(jnp.int32)
+    bb = (best % nbins).astype(jnp.int32)
+    vLs = vRs = None
+    if monotone is not None:
+        # child values at the chosen split, gathered from the SAME
+        # vL/vR used by the admissibility check (bound propagation)
+        flat_pick = lambda A: jnp.take_along_axis(
+            A.reshape(L, F * nbins), best[:, None], axis=1)[:, 0]
+        vLs, vRs = flat_pick(vL), flat_pick(vR)
+    return best_gain, bf, bb, vLs, vRs
 
 
 def value_at(table: jax.Array, idx: jax.Array) -> jax.Array:
@@ -419,69 +506,12 @@ def build_tree(
             keep = jax.random.uniform(sub, (L, F)) < rate
             keep = keep.at[:, 0].set(keep[:, 0] | ~keep.any(axis=1))  # >=1 kept
 
-        vLs = vRs = None
-        if fused_split:
-            best_gain, bf, bb, vLs, vRs = _fused_level_best(
-                hist, active, feat_mask, keep, nbins, min_rows, reg_lambda,
-                reg_alpha, gsum, hsum, wsum, monotone=monotone,
-                lo_lvl=lo_lvl if monotone is not None else None,
-                hi_lvl=hi_lvl if monotone is not None else None)
-        else:
-            # legacy split search: cumulative over bins → gain per (L, F, B)
-            cw = jnp.cumsum(hist[..., 0], axis=2)
-            cg = jnp.cumsum(hist[..., 1], axis=2)
-            ch = jnp.cumsum(hist[..., 2], axis=2)
-            GL, HL, WL = cg, ch, cw
-            G = gsum[:, None, None]
-            H = hsum[:, None, None]
-            W = wsum[:, None, None]
-            GR, HR, WR = G - GL, H - HL, W - WL
-            # xgboost CalcSplitGain: L1 soft-threshold the gradient sums
-            # before squaring (ThresholdL1); exact no-op at reg_alpha=0
-            tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
-            GLt, GRt, Gt = tl1(GL), tl1(GR), tl1(G)
-            gain = (
-                GLt * GLt / (HL + reg_lambda)
-                + GRt * GRt / (HR + reg_lambda)
-                - Gt * Gt / (H + reg_lambda)
-            )
-            ok = (WL >= min_rows) & (WR >= min_rows)
-            ok = ok & (jnp.arange(nbins)[None, None, :] < nbins - 1)   # no split at NA bin
-            ok = ok & (feat_mask[None, :, None] > 0)
-            ok = ok & active[:, None, None]
-            if monotone is not None:
-                # monotone_constraints (hex/tree Constraints / LightGBM): a
-                # split on feature f with constraint c is admissible only
-                # when c·(value_right − value_left) ≥ 0, where the child
-                # values use the SAME soft-thresholded formula as
-                # materialized node values and are clamped into the node's
-                # inherited bounds. Bound propagation (below) then
-                # guarantees zero violations.
-                gthrL = jnp.sign(GL) * jnp.maximum(jnp.abs(GL) - reg_alpha, 0.0)
-                gthrR = jnp.sign(GR) * jnp.maximum(jnp.abs(GR) - reg_alpha, 0.0)
-                vL = jnp.clip(-gthrL / (HL + reg_lambda + 1e-12),
-                              lo_lvl[:, None, None], hi_lvl[:, None, None])
-                vR = jnp.clip(-gthrR / (HR + reg_lambda + 1e-12),
-                              lo_lvl[:, None, None], hi_lvl[:, None, None])
-                mc = monotone[None, :, None]
-                ok = ok & ((mc == 0) | (mc * (vR - vL) >= 0))
-            if keep is not None:
-                ok = ok & keep[:, :, None]
-            gain = jnp.where(ok, gain, -jnp.inf)
-
-            flat = gain.reshape(L, F * nbins)
-            best = jnp.argmax(flat, axis=1)
-            best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-            bf = (best // nbins).astype(jnp.int32)
-            bb = (best % nbins).astype(jnp.int32)
-            if monotone is not None:
-                # child values at the chosen split, gathered from the SAME
-                # vL/vR used by the admissibility check (bound propagation)
-                sel = (bf * nbins + bb)[:, None]
-                flat_pick = lambda A: jnp.take_along_axis(
-                    A.reshape(L, F * nbins), sel, axis=1)[:, 0]
-                vLs = flat_pick(vL)
-                vRs = flat_pick(vR)
+        search = _fused_level_best if fused_split else _flat_level_best
+        best_gain, bf, bb, vLs, vRs = search(
+            hist, active, feat_mask, keep, nbins, min_rows, reg_lambda,
+            reg_alpha, gsum, hsum, monotone=monotone,
+            lo_lvl=lo_lvl if monotone is not None else None,
+            hi_lvl=hi_lvl if monotone is not None else None)
         do_split = best_gain > jnp.maximum(min_split_improvement, 1e-10)
         gain_per_feature = gain_per_feature + jax.ops.segment_sum(
             jnp.where(do_split, best_gain, 0.0).astype(jnp.float32), bf, num_segments=F
@@ -501,18 +531,19 @@ def build_tree(
         # partition rows: decided-leaf rows flow left; splitters route by
         # code. All per-row lookups are one-hot contractions (L and F are
         # small) — a take_along_axis gather here costs ~10× more VPU time.
-        rf = _lookup_int(bf, idx, L)
-        rb = _lookup_int(bb, idx, L)
-        rs = _lookup_bool(do_split, idx, L)
-        if pack_bits:
-            # the row's selected-feature code straight from the packed
-            # words: two byte gathers + a shift per row, O(N) instead of
-            # the O(N·F) one-hot contraction over full-width codes
-            rcode = packing.packed_row_values(codes, rf, pack_bits)
-        else:
-            rcode = _row_feature_value(codes, rf)
-        go_right = (rcode > rb) & rs
-        idx = 2 * idx + go_right.astype(jnp.int32)
+        with jax.named_scope("tree.partition"):
+            rf = _lookup_int(bf, idx, L)
+            rb = _lookup_int(bb, idx, L)
+            rs = _lookup_bool(do_split, idx, L)
+            if pack_bits:
+                # the row's selected-feature code straight from the packed
+                # words: two byte gathers + a shift per row, O(N) instead
+                # of the O(N·F) one-hot contraction over full-width codes
+                rcode = packing.packed_row_values(codes, rf, pack_bits)
+            else:
+                rcode = _row_feature_value(codes, rf)
+            go_right = (rcode > rb) & rs
+            idx = 2 * idx + go_right.astype(jnp.int32)
         if row_leaf is not None:
             row_leaf = jnp.where(rs, (2 ** (d + 1) - 1) + idx, row_leaf)
         active = jnp.repeat(do_split, 2)
@@ -610,36 +641,10 @@ def build_tree(
             rate = mtries_rate if mtries_rate is not None else (mtries / F)
             keep = jax.random.uniform(sub, (CAP + 1, F)) < rate
             keep = keep.at[:, 0].set(keep[:, 0] | ~keep.any(axis=1))
-        if fused_split:
-            best_gain, bf, bb, _, _ = _fused_level_best(
-                slot_hist, valid, feat_mask, keep, nbins, min_rows,
-                reg_lambda, reg_alpha, gsum, hsum, wsum)
-        else:
-            cw = jnp.cumsum(slot_hist[..., 0], axis=2)
-            cg = jnp.cumsum(slot_hist[..., 1], axis=2)
-            ch = jnp.cumsum(slot_hist[..., 2], axis=2)
-            GL, HL, WL = cg, ch, cw
-            G = gsum[:, None, None]
-            H = hsum[:, None, None]
-            W = wsum[:, None, None]
-            GR, HR, WR = G - GL, H - HL, W - WL
-            tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
-            GLt, GRt, Gt = tl1(GL), tl1(GR), tl1(G)
-            gain = (GLt * GLt / (HL + reg_lambda)
-                    + GRt * GRt / (HR + reg_lambda)
-                    - Gt * Gt / (H + reg_lambda))
-            ok = (WL >= min_rows) & (WR >= min_rows)
-            ok = ok & (jnp.arange(nbins)[None, None, :] < nbins - 1)
-            ok = ok & (feat_mask[None, :, None] > 0)
-            ok = ok & valid[:, None, None]
-            if keep is not None:
-                ok = ok & keep[:, :, None]
-            gain = jnp.where(ok, gain, -jnp.inf)
-            flat = gain.reshape(CAP + 1, F * nbins)
-            best = jnp.argmax(flat, axis=1)
-            best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-            bf = (best // nbins).astype(jnp.int32)
-            bb = (best % nbins).astype(jnp.int32)
+        search = _fused_level_best if fused_split else _flat_level_best
+        best_gain, bf, bb, _, _ = search(
+            slot_hist, valid, feat_mask, keep, nbins, min_rows, reg_lambda,
+            reg_alpha, gsum, hsum)
         do = best_gain > jnp.maximum(min_split_improvement, 1e-10)
         gain_per_feature = gain_per_feature + jax.ops.segment_sum(
             jnp.where(do, best_gain, 0.0).astype(jnp.float32), bf,
@@ -655,14 +660,15 @@ def build_tree(
 
         # partition rows (plain gathers: CAP-wide tables, N small)
         do = do & valid
-        rs_do = do[row_slot]
-        bf_r = bf[row_slot]
-        bb_r = bb[row_slot]
-        if pack_bits:
-            rcode = packing.packed_row_values(codes, bf_r, pack_bits)
-        else:
-            rcode = _row_feature_value(codes, bf_r)
-        go_right = (rcode > bb_r) & rs_do
+        with jax.named_scope("tree.partition"):
+            rs_do = do[row_slot]
+            bf_r = bf[row_slot]
+            bb_r = bb[row_slot]
+            if pack_bits:
+                rcode = packing.packed_row_values(codes, bf_r, pack_bits)
+            else:
+                rcode = _row_feature_value(codes, bf_r)
+            go_right = (rcode > bb_r) & rs_do
         child_local = 2 * slot_node[row_slot] + go_right.astype(jnp.int32)
         row_leaf = jnp.where(rs_do, (2 ** (d + 1) - 1) + child_local,
                              row_leaf)
@@ -726,17 +732,15 @@ def build_tree(
     )
 
 
+@jax.named_scope("tree.split")
 def _search_splits(hist, feat_mask, nbins, min_rows, reg_lambda, reg_alpha):
     """Best (gain, feat, bin) per node for an (L, F, B, 3) histogram —
     the split search of `build_tree` without the level-wise bookkeeping
     (`hex/tree/DTree.Split.findBestSplitPoint`; xgboost EvaluateSplits)."""
     L, F = hist.shape[0], hist.shape[1]
     wsum, gsum, hsum = _node_totals(hist)
-    GL = jnp.cumsum(hist[..., 1], axis=2)
-    HL = jnp.cumsum(hist[..., 2], axis=2)
-    WL = jnp.cumsum(hist[..., 0], axis=2)
-    G, H, W = (a[:, None, None] for a in (gsum, hsum, wsum))
-    GR, HR, WR = G - GL, H - HL, W - WL
+    WL, GL, HL, WR, GR, HR = _split_sums(hist)
+    G, H = gsum[:, None, None], hsum[:, None, None]
     # xgboost CalcSplitGain: L1 soft-threshold before squaring (ThresholdL1)
     tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
     GLt, GRt, Gt = tl1(GL), tl1(GR), tl1(G)

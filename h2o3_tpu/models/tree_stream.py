@@ -197,7 +197,7 @@ def _level_decide_jit(hist, active, feat_mask, keep, edges, hp, gain_pf,
     node_val = jnp.clip(node_val, -hp[7], hp[7])
     best_gain, bf, bb, _, _ = _fused_level_best(
         hist, active, feat_mask, keep if has_keep else None, nbins,
-        hp[0], hp[2], hp[3], gsum, hsum, wsum)
+        hp[0], hp[2], hp[3], gsum, hsum)
     do_split = best_gain > jnp.maximum(hp[1], 1e-10)
     gain_pf = gain_pf + jax.ops.segment_sum(
         jnp.where(do_split, best_gain, 0.0).astype(jnp.float32), bf,

@@ -137,6 +137,7 @@ def build_histograms_pallas_factored(
         scratch_shapes=[pltpu.VMEM((3 * L, R), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="tree_hist_factored",
     )(codes_t_bf, node2, vals)
     # (Fpad/8, 3L, 8B) → (Fpad, 3L, B) → (L, F, B, 3)
     out = out.reshape(Fpad // _FB, 3 * L, _FB, B).transpose(0, 2, 1, 3)
@@ -206,6 +207,7 @@ def build_histograms_pallas(
         out_specs=pl.BlockSpec((F, 3, LB), lambda i: (0, 0, 0)),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="tree_hist",
     )(codes_t, cid_base, vals)
     # (F, 3, LB) → (n_nodes, F, nbins, 3)
     return out.reshape(F, 3, n_nodes, nbins).transpose(2, 0, 3, 1)

@@ -547,6 +547,7 @@ def _packed_row_slice(codes, r0: int, r1: int, pack_bits: int):
     return codes[r0 // group * gbytes: r1 // group * gbytes]
 
 
+@jax.named_scope("tree.hist")
 def build_histograms(
     codes: jax.Array,
     node_id: jax.Array,

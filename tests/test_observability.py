@@ -438,6 +438,28 @@ def test_fit_leaves_one_span_tree(cloud1, algo):
         assert in_metrics == ["metrics.d2h", "metrics.order", "metrics.auc",
                               "metrics.roc", "metrics.gains"]
         assert by_name["metrics.order"]["attrs"]["path"] == "packed32"
+    else:
+        # the tree fit's stages tile `fit.design` and `fit.iterate`
+        stages = {"fit.design": ["design.matrix", "design.bins",
+                                 "design.vectors", "design.codes",
+                                 "design.state"],
+                  "fit.iterate": ["iterate.setup", "iterate.dispatch",
+                                  "iterate.forest", "iterate.model"]}
+        root = by_name["train"]
+        for parent, names in stages.items():
+            kids = _children(spans, by_name[parent])
+            assert [s["name"] for s in kids] == names
+            bare = by_name[parent]["duration_s"] - sum(
+                s["duration_s"] for s in kids)
+            assert 0 <= bare < 0.05 * root["duration_s"]
+        for name in ("design.matrix", "design.bins", "design.codes"):
+            assert by_name[name]["attrs"]["cache"] in ("hit", "miss")
+        if by_name["design.codes"]["attrs"]["cache"] == "miss":
+            codes = _children(spans, by_name["design.codes"])
+            assert [s["name"] for s in codes] == ["design.pack",
+                                                  "design.upload"]
+            assert codes[1]["attrs"]["bytes_h2d"] > 0
+        assert by_name["fit.iterate"]["attrs"]["n_devices"] == 1
     # no span per iteration, level or tree: a fit is a handful of spans
     assert len([s for s in spans if s["kind"] == "fit"]) <= 20
 
