@@ -2468,15 +2468,18 @@ class H2OSharedTreeEstimator(H2OEstimator):
         if cfg.grow_policy != "lossguide":
             plan_levels = treelib.histogram_level_plan(cfg.max_depth,
                                                       cfg.compact_cap)
+            plan_read = treelib.partition_read(F)
         else:
             plan_levels = [("lossguide_node", 1)]
+            plan_read = None
         plan_tag = (f"{getattr(self, 'algo', self._mode)}:{K}x{tp['ntrees']}t"
                     f"_d{cfg.max_depth}")
         _record_fit_plan(
             plan_tag, plan_levels, nbins, cfg.hist_method,
             pack_bits=cfg.pack_bits,
             axis_name=cloudlib.ROWS_AXIS if ndev_eff > 1 else None,
-            n_shards=cfg.n_shards, n_devices=ndev_eff)
+            n_shards=cfg.n_shards, n_devices=ndev_eff,
+            partition_read=plan_read)
         # per-lane collective skew of THIS fit (ISSUE 13): fences recorded
         # after this sequence point belong to this fit (training is
         # serialized on meshes via training_guard)

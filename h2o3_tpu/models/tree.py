@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import packing
-from ..ops.histogram import build_histograms, ordered_axis_fold
+from ..ops.histogram import (build_histograms, feature_major,
+                             ordered_axis_fold, record_partition_read)
 
 
 class Tree(NamedTuple):
@@ -100,16 +101,42 @@ def _lookup_bool(table: jax.Array, idx: jax.Array, L: int) -> jax.Array:
     return (oh & table[None, :]).any(axis=1)
 
 
-def _row_feature_value(codes: jax.Array, rf: jax.Array) -> jax.Array:
-    """codes[i, rf[i]] as int32 — the row-wise feature pick of the
-    partition step, as a one-hot contraction over the F axis (O(N·F), so
-    only for narrow frames; wide frames keep the gather)."""
+def partition_read(n_features: int) -> str:
+    """How a partition step reads a row's split-feature code at this frame
+    width: ``"select"`` (dense one-hot over the feature axis) or
+    ``"gather"`` (per-row gather, frames wider than `_ONEHOT_LOOKUP_MAX`).
+    The ONE rule, shared by `_row_codes` and the driver's per-fit plan
+    (`ops.histogram.record_fit_plan`), so the recorded read cannot diverge
+    from the one that runs."""
+    return "gather" if n_features > _ONEHOT_LOOKUP_MAX else "select"
+
+
+def _row_codes(codes: jax.Array, rf: jax.Array, pack_bits: int = 0) -> jax.Array:
+    """codes[i, rf[i]] as int32 — the code of the feature row i's node
+    split on; the one place every partition step and training-time walk
+    over packed codes asks for it.
+
+    Packed codes (`pack_bits` in {4, 5, 6}) are widened first. Narrow
+    frames then select densely over the feature axis of
+    `ops.histogram.feature_major`, the (F, N) float32 buffer the Pallas
+    histogram kernel takes: the program builds it once and every level's
+    select is one more streaming read of it. Nothing gathers into the code
+    matrix: on the TPU a per-row gather costs ~20 ns a row (228.6 ms at
+    11.5M rows) where the compare-select-sum is bound by bytes.
+
+    Frames wider than `_ONEHOT_LOOKUP_MAX` keep the gather, from the
+    widened codes (`partition_read`)."""
+    if pack_bits:
+        codes = packing.unpack_device(codes, pack_bits)
     F = codes.shape[1]
-    if F > _ONEHOT_LOOKUP_MAX:
+    read = partition_read(F)
+    record_partition_read(read)
+    if read == "gather":
         return jnp.take_along_axis(
             codes, rf[:, None].astype(jnp.int32), axis=1)[:, 0].astype(jnp.int32)
-    feat_oh = rf[:, None] == jnp.arange(F, dtype=jnp.int32)[None, :]
-    return jnp.where(feat_oh, codes.astype(jnp.int32), 0).sum(axis=1)
+    feat_oh = rf[None, :] == jnp.arange(F, dtype=jnp.int32)[:, None]
+    return jnp.where(feat_oh, feature_major(codes), 0.0).sum(
+        axis=0).astype(jnp.int32)
 
 
 @jax.named_scope("tree.leaf")
@@ -408,8 +435,9 @@ def build_tree(
     pack_bits in {4, 5, 6} means `codes` is the `ops.packing` packed word
     matrix: histogram kernels consume it (per-chunk unpack — the host/CPU
     path never widens; in-graph kernels widen once per program) and the
-    partition step reads each row's selected-feature code straight from
-    the packed words (`packed_row_values`, two byte gathers per row).
+    partition step reads each row's selected-feature code from the same
+    widened codes by a dense select over the feature axis (`_row_codes`;
+    no gather into the code matrix at F <= `_ONEHOT_LOOKUP_MAX`).
 
     fused_split=True switches the per-level split search to the
     single-pass scan-argmax (`_fused_level_best`, bit-exact with the
@@ -530,18 +558,13 @@ def build_tree(
 
         # partition rows: decided-leaf rows flow left; splitters route by
         # code. All per-row lookups are one-hot contractions (L and F are
-        # small) — a take_along_axis gather here costs ~10× more VPU time.
+        # small, packed codes included) — a per-row gather here costs ~10×
+        # more VPU time.
         with jax.named_scope("tree.partition"):
             rf = _lookup_int(bf, idx, L)
             rb = _lookup_int(bb, idx, L)
             rs = _lookup_bool(do_split, idx, L)
-            if pack_bits:
-                # the row's selected-feature code straight from the packed
-                # words: two byte gathers + a shift per row, O(N) instead
-                # of the O(N·F) one-hot contraction over full-width codes
-                rcode = packing.packed_row_values(codes, rf, pack_bits)
-            else:
-                rcode = _row_feature_value(codes, rf)
+            rcode = _row_codes(codes, rf, pack_bits)
             go_right = (rcode > rb) & rs
             idx = 2 * idx + go_right.astype(jnp.int32)
         if row_leaf is not None:
@@ -664,10 +687,7 @@ def build_tree(
             rs_do = do[row_slot]
             bf_r = bf[row_slot]
             bb_r = bb[row_slot]
-            if pack_bits:
-                rcode = packing.packed_row_values(codes, bf_r, pack_bits)
-            else:
-                rcode = _row_feature_value(codes, bf_r)
+            rcode = _row_codes(codes, bf_r, pack_bits)
             go_right = (rcode > bb_r) & rs_do
         child_local = 2 * slot_node[row_slot] + go_right.astype(jnp.int32)
         row_leaf = jnp.where(rs_do, (2 ** (d + 1) - 1) + child_local,
@@ -945,8 +965,8 @@ def predict_codes_packed(tree: Tree, packed: jax.Array, bits: int,
                          max_depth: int) -> jax.Array:
     """Leaf value per row, traversing straight on the `ops.packing` packed
     word matrix (the streamed/GOSS margin-update path, ISSUE 14): each
-    level reads the row's split-feature code via `packed_row_values` (two
-    byte gathers + a shift) instead of widening the block. With bits=0
+    level reads the row's split-feature code via `_row_codes` (the block
+    widened once per program, a dense select per level). With bits=0
     `packed` is a full-width code matrix and this is `predict_codes`."""
     if not bits:
         return predict_codes(tree, packed, max_depth)
@@ -956,7 +976,7 @@ def predict_codes_packed(tree: Tree, packed: jax.Array, bits: int,
         f = tree.feat[node]
         b = tree.bin[node]
         s = tree.is_split[node]
-        c = packing.packed_row_values(packed, f, bits)
+        c = _row_codes(packed, f, bits)
         child = 2 * node + 1 + ((c > b) & s).astype(jnp.int32)
         node = jnp.where(s, child, node)
     return tree.value[node]

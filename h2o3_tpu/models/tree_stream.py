@@ -13,8 +13,8 @@ partials with the same deterministic left-to-right f32 fold
 Bit-exactness contract: every computation here reuses the in-core path's
 own building blocks — `ops.histogram.run_block_kernel` (each partial is
 exactly one block of the blocked in-core reduction), `_fused_level_best`
-(the single-pass split search), `_lookup_int`/`packed_row_values` (the
-partition gathers), `value_at` (the margin update) and the `_one_tree`
+(the single-pass split search), `_lookup_int`/`_row_codes` (the
+partition reads), `value_at` (the margin update) and the `_one_tree`
 RNG-key derivation chain — so a streamed fit with sampling OFF is
 BIT-IDENTICAL to the in-core fit sharing its block count S (pinned in
 tests/test_tree_stream.py: forest, varimp, scoring history, early-stop
@@ -54,7 +54,7 @@ from ..ops.histogram import (host_hist_direct, ordered_axis_fold,
 from . import distributions as dist_mod
 from . import tree as treelib
 from .tree import (_ONEHOT_LOOKUP_MAX, _fused_level_best, _lookup_bool,
-                   _lookup_int, _row_feature_value, heap_size)
+                   _lookup_int, _row_codes, heap_size)
 
 # -- jitted pieces ----------------------------------------------------------
 #
@@ -105,15 +105,12 @@ def _scale_jit(hp, m):
 
 def _partition(codes_b, idx_b, bf, bb, do_split, L: int, pack_bits: int):
     """One block's row partition under a level decision — the build_tree
-    partition gathers, verbatim (block-local packed reads are exact:
-    block boundaries sit on pack-group boundaries)."""
+    partition reads, verbatim (a packed block widens standalone: block
+    boundaries sit on pack-group boundaries)."""
     rf = _lookup_int(bf, idx_b, L)
     rb = _lookup_int(bb, idx_b, L)
     rs = _lookup_bool(do_split, idx_b, L)
-    if pack_bits:
-        rcode = packing.packed_row_values(codes_b, rf, pack_bits)
-    else:
-        rcode = _row_feature_value(codes_b, rf)
+    rcode = _row_codes(codes_b, rf, pack_bits)
     go_right = (rcode > rb) & rs
     return 2 * idx_b + go_right.astype(jnp.int32)
 

@@ -20,7 +20,10 @@ Packed-code input (ISSUE 7): the device-RESIDENT matrix is the 4/5/6-bit
 `ops.packing` word matrix; `build_histograms` widens it IN-GRAPH before
 these kernels, once per compiled tree program (XLA CSEs the widen across
 every level's pass — only a program-lifetime transient is full-width, the
-resident/cached/uploaded artifact stays packed). In-KERNEL sub-byte
+resident/cached/uploaded artifact stays packed). The factored kernel's
+operand (`ops.histogram.feature_major`) has a second reader in the
+same program: the partition step's dense select of each row's
+split-feature code (`models/tree._row_codes`). In-KERNEL sub-byte
 decode was evaluated and deferred: the factored kernel reads codes as
 8-sublane f32 feature blocks, while Mosaic's int8 minimum tile is
 (32, 128) — a u8 packed operand would force a 32-feature block

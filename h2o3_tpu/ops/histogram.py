@@ -133,6 +133,12 @@ def _sel_registry() -> dict:
             "fit-plan levels (per fit, per level) whose pallas_factored "
             "selection fell back to the segment path because no VMEM row "
             "chunk >= 512 fits")
+        _SEL_REG["partition_read"] = _reg.counter(
+            "h2o3_tree_partition_read",
+            "partition-step reads of a row's split-feature code by kind "
+            "(trace-time): select = dense one-hot over the feature axis, "
+            "gather = per-row gather (frames wider than the one-hot limit)",
+            labelnames=("read",))
     return _SEL_REG
 
 
@@ -197,17 +203,31 @@ def _record_selection(sel: dict, vmem: bool = False) -> None:
         pass
 
 
+def record_partition_read(read: str) -> None:
+    """Count one traced partition-step read of a row's split-feature code
+    (`tree._row_codes`): "select" or "gather". Trace-time, like `dispatch`
+    — a warm fit re-traces nothing and counts nothing; the per-fit answer
+    is the plan's `partition_read`."""
+    try:
+        _sel_registry()["partition_read"].inc(1.0, read)
+    except Exception:
+        pass
+
+
 def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
                     pack_bits: int = 0, axis_name: Optional[str] = None,
                     platform: Optional[str] = None, n_shards: int = 0,
-                    n_devices: int = 1) -> dict:
+                    n_devices: int = 1,
+                    partition_read: Optional[str] = None) -> dict:
     """Resolve + record the per-level kernel plan of one tree fit.
 
     `levels` is a sequence of (label, n_nodes) histogram passes the fit
     will run. Logs ONE warning per fit when any level hits the VMEM
     pressure fallback (the previously-silent `_factored_row_chunk` < 512
     path), counts every level's selection in the registry, and keeps the
-    plan in a bounded ring surfaced at /3/Profiler."""
+    plan in a bounded ring surfaced at /3/Profiler. `partition_read` is
+    how the fit's levels read a row's split-feature code
+    (`tree.partition_read`; None for a fit without a level partition)."""
     import time as _time
 
     plan_levels = []
@@ -222,7 +242,7 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     plan = dict(tag=tag, ts=_time.time(), nbins=int(nbins),
                 hist_method=hist_method, pack_bits=int(pack_bits),
                 n_shards=int(n_shards), n_devices=int(n_devices),
-                levels=plan_levels)
+                partition_read=partition_read, levels=plan_levels)
     if fellback:
         from ..runtime.log import Log
 
@@ -266,11 +286,12 @@ def kernel_stats() -> dict:
     `tree` fold). Pure counter read."""
     with _SEL_LOCK:
         plans = list(_FIT_PLANS)
-    out = dict(plans=plans, dispatch={}, vmem_fallbacks=0)
+    out = dict(plans=plans, dispatch={}, partition_read={}, vmem_fallbacks=0)
     try:
         reg = _sel_registry()
-        out["dispatch"] = {lv[0]: c.value()
-                           for lv, c in reg["dispatch"].children().items()}
+        for fam in ("dispatch", "partition_read"):
+            out[fam] = {lv[0]: c.value()
+                        for lv, c in reg[fam].children().items()}
         out["vmem_fallbacks"] = reg["vmem_fallbacks"].value()
     except Exception:
         pass
@@ -505,6 +526,16 @@ def ordered_axis_fold(parts: jax.Array, axis_name: Optional[str],
     return acc
 
 
+def feature_major(codes: jax.Array) -> jax.Array:
+    """Full-width (N, F) codes as the feature-major float32 (F, N) array,
+    rows on the lanes (bin codes are exact in float32). The factored
+    Pallas kernel's operand and the partition step's select
+    (`models/tree._row_codes`) both take it from HERE: one expression of
+    the program's loop-invariant codes, so XLA builds the buffer once per
+    program and every reader streams the same one."""
+    return codes.T.astype(jnp.float32)
+
+
 def _run_kernel(sel: dict, codes, node_id, vals, n_nodes: int, nbins: int,
                 pack_bits: int):
     """One resolved kernel invocation over one contiguous row range."""
@@ -531,7 +562,7 @@ def _run_kernel(sel: dict, codes, node_id, vals, n_nodes: int, nbins: int,
         from . import hist_pallas
 
         return hist_pallas.build_histograms_pallas_factored(
-            codes.T.astype(jnp.float32), node_id, vals, n_nodes, nbins,
+            feature_major(codes), node_id, vals, n_nodes, nbins,
             row_chunk=sel["row_chunk"],
         )
     raise ValueError(f"unknown histogram method {method!r}")

@@ -21,15 +21,19 @@ bits   rows/group  bytes/group  bitstream
 
 Consumers:
 
-* ``unpack_device`` — whole-matrix widening on device (the legacy
-  ``H2O3_TREE_LEGACY=1`` path: ship packed, materialize full width once).
+* ``unpack_device`` — whole-matrix widening on device: the in-graph
+  histogram kernels and the partition step (below) evaluate it inside the
+  tree program; the legacy ``H2O3_TREE_LEGACY=1`` path ships packed and
+  materializes full width once.
 * ``ops/histogram.py`` — the host callback path unpacks in numpy per
   64k-row chunk (the full-width matrix never exists); in-graph kernels
   widen once per jitted tree program (a program-lifetime transient — the
   resident matrix stays packed).
-* ``packed_row_values`` — the partition step's per-row selected-feature
-  code, extracted straight from the packed words (two byte gathers + a
-  shift per row).
+* ``models/tree._row_codes`` — the partition step's per-row
+  selected-feature code: a dense select over the feature axis of the SAME
+  widened codes (`unpack_device`, shared with the histogram kernels of the
+  program). Nothing gathers into the packed words: on the TPU a per-row
+  gather costs ~20 ns a row, the select is a streaming read.
 """
 
 from __future__ import annotations
@@ -165,28 +169,3 @@ def unpack_device(packed, bits: int):
     k = packed.shape[0] // 3
     out = jnp.stack([a, b, c, d], axis=1).reshape((4 * k,) + packed.shape[1:])
     return out.astype(jnp.uint8)
-
-
-def packed_row_values(packed: jax.Array, rf: jax.Array, bits: int) -> jax.Array:
-    """codes[i, rf[i]] as int32, read straight from the packed words —
-    the per-row selected-feature code of the partition step.
-
-    A row's code spans at most two adjacent bytes of its group's
-    bitstream; two flat gathers + one shift recover it exactly. When the
-    code sits entirely in byte0 the second gather (clamped in-bounds) is
-    shifted out, so no group ever reads past its own bytes."""
-    P, F = packed.shape
-    rows_per = GROUP_ROWS[bits]
-    bytes_per = GROUP_BYTES[bits]
-    n = P * 8 // bits
-    i = jnp.arange(n, dtype=jnp.int32)
-    grp = i // rows_per
-    bit0 = (i % rows_per) * bits
-    b0 = grp * bytes_per + bit0 // 8
-    off = bit0 % 8
-    b1 = jnp.minimum(b0 + 1, P - 1)
-    flat = packed.reshape(-1).astype(jnp.int32)
-    rfi = rf.astype(jnp.int32)
-    v0 = flat[b0 * F + rfi]
-    v1 = flat[b1 * F + rfi]
-    return (((v0 << 8) | v1) >> (16 - bits - off)) & ((1 << bits) - 1)

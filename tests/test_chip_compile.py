@@ -134,7 +134,13 @@ def test_tree_step_compiles_for_v5e(one_chip):
         _sds((npad,), f32, s), _sds((npad,), f32, s),
         _sds((F, NBINS - 2), f32, s), _sds((F,), f32, s), _sds((9,), f32, s),
         _sds((2,), jnp.uint32, s), _sds((), jnp.int32, s)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 6
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 6
+    # the partition selects over the widened codes the kernel takes: in
+    # the chip's program no gather of the partition step survives, nor
+    # the flat int32 copy of the packed words it used to gather from
+    assert "tree.partition/gather" not in text
+    assert f"s32[{packed_rows * F}]" not in text
 
 
 # -- interpret-mode numerics on the CPU ---------------------------------------

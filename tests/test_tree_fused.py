@@ -55,16 +55,29 @@ def _leaves_equal(a, b):
 
 # -- ops: packed consumption ------------------------------------------------
 
-def test_packed_row_values_exact():
-    rng = np.random.default_rng(0)
-    N, F = 4096, 7
-    for bits, B in ((4, 16), (5, 21), (6, 33)):
-        codes = rng.integers(0, B, (N, F)).astype(np.uint8)
-        pk = packing.pack_host(codes, bits)
-        rf = rng.integers(0, F, N).astype(np.int32)
-        got = np.asarray(packing.packed_row_values(
-            jnp.asarray(pk), jnp.asarray(rf), bits))
-        assert np.array_equal(got, codes[np.arange(N), rf])
+@pytest.mark.parametrize("F", [7, 130])
+@pytest.mark.parametrize("bits,B", [(0, 21), (4, 16), (5, 21), (6, 33)])
+def test_row_codes_exact(bits, B, F):
+    """`_row_codes` — the one reader of a row's split-feature code — is
+    exact on full-width and on every packed width, through the dense
+    select (F = 7) and through the wide-frame gather (F = 130)."""
+    rng = np.random.default_rng(bits * 1000 + F)
+    N = 4096
+    codes = rng.integers(0, B, (N, F)).astype(np.uint8)
+    resident = packing.pack_host(codes, bits) if bits else codes
+    rf = rng.integers(0, F, N).astype(np.int32)
+    got = treelib._row_codes(jnp.asarray(resident), jnp.asarray(rf), bits)
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got), codes[np.arange(N), rf])
+
+
+@pytest.mark.parametrize("F,read", [(1, "select"), (28, "select"),
+                                    (128, "select"), (129, "gather")])
+def test_partition_read_rule(F, read):
+    """The rule `_row_codes` and the per-fit plan share: dense select up
+    to `_ONEHOT_LOOKUP_MAX` features, gather beyond."""
+    assert treelib._ONEHOT_LOOKUP_MAX == 128
+    assert treelib.partition_read(F) == read
 
 
 def test_host_histogram_bitexact_with_segment_packed_and_dense():
@@ -94,11 +107,19 @@ def test_host_histogram_bitexact_with_segment_packed_and_dense():
 
 @pytest.mark.parametrize("variant", [
     "fused", "packed", "packed_fused", "mtries", "monotone",
-    "alpha_lambda0",
+    "alpha_lambda0", "bits4", "bits5", "bits6", "f7", "f130", "compact",
 ])
 def test_build_tree_fused_packed_parity(variant):
-    codes, g, h, w, fm, edges, B = _tree_data()
+    # the packed variants hold `_row_codes` inside the level loop: every
+    # pack width, a narrow frame (the dense select), a frame over
+    # `_ONEHOT_LOOKUP_MAX` (the gather branch) and the compact levels
+    shape = {"bits4": dict(B=16), "bits5": dict(B=21), "bits6": dict(B=33),
+             "f7": dict(F=7), "f130": dict(F=130, N=1024),
+             "compact": dict(B=16)}.get(variant, {})
+    codes, g, h, w, fm, edges, B = _tree_data(**shape)
     bits = packing.pack_bits_for(B, codes.shape[0])
+    if variant.startswith("bits"):
+        assert bits == int(variant[4:])
     pk = packing.pack_host(codes, bits)
     key = jax.random.PRNGKey(3)
     kw = dict(max_depth=4, nbins=B, min_rows=5.0, key=key)
@@ -110,6 +131,9 @@ def test_build_tree_fused_packed_parity(variant):
         kw["monotone"] = jnp.asarray(mono)
     if variant == "alpha_lambda0":
         kw.update(reg_lambda=0.0, reg_alpha=0.5)   # NaN-prone gains
+    if variant == "compact":
+        # levels 6 and 7 partition in compact slots (`bf[row_slot]` reads)
+        kw.update(max_depth=8, min_rows=1.0, compact_cap=32)
     base = treelib.build_tree(jnp.asarray(codes), g, h, w, fm, edges, **kw)
     fused_kw = dict(kw, fused_split=True)
     if variant != "fused":
@@ -119,6 +143,43 @@ def test_build_tree_fused_packed_parity(variant):
         got = treelib.build_tree(jnp.asarray(codes), g, h, w, fm, edges,
                                  **fused_kw)
     assert _leaves_equal(base, got)
+
+
+def _row_gathers_over(text, numels):
+    """The `stablehlo.gather`s of a lowered program that read ONE element
+    per index from an operand of `numels` elements — a per-row read of the
+    code matrix (packed words or widened codes). The packed widen's own
+    strided row slices are gathers too, of whole F-wide rows: not these."""
+    import re
+
+    hits = []
+    for line in text.splitlines():
+        if "stablehlo.gather" not in line:
+            continue
+        sizes = re.search(r"slice_sizes = array<i64: ([0-9, ]+)>", line)
+        operand = re.search(r": \(tensor<([0-9x]+)x[a-z]+[0-9]+>", line)
+        numel = int(np.prod([int(d) for d in operand.group(1).split("x")]))
+        per_index = int(np.prod([int(d) for d in sizes.group(1).split(",")]))
+        if numel in numels and per_index == 1:
+            hits.append(line.strip()[:160])
+    return hits
+
+
+@pytest.mark.parametrize("F,gathers", [(28, False), (130, True)])
+def test_packed_partition_issues_no_gather_into_the_code_matrix(F, gathers):
+    """The guard against the gather's quiet return: the tree program at
+    the flagship's structure (5-bit packed codes, F = 28, fused split
+    search, depth 6) reads a row's code by the dense select — no gather
+    whose operand is the code matrix, packed or widened — while a frame
+    over `_ONEHOT_LOOKUP_MAX` still gathers."""
+    N, B, depth = 4096, 21, 6
+    codes, g, h, w, fm, edges, _ = _tree_data(N=N, F=F, B=B)
+    pk = packing.pack_host(codes, 5)
+    text = treelib.build_tree.lower(
+        jnp.asarray(pk), g, h, w, fm, edges, max_depth=depth, nbins=B,
+        pack_bits=5, fused_split=True, hist_method="segment").as_text()
+    hits = _row_gathers_over(text, {pk.size, N * F})
+    assert bool(hits) == gathers, hits
 
 
 def test_build_tree_compact_cap_parity_and_overflow_flag():
@@ -348,6 +409,32 @@ def test_fit_plan_recorded_and_profiler_fold(cloud1, _no_legacy):
 
     text = metrics_registry.prometheus_text()
     assert "h2o3_tree_hist_dispatch_total" in text
+
+
+def test_partition_read_recorded_in_kernel_stats(cloud1, _no_legacy):
+    """Which read the partition took is recorded beside the kernel plan:
+    per fit in the plan, cumulatively (trace-time) in the registry, and
+    folded into the profiler's `tree` surface."""
+    X, y = make_classification(n=2048, f=5, seed=19)
+    names = [f"f{i}" for i in range(5)] + ["label"]
+    _fit_gbm(False, X, y, names, ntrees=2, max_depth=3)
+    stats = histogram.kernel_stats()
+    assert stats["plans"][-1]["partition_read"] == "select"
+    assert stats["partition_read"].get("select", 0) > 0
+    from h2o3_tpu.runtime import profiler
+
+    assert profiler.tree_stats()["partition_read"] == stats["partition_read"]
+    before = stats["partition_read"].get("gather", 0)
+    rf = jnp.zeros(8, jnp.int32)
+    treelib._row_codes(jnp.zeros((8, 130), jnp.uint8), rf)
+    assert histogram.kernel_stats()["partition_read"]["gather"] == before + 1
+    plan = histogram.record_fit_plan(
+        "test:wide", [("d0", 1)], 21, "segment",
+        partition_read=treelib.partition_read(130))
+    assert plan["partition_read"] == "gather"
+    from h2o3_tpu.runtime import metrics_registry
+
+    assert "h2o3_tree_partition_read_total" in metrics_registry.prometheus_text()
 
 
 def test_vmem_fallback_counted_and_logged(_no_legacy):

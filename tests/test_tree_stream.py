@@ -593,24 +593,51 @@ def test_goss_tied_gradients_sample_exactly(cloud1, _ooc_env):
     assert st["goss"] is True and st["streamed_bytes"] > 0
 
 
-def test_predict_codes_packed_matches_dense(cloud1):
-    """The packed-word forest traversal (GOSS margin update) matches the
-    dense predict_codes on every pack width."""
-    rng = np.random.default_rng(9)
-    N, F, D = 512, 5, 3
+def _random_tree(rng, F, D, B):
     T = treelib.heap_size(D)
-    tree = treelib.Tree(
+    return treelib.Tree(
         feat=jnp.asarray(rng.integers(0, F, T).astype(np.int32)),
-        bin=jnp.asarray(rng.integers(0, 14, T).astype(np.int32)),
+        bin=jnp.asarray(rng.integers(0, B - 2, T).astype(np.int32)),
         thr=jnp.zeros(T, jnp.float32),
         is_split=jnp.asarray(rng.random(T) < 0.8),
         value=jnp.asarray(rng.normal(size=T).astype(np.float32)))
-    for bits, B in ((4, 16), (5, 21), (6, 33)):
-        codes = rng.integers(0, B, (N, F)).astype(np.uint8)
-        dense = treelib.predict_codes(tree, jnp.asarray(codes), D)
-        packed = treelib.predict_codes_packed(
-            tree, jnp.asarray(packing.pack_host(codes, bits)), bits, D)
-        np.testing.assert_array_equal(np.asarray(dense), np.asarray(packed))
+
+
+@pytest.mark.parametrize("bits,B", [(4, 16), (5, 21), (6, 33)])
+def test_predict_codes_packed_matches_dense(cloud1, bits, B):
+    """The packed-word forest traversal (GOSS margin update) matches the
+    dense predict_codes on every pack width."""
+    rng = np.random.default_rng(9 + bits)
+    N, F, D = 512, 5, 3
+    tree = _random_tree(rng, F, D, B)
+    codes = rng.integers(0, B, (N, F)).astype(np.uint8)
+    dense = treelib.predict_codes(tree, jnp.asarray(codes), D)
+    packed = treelib.predict_codes_packed(
+        tree, jnp.asarray(packing.pack_host(codes, bits)), bits, D)
+    np.testing.assert_array_equal(np.asarray(dense), np.asarray(packed))
+
+
+@pytest.mark.parametrize("bits,B", [(0, 21), (4, 16), (5, 21), (6, 33)])
+def test_streamed_partition_matches_dense_walk(cloud1, bits, B):
+    """One streamed block's partition under a level decision — packed at
+    every width, and full-width — routes each row as the dense walk over
+    the full-width codes does."""
+    from h2o3_tpu.models import tree_stream
+
+    rng = np.random.default_rng(21 + bits)
+    N, F, L = 1024, 6, 8
+    codes = rng.integers(0, B, (N, F)).astype(np.uint8)
+    idx = rng.integers(0, L, N).astype(np.int32)
+    bf = rng.integers(0, F, L).astype(np.int32)
+    bb = rng.integers(0, B - 2, L).astype(np.int32)
+    do_split = rng.random(L) < 0.7
+    want = 2 * idx + ((codes[np.arange(N), bf[idx]] > bb[idx])
+                      & do_split[idx]).astype(np.int32)
+    block = packing.pack_host(codes, bits) if bits else codes
+    got = tree_stream._partition_jit(
+        jnp.asarray(block), jnp.asarray(idx), jnp.asarray(bf),
+        jnp.asarray(bb), jnp.asarray(do_split), L=L, pack_bits=bits)
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # -- slow lane ---------------------------------------------------------------
