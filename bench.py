@@ -121,66 +121,6 @@ def bench_gbm():
              "stream": getattr(gbm.model, "_stream_stats", None) or None})
 
 
-def bench_gbm_cpu():
-    """Forced-CPU GBM trajectory lane (ISSUE 7): a scaled-down higgs-like
-    fit through the SAME fused hot path as the device config — packed-code
-    host histograms (`np.add.at` callback), single-pass split search,
-    overlapped chunk scoring — plus one H2O3_TREE_LEGACY=1 comparator rep,
-    so the host kernels keep a trajectory of their own (CPU by design —
-    the record says `"platform": "cpu"`).
-    Acceptance floor: vs_seed ≥ 1.5 (pinned as a slow test)."""
-    n_rows = int(os.environ.get("BENCH_ROWS", 100_000))
-    ntrees = int(os.environ.get("BENCH_TREES", 20))
-    max_depth = int(os.environ.get("BENCH_DEPTH", 6))
-    from h2o3_tpu.frame.frame import Frame
-    from h2o3_tpu.models.dataset_cache import clear as _cache_clear
-    from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
-
-    X, y = make_higgs_like(n_rows)
-    names = [f"f{i}" for i in range(X.shape[1])] + ["label"]
-    from h2o3_tpu.runtime import phases as _phz_mod
-
-    def run(legacy, reps):
-        best, auc = float("inf"), None
-        for _ in range(reps):
-            _cache_clear()
-            with _forced_env("H2O3_TREE_LEGACY", legacy):
-                fr = Frame.from_numpy(np.column_stack([X, y]),
-                                      names=names).asfactor("label")
-                gbm = H2OGradientBoostingEstimator(
-                    ntrees=ntrees, max_depth=max_depth, learn_rate=0.1,
-                    histogram_type="UniformAdaptive", seed=42,
-                    score_tree_interval=max(ntrees // 4, 1))
-                t0 = time.perf_counter()
-                gbm.train(y="label", training_frame=fr)
-                best = min(best, time.perf_counter() - t0)
-            auc = float(gbm.auc())
-        return best, auc
-
-    # best-of-2 for BOTH paths (rep 1 absorbs each path's own trace +
-    # compile, so vs_seed compares warm kernels with warm kernels); phase
-    # accounting stays on for both (same barriers, comparable walls), but
-    # the record embeds the FUSED reps' phase split only — buckets mixed
-    # across comparator paths decompose nothing
-    _phz_mod.reset()
-    lane_seq0 = _lane_seq()
-    wall_new, auc = run(False, reps=2)
-    fused_phases = _phz_mod.snapshot()
-    # snapshot BEFORE the legacy comparator reps: the embed describes the
-    # fused measurement's fences only
-    skew = _skew_embed(lane_seq0)
-    _phz_mod.reset()
-    wall_seed, _ = run(True, reps=2)
-    _phz_mod.reset()
-    return (f"gbm_cpu_{n_rows//1000}k_{ntrees}trees_wall_s", wall_new,
-            {"auc": round(auc, 5),
-             "n_devices": _note_devices(),
-             "collective_skew_ms": skew,
-             "seed_wall_s": round(wall_seed, 3),
-             "vs_seed": round(wall_seed / wall_new, 2),
-             "phases": fused_phases or None})
-
-
 def bench_glm():
     """Airlines-like logistic GLM, IRLS (BASELINE.json config 2): mixed
     numeric + high-cardinality categoricals, like Year/Month/Origin/Dest."""
@@ -314,7 +254,7 @@ def bench_oversubscription():
     the IN-CORE comparator (`H2O3_TREE_OOC=0` + the matching blocked
     reduction — the bit-identical baseline), and streamed with
     gradient-based SAMPLING on (`goss=True`: later trees stream a fraction
-    of the bytes). Forced-CPU like gbm_cpu, so the lane stays
+    of the bytes). Forced-CPU, so the lane stays
     comparable round over round; the budget is forced small
     (`H2O3_STREAM_BUDGET_MB` = matrix/10) so oversubscription is real on
     any host. The record embeds streamed bytes, the resident-block peak
@@ -364,11 +304,7 @@ def bench_oversubscription():
     st = getattr(m_stream.model, "_stream_stats", {}) or {}
     blocks = str(st.get("blocks", 8))
     # in-core comparator shares the streamed fit's block grid so the two
-    # walls bracket the same bit-identical computation. Warm thread stays
-    # ON (round 19): the old H2O3_WARM_THREAD=0 here worked around the
-    # 1-core in-graph callback deadlock, which `host_callback_safe` now
-    # closes at method selection — single-core hosts keep the segment
-    # kernel, so the comparator rep can no longer wedge
+    # walls bracket the same bit-identical computation
     wall_incore, _ = run({"H2O3_TREE_OOC": "0", "H2O3_TREE_SHARD": "1",
                           "H2O3_TREE_SHARD_BLOCKS": blocks})
     wall_goss, m_goss = run({"H2O3_TREE_OOC": "1",
@@ -482,7 +418,7 @@ def bench_estimators():
     PCA on ONE cached frame, measured fused vs the `H2O3_EST_LEGACY=1`
     comparator (host per-iteration loops: per-λ/per-Lloyd-step dispatch +
     sync + host solves, re-extracting the float matrix per fit). Forced-CPU
-    like gbm_cpu (CPU by design). Acceptance: vs_seed
+    (CPU by design). Acceptance: vs_seed
     (legacy wall / fused wall over the combined three-fit sequence) ≥ 3 at
     equal results (the tier-1 parity matrix pins equality).
 
@@ -541,8 +477,7 @@ def bench_estimators():
         return best, walls, auc
 
     # best-of-2 for BOTH paths (rep 1 absorbs each path's own trace +
-    # compile, so vs_seed compares warm programs with warm programs — the
-    # gbm_cpu stance)
+    # compile, so vs_seed compares warm programs with warm programs)
     _phz_mod.reset()
     wall_fused, walls_fused, auc = run(False, reps=2)
     fused_phases = _phz_mod.snapshot()
@@ -1582,16 +1517,16 @@ def bench_automl():
 # absorbs compilation / executable deserialization for the later ones).
 DEFAULT_REPEATS = {"gbm": 3, "glm": 3, "xgb_rank": 2, "dl": 2, "automl": 2,
                    "scaling": 1, "ingest": 2, "munge": 2, "grid": 1,
-                   "chaos": 1, "serving": 1, "gbm_cpu": 1, "estimators": 1,
+                   "chaos": 1, "serving": 1, "estimators": 1,
                    "disk_oversubscription": 1, "fleet_serving": 1,
                    "qos": 1}
 
 
 # lanes that are CPU by design: the scaling curve and the pod/fleet lanes run
 # in CPU subprocesses, the munge bench is pure host numpy, the chaos/serving
-# lanes measure FAILOVER/SLO behavior, gbm_cpu IS the forced-CPU trajectory
-# lane. They pin the CPU themselves and their record says so.
-CPU_LANES = ("scaling", "munge", "chaos", "pod_chaos", "serving", "gbm_cpu",
+# lanes measure FAILOVER/SLO behavior. They pin the CPU themselves and their
+# record says so.
+CPU_LANES = ("scaling", "munge", "chaos", "pod_chaos", "serving",
              "oversubscription", "disk_oversubscription", "estimators",
              "fleet_serving", "qos")
 
@@ -1971,7 +1906,7 @@ def main():
           "ingest": bench_ingest, "munge": bench_munge,
           "grid": bench_grid, "chaos": bench_chaos,
           "pod_chaos": bench_pod_chaos,
-          "serving": bench_serving, "gbm_cpu": bench_gbm_cpu,
+          "serving": bench_serving,
           "oversubscription": bench_oversubscription,
           "disk_oversubscription": bench_disk_oversubscription,
           "estimators": bench_estimators,

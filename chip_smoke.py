@@ -420,10 +420,9 @@ def phase_sharded(devices, n_rows: int, ntrees: int, max_depth: int,
 
     s_mesh = 8 * ndev // math.gcd(8, ndev)
     keep = {k: os.environ.get(k) for k in
-            ("H2O3_TREE_SHARD", "H2O3_TREE_SHARD_BLOCKS", "H2O3_TREE_LEGACY")}
+            ("H2O3_TREE_SHARD", "H2O3_TREE_SHARD_BLOCKS")}
     os.environ["H2O3_TREE_SHARD_BLOCKS"] = str(s_mesh)
     os.environ.pop("H2O3_TREE_SHARD", None)
-    os.environ.pop("H2O3_TREE_LEGACY", None)
     try:
         cloudlib.reset()
         cloud = cloudlib.init(list(devices))

@@ -488,8 +488,8 @@ class BlockStore:
         """Host array of block `b`, restoring from its spill file when the
         host copy was shed. Touches the host LRU and enforces the host
         budget (so a restore can spill a colder block in turn). This is
-        the ONE host read path — the streamed driver's host-method
-        kernels, GOSS gathers and device uploads all come through here,
+        the ONE host read path — the streamed driver's GOSS gathers and
+        device uploads all come through here,
         which is what makes restored bytes bit-identical by construction."""
         b = int(b)
         with self._lock:

@@ -454,7 +454,6 @@ class _StepCfg(NamedTuple):
     max_leaves: int = 0              # lossguide leaf budget (0 = 2^depth)
     compact_cap: int = 0             # deep-level active-node compaction
     pack_bits: int = 0               # device-RESIDENT sub-byte code packing
-    fused_split: bool = False        # single-pass split search (ISSUE 7)
     # sharded end-to-end training (ISSUE 12):
     #   "off"       — single-device semantics (also the H2O3_TREE_SHARD=0
     #                 escape hatch on a mesh: data stays on one device)
@@ -464,9 +463,9 @@ class _StepCfg(NamedTuple):
     #   "blocks"    — the SAME blocked reduction on one device, no mesh
     #                 (H2O3_TREE_SHARD=1: the forced-CPU lane that is
     #                 bit-identical to any mesh fit sharing n_shards)
-    #   "mesh_psum" — the pre-ISSUE-12 shard_map + psum path, kept for the
-    #                 legacy comparator and lossguide growth (multi-process
-    #                 fits run "mesh" since ISSUE 18's pod lane)
+    #   "mesh_psum" — the pre-ISSUE-12 shard_map + psum path, kept for
+    #                 lossguide growth (multi-process fits run "mesh"
+    #                 since ISSUE 18's pod lane)
     shard_mode: str = "off"
     n_shards: int = 0                # canonical total block count (S)
 
@@ -518,20 +517,12 @@ def _concat_args(*xs):
 # histogram kernels and the partition step consume the packed words
 # directly); these aliases keep the driver's historical surface
 from ..ops import packing as _packing
-from ..ops.histogram import host_callback_safe as _host_callback_safe
 from ..ops.histogram import record_fit_plan as _record_fit_plan
+from ..ops.histogram import resolve_method as _resolve_method
 
 _pack_host = _packing.pack_host
 _unpack_device = _packing.unpack_device
 _pack_bits_for = _packing.pack_bits_for
-
-
-def tree_legacy() -> bool:
-    """True when ``H2O3_TREE_LEGACY=1`` pins the seed tree hot path —
-    full-width resident codes, the (L, F, B)-temporary split search and
-    blocking chunk-boundary scoring — as the bit-exactness comparator
-    (same pattern as the ingest/munge/train legacy flags)."""
-    return os.environ.get("H2O3_TREE_LEGACY", "") == "1"
 
 
 def _shard_plan(ndev: int, multiproc: bool, tp) -> tuple:
@@ -558,21 +549,20 @@ def _shard_plan(ndev: int, multiproc: bool, tp) -> tuple:
     keeps all real rows contiguous in global ingest order with the pad at
     the tail, so the S ordered block partials are the same sums a 1-device
     forced-shard fit computes and an N-process fit is bit-identical to it.
-    Legacy comparator and lossguide growth keep the pre-ISSUE-12 shard_map
-    + psum path ("mesh_psum") — on pods too. H2O3_TREE_SHARD=0 demotes a
+    Lossguide growth keeps the pre-ISSUE-12 shard_map + psum path
+    ("mesh_psum") — on pods too. H2O3_TREE_SHARD=0 demotes a
     pod to mesh_psum rather than "off" (the data lives on other processes,
     so "train on one device" is not available there)."""
     import math
 
     env = os.environ.get("H2O3_TREE_SHARD", "").strip()
-    legacy_lane = (tree_legacy()
-                   or tp.get("grow_policy", "depthwise") == "lossguide")
+    lossguide = tp.get("grow_policy", "depthwise") == "lossguide"
     if multiproc:
-        if env == "0" or legacy_lane:
+        if env == "0" or lossguide:
             return ("mesh_psum" if ndev > 1 else "off"), 0
     elif env == "0":
         return "off", 0
-    elif legacy_lane:
+    elif lossguide:
         return ("mesh_psum" if ndev > 1 else "off"), 0
     base = max(int(os.environ.get("H2O3_TREE_SHARD_BLOCKS", "8") or 8), 1)
     if ndev > 1:
@@ -694,8 +684,7 @@ def _build_tree_step_fns(cfg: _StepCfg, cloud):
         kwargs = dict(max_depth=cfg.max_depth, nbins=cfg.nbins,
                       hist_method=cfg.hist_method,
                       compact_cap=cfg.compact_cap,
-                      pack_bits=cfg.pack_bits,
-                      fused_split=cfg.fused_split)
+                      pack_bits=cfg.pack_bits)
         use_mesh = cloud.size > 1 and cfg.shard_mode in ("mesh", "mesh_psum")
         if use_mesh or cfg.shard_mode == "blocks":
             # ISSUE 12: the sharded tree step. ONE inner function serves
@@ -1406,40 +1395,18 @@ class H2OSharedTreeEstimator(H2OEstimator):
         built identically by the early warm-up thread and the training path
         so both hit the same cached program. `pack_bits` is the resident
         code packing the caller resolved (0 = full-width);
-        `shard_mode`/`n_shards` come from `_shard_plan` — the host-callback
-        histogram default is gated to the collective-free modes (a
-        pure_callback cannot run under a collective program; the mesh lane
-        keeps the in-graph scatter, which is pinned bit-exact with it)."""
-        host_ok = shard_mode in ("off", "blocks")
+        `shard_mode`/`n_shards` come from `_shard_plan`. `hist_method` is
+        what the estimator (or H2O3_HIST_METHOD) named, a structural cfg
+        field → program-cache key, so an in-process flip retraces instead
+        of being silently frozen into a cached program;
+        `ops.histogram.resolve_method` turns `auto` into a kernel."""
         mtries = self._resolved_mtries(tp, F, problem)
         colp = tp["col_sample_rate"] * tp["col_sample_rate_per_tree"]
-        legacy = tree_legacy()
-        # the ONE auto→concrete hist-method resolution for the fused path:
-        # CPU's XLA scatter loops updates at ~100 ns each, the host
-        # np.add.at callback runs the same sequential f32 fold ~9× faster
-        # and consumes the packed codes without widening. Resolved HERE (a
-        # structural cfg field → program-cache key), like the env override
-        # below, so an in-process flag flip retraces instead of being
-        # silently frozen into a cached program.
-        #
-        # Row floor: a pure_callback custom-call embeds a process-local
-        # pointer, so host-path programs are EXCLUDED from the persistent
-        # compilation cache — every fresh process pays the full XLA
-        # compile (~5 s/config). Real workloads amortize that against the
-        # 9× per-level win; tiny fits (tests, toy frames) never do, so
-        # they keep the cacheable segment program.
         hist_method = os.environ.get(
             "H2O3_HIST_METHOD", tp.get("hist_method", "auto"))
-        if (hist_method == "auto" and not legacy and host_ok
-                and jax.default_backend() == "cpu"
-                and _host_callback_safe()
-                and npad >= int(os.environ.get(
-                    "H2O3_HOST_HIST_MIN_ROWS", 32768))):
-            # host_callback_safe: on a 1-core host the in-graph callback
-            # deadlocks (the intra-op pool's only thread blocks inside the
-            # custom call while the operand producers queue behind it), so
-            # single-core hosts keep the bit-identical segment scatter
-            hist_method = "host"
+        # an unknown name is refused here, before the warm-up thread or
+        # the fit traces anything
+        _resolve_method(1, nbins, hist_method)
         return _StepCfg(
             npad=npad, K=K, F=F, nbins=nbins, problem=problem, dist=dist,
             mode=self._mode, max_depth=tp["max_depth"],
@@ -1456,7 +1423,6 @@ class H2OSharedTreeEstimator(H2OEstimator):
             grow_policy=tp.get("grow_policy", "depthwise"),
             max_leaves=int(tp.get("max_leaves", 0)),
             pack_bits=int(pack_bits),
-            fused_split=not legacy,
             shard_mode=shard_mode,
             n_shards=int(n_shards),
             # deep trees switch wide levels to active-node compaction
@@ -1539,10 +1505,10 @@ class H2OSharedTreeEstimator(H2OEstimator):
         ordered_axis_fold contract; S stays a multiple of the mesh grid
         via the ``base = max(base, n_shards)`` rule below).
 
-        Ineligible fits (legacy comparator, multiproc mesh_psum,
-        checkpoint, DART, custom objectives, lossguide, monotone,
-        nbins > 256) train in-core exactly as before; a goss request on
-        an ineligible fit warns and trains unsampled."""
+        Ineligible fits (multiproc mesh_psum, checkpoint, DART, custom
+        objectives, lossguide, monotone, nbins > 256) train in-core
+        exactly as before; a goss request on an ineligible fit warns and
+        trains unsampled."""
         env = (os.environ.get("H2O3_TREE_OOC", "auto").strip() or "auto")
         goss_cfg = None
         if tp.get("goss"):
@@ -1560,7 +1526,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             goss_cfg = dict(top_rate=float(tp["goss_top_rate"]),
                             other_rate=float(tp["goss_other_rate"]),
                             start_tree=int(start))
-        eligible = (env != "0" and not tree_legacy()
+        eligible = (env != "0"
                     and shard_mode in ("off", "blocks", "mesh")
                     and self._parms.get("checkpoint") is None
                     and not tp.get("dart")
@@ -1964,19 +1930,17 @@ class H2OSharedTreeEstimator(H2OEstimator):
 
         # ---- resident sub-byte code packing (ISSUE 7 tentpole) -----------
         # The device-resident code matrix stays PACKED for the whole fit:
-        # the histogram kernels consume the packed words (the CPU host
-        # callback unpacks per 64k-row chunk; in-graph kernels widen once
-        # per program) and the partition step reads per-row codes straight
-        # from them — so the matrix the dataset cache holds in HBM (and
-        # ships through the host↔device link) shrinks 2-4×. Paths that score
-        # `predict_codes` against the resident matrix (DART dropout,
-        # checkpoint fast-forward) and the lossguide builder keep full
-        # width; H2O3_TREE_LEGACY=1 restores the seed unpack-once path.
+        # the histogram kernels consume the packed words (widened once
+        # per program) and the partition step reads per-row codes from the
+        # same widened codes — so the matrix the dataset cache holds in HBM
+        # (and ships through the host↔device link) shrinks 2-4×. Paths that
+        # score `predict_codes` against the resident matrix (DART dropout,
+        # checkpoint fast-forward) and the lossguide builder keep full width.
         # Pod fits keep the packed-resident win: quota is 8-aligned, so
         # packing this rank's canonical slice equals slicing the packed
         # global matrix — same bytes the 1-device comparator holds.
         resident_bits = 0
-        if (not tree_legacy() and (pod or not multiproc)
+        if ((pod or not multiproc)
                 and self._parms.get("checkpoint") is None
                 and not tp.get("dart")
                 and tp.get("grow_policy", "depthwise") != "lossguide"
@@ -2167,17 +2131,17 @@ class H2OSharedTreeEstimator(H2OEstimator):
             def _build_codes_dev():
                 # the bin-code matrix is the biggest fixed H2D cost: it
                 # ships as 4/5/6-bit packed words wherever it can. Resident
-                # packing (fused path) KEEPS it packed in HBM, 2-4x smaller,
-                # and the tree kernels consume the words directly; the
-                # legacy/ungated path packs for the transfer only and widens
-                # on device. On a mesh the upload is ROW-SHARDED straight
-                # from HOST memory (packed word groups align with the 8-row
-                # shard grid): each chip receives only its slice — staging
-                # the whole matrix on one device and resharding would make
-                # per-chip HBM peak equal the GLOBAL matrix. A full-width
-                # sharded upload (rare: nbins>256 / dart / checkpoint on a
-                # mesh) ships unpacked: pack-for-transfer targets the single
-                # host↔device link and would stage everything on one chip.
+                # packing KEEPS it packed in HBM, 2-4x smaller, and the tree
+                # kernels consume the words directly; a full-width resident
+                # fit packs for the transfer only and widens on device. On a
+                # mesh the upload is ROW-SHARDED straight from HOST memory
+                # (packed word groups align with the 8-row shard grid): each
+                # chip receives only its slice — staging the whole matrix on
+                # one device and resharding would make per-chip HBM peak
+                # equal the GLOBAL matrix. A full-width sharded upload (rare:
+                # nbins>256 / dart / checkpoint on a mesh) ships unpacked:
+                # pack-for-transfer targets the single host↔device link and
+                # would stage everything on one chip.
                 rs_codes = (cloud.row_sharding() if ndev_eff > 1 else None)
                 widen = 0
                 with _tracing.span("design.pack", kind="fit"):
@@ -2477,7 +2441,6 @@ class H2OSharedTreeEstimator(H2OEstimator):
         _record_fit_plan(
             plan_tag, plan_levels, nbins, cfg.hist_method,
             pack_bits=cfg.pack_bits,
-            axis_name=cloudlib.ROWS_AXIS if ndev_eff > 1 else None,
             n_shards=cfg.n_shards, n_devices=ndev_eff,
             partition_read=plan_read)
         # per-lane collective skew of THIS fit (ISSUE 13): fences recorded
@@ -2826,8 +2789,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
         # transfers and evaluates, so the device stays busy through
         # score_tree_interval instead of idling at every chunk boundary.
         # Gated to paths whose scoring event runs on device (the DRF OOB
-        # event pulls host arrays) and OFF under the legacy comparator,
-        # DART and custom objectives (inherently host-synced, chunk=1) and
+        # event pulls host arrays) and OFF under DART and custom
+        # objectives (inherently host-synced, chunk=1) and
         # compact-cap fits (their overflow-flag pull is a host sync, so a
         # "speculative" chunk would complete synchronously before the stop
         # decision — strictly worse than the sequential path).
@@ -2837,7 +2800,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         # double buffer lives INSIDE its level loop instead.)
         # (checkpointing also disables overlap: the speculative chunk
         # donates the very margins buffers the snapshot saver reads)
-        overlap = (not tree_legacy() and not multiproc
+        overlap = (not multiproc
                    and custom_obj is None and not dart
                    and not cfg.compact_cap and not ooc_blocks
                    and not (self._mode == "drf" and row_sampled)

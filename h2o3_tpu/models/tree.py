@@ -206,10 +206,9 @@ def _split_sums(hist):
     six orders larger, its gain GR^2/(HR+lambda) explodes or turns NaN and
     the search splits 11 rows off the root. Folded from the right, a tail
     child's sums are made of the few small bins it holds and carry their own
-    relative precision whatever the node's total is. Every split search
-    (`_fused_level_best`; `_flat_level_best`, which both `fused_split=False`
-    levels of `build_tree` call; `_search_splits`) reads this one function,
-    so they stay bit-identical."""
+    relative precision whatever the node's total is. Both split searches
+    (`_fused_level_best` of `build_tree` and the streamed step;
+    `_search_splits` of lossguide growth) read this one function."""
     ax = hist.ndim - 2
 
     def right(x):
@@ -228,14 +227,15 @@ def _split_sums(hist):
 def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
                       reg_lambda, reg_alpha, gsum, hsum,
                       monotone=None, lo_lvl=None, hi_lvl=None):
-    """Single-pass split search (ISSUE 7 tentpole): ONE sequential pass
+    """The split search of a dense or a compact level: ONE sequential pass
     over features computes each feature's (L, B) gain tile and folds it
     into a running per-node best, so a level emits only the (L,) winner
-    tuple — the legacy path materializes ~6 (L, F, B) f32 temporaries
-    (cumsums, thresholded sums, gain, masks) that round-trip HBM at every
+    tuple and never materializes (L, F, B) temporaries (cumsums,
+    thresholded sums, gain, masks) that would round-trip HBM at every
     level (xgboost EvaluateSplits restructured as a running scan-argmax).
 
-    Bit-exact with the legacy flat ``argmax(gain.reshape(L, F·B))``:
+    Equal, bit for bit, to a flat ``argmax(gain.reshape(L, F·B))`` (the
+    tests' reference, `tests/test_tree_split_sums.plain_level_best`):
     per-feature cumsums are the same per-lane folds as the (L, F, B)
     ``jnp.cumsum`` (lanes are independent), the running compare uses
     strict ``>`` so ties keep the EARLIEST feature/bin exactly like
@@ -250,6 +250,8 @@ def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
     do_split gate."""
     L, F, B = hist.shape[0], hist.shape[1], hist.shape[2]
     G, H = gsum[:, None], hsum[:, None]                      # (L, 1)
+    # xgboost CalcSplitGain: L1 soft-threshold the gradient sums
+    # before squaring (ThresholdL1); exact no-op at reg_alpha=0
     tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
     Gt = tl1(G)
     base = Gt * Gt / (H + reg_lambda)                        # (L, 1)
@@ -271,6 +273,13 @@ def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
             ok = ok & jax.lax.dynamic_index_in_dim(
                 keep, f, axis=1, keepdims=False)[:, None]
         if mono_on:
+            # monotone_constraints (hex/tree Constraints / LightGBM): a
+            # split on feature f with constraint c is admissible only
+            # when c·(value_right − value_left) ≥ 0, where the child
+            # values use the SAME soft-thresholded formula as
+            # materialized node values and are clamped into the node's
+            # inherited bounds. Bound propagation (in `build_tree`) then
+            # guarantees zero violations.
             vL = jnp.clip(-GLt / (HL + reg_lambda + 1e-12),
                           lo_lvl[:, None], hi_lvl[:, None])
             vR = jnp.clip(-GRt / (HR + reg_lambda + 1e-12),
@@ -297,65 +306,6 @@ def _fused_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
     return jax.lax.fori_loop(0, F, body, init)
 
 
-@jax.named_scope("tree.split")
-def _flat_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
-                     reg_lambda, reg_alpha, gsum, hsum,
-                     monotone=None, lo_lvl=None, hi_lvl=None):
-    """The seed split search (``fused_split=False``, the
-    ``H2O3_TREE_LEGACY=1`` comparator) of a dense or a compact level: gain
-    per (L, F, B) from `_split_sums`, one flat argmax. Same arguments and
-    results as `_fused_level_best`, except that the child values are None
-    without `monotone`."""
-    L, F = hist.shape[0], hist.shape[1]
-    WL, GL, HL, WR, GR, HR = _split_sums(hist)
-    G = gsum[:, None, None]
-    H = hsum[:, None, None]
-    # xgboost CalcSplitGain: L1 soft-threshold the gradient sums
-    # before squaring (ThresholdL1); exact no-op at reg_alpha=0
-    tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
-    GLt, GRt, Gt = tl1(GL), tl1(GR), tl1(G)
-    gain = (
-        GLt * GLt / (HL + reg_lambda)
-        + GRt * GRt / (HR + reg_lambda)
-        - Gt * Gt / (H + reg_lambda)
-    )
-    ok = (WL >= min_rows) & (WR >= min_rows)
-    ok = ok & (jnp.arange(nbins)[None, None, :] < nbins - 1)   # no split at NA bin
-    ok = ok & (feat_mask[None, :, None] > 0)
-    ok = ok & node_ok[:, None, None]
-    if monotone is not None:
-        # monotone_constraints (hex/tree Constraints / LightGBM): a
-        # split on feature f with constraint c is admissible only
-        # when c·(value_right − value_left) ≥ 0, where the child
-        # values use the SAME soft-thresholded formula as
-        # materialized node values and are clamped into the node's
-        # inherited bounds. Bound propagation (in `build_tree`) then
-        # guarantees zero violations.
-        vL = jnp.clip(-GLt / (HL + reg_lambda + 1e-12),
-                      lo_lvl[:, None, None], hi_lvl[:, None, None])
-        vR = jnp.clip(-GRt / (HR + reg_lambda + 1e-12),
-                      lo_lvl[:, None, None], hi_lvl[:, None, None])
-        mc = monotone[None, :, None]
-        ok = ok & ((mc == 0) | (mc * (vR - vL) >= 0))
-    if keep is not None:
-        ok = ok & keep[:, :, None]
-    gain = jnp.where(ok, gain, -jnp.inf)
-
-    flat = gain.reshape(L, F * nbins)
-    best = jnp.argmax(flat, axis=1)
-    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-    bf = (best // nbins).astype(jnp.int32)
-    bb = (best % nbins).astype(jnp.int32)
-    vLs = vRs = None
-    if monotone is not None:
-        # child values at the chosen split, gathered from the SAME
-        # vL/vR used by the admissibility check (bound propagation)
-        flat_pick = lambda A: jnp.take_along_axis(
-            A.reshape(L, F * nbins), best[:, None], axis=1)[:, 0]
-        vLs, vRs = flat_pick(vL), flat_pick(vR)
-    return best_gain, bf, bb, vLs, vRs
-
-
 def value_at(table: jax.Array, idx: jax.Array) -> jax.Array:
     """table[idx] for a small f32 table (e.g. leaf values by heap index) as
     an MXU one-hot matvec. Precision.HIGHEST is required: the TPU default
@@ -375,7 +325,7 @@ def value_at(table: jax.Array, idx: jax.Array) -> jax.Array:
     jax.jit,
     static_argnames=(
         "max_depth", "nbins", "hist_method", "axis_name", "mtries",
-        "compact_cap", "pack_bits", "fused_split", "n_shard_blocks",
+        "compact_cap", "pack_bits", "n_shard_blocks",
     ),
 )
 def build_tree(
@@ -404,7 +354,6 @@ def build_tree(
     #                     max_abs_leafnode_pred / xgboost max_delta_step)
     compact_cap: int = 0,
     pack_bits: int = 0,
-    fused_split: bool = False,
     n_shard_blocks: int = 0,
 ):
     """Build one tree; returns (Tree, final_leaf_heap_idx (N,),
@@ -433,16 +382,10 @@ def build_tree(
     monotone=None.
 
     pack_bits in {4, 5, 6} means `codes` is the `ops.packing` packed word
-    matrix: histogram kernels consume it (per-chunk unpack — the host/CPU
-    path never widens; in-graph kernels widen once per program) and the
+    matrix: histogram kernels consume it (widened once per program) and the
     partition step reads each row's selected-feature code from the same
     widened codes by a dense select over the feature axis (`_row_codes`;
     no gather into the code matrix at F <= `_ONEHOT_LOOKUP_MAX`).
-
-    fused_split=True switches the per-level split search to the
-    single-pass scan-argmax (`_fused_level_best`, bit-exact with the
-    legacy flat argmax); False keeps the seed formulation — the
-    ``H2O3_TREE_LEGACY=1`` comparator.
 
     n_shard_blocks > 0 (ISSUE 12) makes every row reduction (histograms
     and final leaf totals) use the shard-invariant blocked fold of
@@ -526,7 +469,6 @@ def build_tree(
 
         # per-(node,feature) bernoulli keep with the same node psum'd RNG
         # on every host (key is replicated) so partitions stay consistent.
-        # Drawn identically (one split per level) on both search paths.
         keep = None
         if mtries > 0 or mtries_rate is not None:
             key, sub = jax.random.split(key)
@@ -534,8 +476,7 @@ def build_tree(
             keep = jax.random.uniform(sub, (L, F)) < rate
             keep = keep.at[:, 0].set(keep[:, 0] | ~keep.any(axis=1))  # >=1 kept
 
-        search = _fused_level_best if fused_split else _flat_level_best
-        best_gain, bf, bb, vLs, vRs = search(
+        best_gain, bf, bb, vLs, vRs = _fused_level_best(
             hist, active, feat_mask, keep, nbins, min_rows, reg_lambda,
             reg_alpha, gsum, hsum, monotone=monotone,
             lo_lvl=lo_lvl if monotone is not None else None,
@@ -574,9 +515,9 @@ def build_tree(
         if monotone is not None:
             # propagate bounds to children: on a ±1-constrained split the
             # mid-point of the chosen split's child values caps the lower-
-            # valued side and floors the higher-valued side. vLs/vRs were
-            # gathered above from the SAME vL/vR the admissibility check
-            # used (legacy flat_pick or the fused running carry).
+            # valued side and floors the higher-valued side. vLs/vRs are
+            # the search's running carry of the SAME vL/vR its
+            # admissibility check used.
             mid = 0.5 * (vLs + vRs)
             c = monotone[bf] * do_split.astype(monotone.dtype)
             # c=+1: left ≤ mid ≤ right; c=−1: mirrored; c=0: inherit as-is
@@ -664,8 +605,7 @@ def build_tree(
             rate = mtries_rate if mtries_rate is not None else (mtries / F)
             keep = jax.random.uniform(sub, (CAP + 1, F)) < rate
             keep = keep.at[:, 0].set(keep[:, 0] | ~keep.any(axis=1))
-        search = _fused_level_best if fused_split else _flat_level_best
-        best_gain, bf, bb, _, _ = search(
+        best_gain, bf, bb, _, _ = _fused_level_best(
             slot_hist, valid, feat_mask, keep, nbins, min_rows, reg_lambda,
             reg_alpha, gsum, hsum)
         do = best_gain > jnp.maximum(min_split_improvement, 1e-10)

@@ -23,11 +23,6 @@ entering level d, one block visit applies level d-1's partition and
 accumulates level d's sibling-left histogram partial, so a tree streams
 (depth+1)·S block reads, not 2·depth·S.
 
-Host-histogram blocks never touch `pure_callback`: the per-block
-accumulate runs `_host_hist_cb` directly on the ONE dedicated worker
-thread (`ops.histogram.host_hist_direct`) — same math, bit-exact, and
-immune to the warm-thread callback hang documented in docs/perf.md.
-
 Gradient-based sampling (the paper's GOSS-shaped §sampling): past the
 warm-up trees, keep the top-|g| rows plus an amplified random rest, gather
 them into a compact packed sample, and build the tree on THAT — the
@@ -49,8 +44,8 @@ import numpy as np
 from ..ops import packing
 from ..runtime import qos as _qos
 from ..runtime import supervisor as _supervisor
-from ..ops.histogram import (host_hist_direct, ordered_axis_fold,
-                             resolve_method, run_block_kernel)
+from ..ops.histogram import (ordered_axis_fold, resolve_method,
+                             run_block_kernel)
 from . import distributions as dist_mod
 from . import tree as treelib
 from .tree import (_ONEHOT_LOOKUP_MAX, _fused_level_best, _lookup_bool,
@@ -113,11 +108,6 @@ def _partition(codes_b, idx_b, bf, bb, do_split, L: int, pack_bits: int):
     rcode = _row_codes(codes_b, rf, pack_bits)
     go_right = (rcode > rb) & rs
     return 2 * idx_b + go_right.astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("L", "pack_bits"))
-def _partition_jit(codes_b, idx_b, bf, bb, do_split, L: int, pack_bits: int):
-    return _partition(codes_b, idx_b, bf, bb, do_split, L, pack_bits)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -238,15 +228,11 @@ class _ResidentBlocks:
     compact sample) — same surface as BlockStore where the level loop
     needs it."""
 
-    def __init__(self, dev_blocks: List, host_blocks: List[np.ndarray]):
+    def __init__(self, dev_blocks: List):
         self._dev = dev_blocks
-        self.host_blocks = host_blocks
 
     def get(self, b: int):
         return self._dev[b]
-
-    def fetch_host(self, b: int) -> np.ndarray:
-        return self.host_blocks[b]
 
     def prefetch(self, b: int) -> None:
         pass
@@ -277,19 +263,6 @@ class StreamedTreeStep:
             cap = int(cfg.npad * frac) + 8
             self.goss_cap = min(cfg.npad, ((cap + 7) // 8) * 8)
 
-    # -- helpers -----------------------------------------------------------
-
-    def _method_for(self, n_nodes: int) -> dict:
-        return resolve_method(n_nodes, self.cfg.nbins, self.cfg.hist_method,
-                              axis_name=None)
-
-    def _host_rows(self, g, h, wt):
-        """Host copies of the per-row vectors for host-method kernels
-        (free on CPU, where the host method is the only place this
-        runs)."""
-        return (np.asarray(g, np.float32), np.asarray(h, np.float32),
-                np.asarray(wt, np.float32))
-
     # -- the streamed build_tree (dense depthwise, fused split) ------------
 
     def _build_streamed(self, provider, S: int, rows: int, g, h, wt, fm,
@@ -307,17 +280,14 @@ class StreamedTreeStep:
         gain_pf = jnp.zeros(F, jnp.float32)
         active = jnp.ones(1, bool)
         idx_blocks = [jnp.zeros(rows, jnp.int32) for _ in range(S)]
-        host_rows = None
         dec = None
         hist_prev = None
         key_b = key
         for d in range(D):
             L = 2 ** d
             L_kernel = 1 if d == 0 else L // 2
-            sel = self._method_for(L_kernel)
+            sel = resolve_method(L_kernel, nbins, cfg.hist_method)
             method, row_chunk = sel["method"], sel["row_chunk"]
-            if method == "host" and host_rows is None:
-                host_rows = self._host_rows(g, h, wt)
             parts = []
             for b in range(S):
                 # per-BLOCK QoS yield: the streamed grid is the natural
@@ -330,43 +300,19 @@ class StreamedTreeStep:
                 _supervisor.pulse("tree_stream", d * S + b)
                 codes_b = provider.get(b)
                 if d == 0:
-                    if method == "host":
-                        g_np, h_np, wt_np = (a[b * rows:(b + 1) * rows]
-                                             for a in host_rows)
-                        vals = np.stack([wt_np, g_np * wt_np,
-                                         h_np * wt_np]).astype(np.float32)
-                        part = jnp.asarray(host_hist_direct(
-                            provider.fetch_host(b),
-                            np.zeros(rows, np.int32), vals, 1, nbins,
-                            pack_bits))
-                    else:
-                        part = _first_pass_jit(
-                            codes_b, g[b * rows:(b + 1) * rows],
-                            h[b * rows:(b + 1) * rows],
-                            wt[b * rows:(b + 1) * rows],
-                            nbins, method, pack_bits, row_chunk)
+                    part = _first_pass_jit(
+                        codes_b, g[b * rows:(b + 1) * rows],
+                        h[b * rows:(b + 1) * rows],
+                        wt[b * rows:(b + 1) * rows],
+                        nbins, method, pack_bits, row_chunk)
                 else:
-                    if method == "host":
-                        idx_b = _partition_jit(
-                            codes_b, idx_blocks[b], *dec, L // 2, pack_bits)
-                        idx_blocks[b] = idx_b
-                        idx_np = np.asarray(idx_b, np.int32)
-                        g_np, h_np, wt_np = (a[b * rows:(b + 1) * rows]
-                                             for a in host_rows)
-                        w_eff = wt_np * (idx_np % 2 == 0)
-                        vals = np.stack([w_eff, g_np * w_eff,
-                                         h_np * w_eff]).astype(np.float32)
-                        part = jnp.asarray(host_hist_direct(
-                            provider.fetch_host(b), idx_np // 2, vals,
-                            L // 2, nbins, pack_bits))
-                    else:
-                        idx_b, part = _level_pass_jit(
-                            codes_b, idx_blocks[b],
-                            g[b * rows:(b + 1) * rows],
-                            h[b * rows:(b + 1) * rows],
-                            wt[b * rows:(b + 1) * rows], *dec,
-                            L // 2, nbins, method, pack_bits, row_chunk)
-                        idx_blocks[b] = idx_b
+                    idx_b, part = _level_pass_jit(
+                        codes_b, idx_blocks[b],
+                        g[b * rows:(b + 1) * rows],
+                        h[b * rows:(b + 1) * rows],
+                        wt[b * rows:(b + 1) * rows], *dec,
+                        L // 2, nbins, method, pack_bits, row_chunk)
+                    idx_blocks[b] = idx_b
                 # double buffer: block b's kernel is dispatched (async);
                 # start block b+1's H2D now so transfer and compute overlap
                 provider.prefetch((b + 1) % S)
@@ -486,7 +432,7 @@ class StreamedTreeStep:
         g_sel = jnp.take(g, sel_d)
         h_sel = jnp.take(h, sel_d)
         w_sel = jnp.asarray(w_sel_np)
-        provider = _ResidentBlocks([dev], [packed_sel])
+        provider = _ResidentBlocks([dev])
         tr, _idx, gains, cover = self._build_streamed(
             provider, 1, cap, g_sel, h_sel, w_sel, fm, edges, hp, ktree)
         tr = tr._replace(value=tr.value * scale)

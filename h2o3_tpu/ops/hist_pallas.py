@@ -13,12 +13,13 @@ multiplies, and accumulates into the output block, which stays resident
 across the sequential TPU grid (output-revisiting pattern). HBM traffic is
 therefore just codes-in + histogram-out.
 
-Layout: grid = (row_chunks,); per step the kernel scans features with a
-fori_loop, computing hist[f, 3, L·B] += valsᵀ(3,R) @ onehot(R, L·B).
+Layout: grid = (row_chunks, F/8), feature blocks innermost; per row chunk
+the (3L, R) node-weighted values are built once in scratch, and each step
+computes hist[fb, 3L, 8·B] += weighted(3L,R) @ bin_onehot(R, 8·B).
 
 Packed-code input (ISSUE 7): the device-RESIDENT matrix is the 4/5/6-bit
 `ops.packing` word matrix; `build_histograms` widens it IN-GRAPH before
-these kernels, once per compiled tree program (XLA CSEs the widen across
+the kernel, once per compiled tree program (XLA CSEs the widen across
 every level's pass — only a program-lifetime transient is full-width, the
 resident/cached/uploaded artifact stays packed). The factored kernel's
 operand (`ops.histogram.feature_major`) has a second reader in the
@@ -43,9 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_ROW_CHUNK = 2048
 FACTORED_ROW_CHUNK = 8192
-# The scoped-VMEM budget both kernels are compiled under, STATED in the
+# The scoped-VMEM budget the kernel is compiled under, STATED in the
 # pallas_call instead of left to the compiler's per-generation default (it
 # equals the v5e default). `histogram._factored_row_chunk` sizes the row
 # chunk against this number; tests/test_chip_compile.py holds the pair to
@@ -146,71 +146,3 @@ def build_histograms_pallas_factored(
     out = out.reshape(Fpad // _FB, 3 * L, _FB, B).transpose(0, 2, 1, 3)
     out = out.reshape(Fpad, 3 * L, B)[:F]
     return out.reshape(F, 3, L, B).transpose(2, 0, 3, 1)
-
-
-def _hist_kernel(codes_ref, cid_base_ref, vals_ref, out_ref, *, F: int, LB: int):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vals = vals_ref[...]                       # (3, R) f32
-    base = cid_base_ref[...]                   # (1, R) i32 = node*B
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, LB), 1)
-
-    def body(f, _):
-        code_f = codes_ref[f, :]               # (R,) i32
-        cid = base[0, :] + code_f              # (R,)
-        onehot = (cid[:, None] == iota).astype(jnp.bfloat16)      # (R, LB)
-        part = jax.lax.dot_general(
-            vals.astype(jnp.bfloat16), onehot,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (3, LB)
-        out_ref[f, :, :] += part
-        return 0
-
-    jax.lax.fori_loop(0, F, body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("n_nodes", "nbins", "row_chunk"))
-def build_histograms_pallas(
-    codes: jax.Array,      # (N, F) any int dtype
-    node_id: jax.Array,    # (N,) int32
-    vals: jax.Array,       # (3, N) f32 — rows already weight-masked
-    n_nodes: int,
-    nbins: int,
-    row_chunk: int = DEFAULT_ROW_CHUNK,
-) -> jax.Array:
-    """(n_nodes, F, nbins, 3) histogram via the fused pallas kernel."""
-    N, F = codes.shape
-    LB = n_nodes * nbins
-    R = row_chunk
-    npad = ((N + R - 1) // R) * R
-    pad = npad - N
-    codes_i = codes.astype(jnp.int32)
-    if pad:
-        codes_i = jnp.pad(codes_i, ((0, pad), (0, 0)))
-        node_id = jnp.pad(node_id.astype(jnp.int32), (0, pad))
-        vals = jnp.pad(vals, ((0, 0), (0, pad)))  # zero vals ⇒ no contribution
-    cid_base = (node_id.astype(jnp.int32) * nbins)[None, :]  # (1, npad)
-    codes_t = codes_i.T  # (F, npad) — feature-major so each chunk is contiguous
-
-    grid = (npad // R,)
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, F=F, LB=LB),
-        out_shape=jax.ShapeDtypeStruct((F, 3, LB), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((F, R), lambda i: (0, i)),      # codes_t chunk
-            pl.BlockSpec((1, R), lambda i: (0, i)),      # cid_base chunk
-            pl.BlockSpec((3, R), lambda i: (0, i)),      # vals chunk
-        ],
-        out_specs=pl.BlockSpec((F, 3, LB), lambda i: (0, 0, 0)),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="tree_hist",
-    )(codes_t, cid_base, vals)
-    # (F, 3, LB) → (n_nodes, F, nbins, 3)
-    return out.reshape(F, 3, n_nodes, nbins).transpose(2, 0, 3, 1)

@@ -9,32 +9,19 @@ histogram arrays across nodes), and XGBoost's CUDA `gpu_hist` updater
 
 On TPU, scatter-add (the GPU approach: atomics into shared-memory
 histograms) is the enemy — the VPU has no atomics and XLA lowers scatter to
-serialized updates. On CPU, XLA's scatter emitter is the enemy too: it
-loops updates at ~100 ns each. Strategies, selectable and benchmarked:
+serialized updates. Three kernels; `resolve_method` is the one rule that
+picks among them (``pallas_factored`` on a TPU with ``segment`` as its
+VMEM fallback, ``segment`` on a CPU, ``onehot`` on other accelerators):
 
 * ``onehot``: encode (node,bin) as a one-hot matrix and reduce with a
   matmul — rides the MXU. hist[c, l*B+b] = Σ_rows vals[c,row] ·
   onehot[row, l*B+b], scanned over features. O(N·L·B) FLOPs per feature but
   systolic-array FLOPs are nearly free at these sizes.
 * ``segment``: `jax.ops.segment_sum` with ids = node·B + bin (XLA sorted
-  scatter). The seed CPU default, kept as the ``H2O3_TREE_LEGACY``
-  comparator and for very large L·B.
-* ``host``: `jax.pure_callback` to a scalar ``np.add.at`` loop — numpy's
-  indexed-add fast path runs the SAME sequential in-order f32 fold as the
-  XLA scatter at ~10x the speed (measured 16 ms vs 150 ms for 1.4M updates
-  on the dev box), so it is bit-exact with ``segment``. The fused-tree CPU
-  default for fits >= H2O3_HOST_HIST_MIN_ROWS (32768) padded rows: a
-  callback custom-call embeds a process-local pointer, which excludes the
-  program from the persistent compile cache — tiny fits keep the cacheable
-  ``segment`` program instead of paying a fresh XLA compile per process.
-  Consumes 4/5/6-bit packed codes directly, unpacking per row-chunk in
-  numpy. Single-shard only (never under a collective), and only when the
-  host has a SPARE core (`host_callback_safe`): with one usable CPU the
-  XLA CPU runtime deadlocks on any in-graph callback whose operands are
-  computed by a large (task-split) op — see `host_callback_safe` — so
-  1-core hosts keep the in-graph ``segment`` scatter (bit-identical).
-* ``pallas``/``pallas_factored``: the fused VMEM kernels in
-  `hist_pallas.py`. With packed input they widen IN-GRAPH once per jitted
+  scatter). The kernel of very large L·B (the VMEM fallback) and of
+  CPU fits.
+* ``pallas_factored``: the fused VMEM kernel in
+  `hist_pallas.py`. With packed input it widens IN-GRAPH once per jitted
   tree program (XLA CSEs the widen across every level's histogram pass of
   the program), so the RESIDENT matrix — what the dataset cache holds
   across fits and what the H2D upload moves — stays packed; only a
@@ -53,9 +40,7 @@ equal-sized row range) that are gathered into global block order
 a fixed reduction tree independent of how many devices the blocks live
 on. An N-device fit and a 1-device fit configured with the same total
 block count therefore produce BIT-IDENTICAL histograms: each block
-partial is the same sequential in-order f32 fold over the same rows
-(`host` np.add.at and the XLA `segment` scatter are pinned bit-exact, so
-the mesh lane's in-graph scatter matches the forced-CPU lane's callback),
+partial is the same sequential in-order f32 fold over the same rows,
 and the cross-block fold order is pinned by the expression tree. This is
 what makes "8-device fit == 1-device fused fit" a bit-stability pin
 rather than an allclose hope.
@@ -69,7 +54,6 @@ metrics registry, and the tree driver records a per-fit level plan via
 
 from __future__ import annotations
 
-import functools
 import os
 import threading
 from collections import deque
@@ -77,12 +61,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import packing
 
-# row-chunk for the host callback's packed unpack (numpy transient bound)
-HOST_UNPACK_CHUNK = 1 << 16
+# what `method=` / `hist_method=` / H2O3_HIST_METHOD may name
+METHODS = ("auto", "onehot", "segment", "pallas_factored")
 
 
 def _factored_row_chunk(n_nodes: int, nbins: int) -> int:
@@ -143,7 +126,6 @@ def _sel_registry() -> dict:
 
 
 def resolve_method(n_nodes: int, nbins: int, method: str = "auto",
-                   axis_name: Optional[str] = None,
                    platform: Optional[str] = None) -> dict:
     """The ONE auto-dispatch rule, shared by `build_histograms` and the
     driver's per-fit plan recording so the observed plan cannot diverge
@@ -151,9 +133,13 @@ def resolve_method(n_nodes: int, nbins: int, method: str = "auto",
     ``{"method", "row_chunk", "fallback"}`` — `row_chunk` is the pallas
     grid chunk (None off the pallas path), `fallback` names why a
     requested kernel was substituted (today: "vmem" for the
-    `_factored_row_chunk` < 512 pressure fallback)."""
+    `_factored_row_chunk` < 512 pressure fallback). A name that is not one
+    of `METHODS` is refused here, before any kernel is traced."""
     if method == "auto":
         method = os.environ.get("H2O3_HIST_METHOD", "auto")
+    if method not in METHODS:
+        raise ValueError(f"unknown histogram method {method!r}: "
+                         f"valid methods are {', '.join(METHODS)}")
     if platform is None:
         platform = jax.default_backend()
     if method == "auto":
@@ -168,10 +154,6 @@ def resolve_method(n_nodes: int, nbins: int, method: str = "auto",
             method = "onehot"  # non-TPU accelerators: Mosaic won't lower
     row_chunk = None
     fallback = None
-    if method == "host" and axis_name is not None:
-        # the host callback cannot run under a collective program — the
-        # psum'd shard path keeps the in-graph scatter
-        method, fallback = "segment", "collective"
     if method == "pallas_factored":
         rc = _factored_row_chunk(n_nodes, nbins)
         if rc < 512:
@@ -215,9 +197,8 @@ def record_partition_read(read: str) -> None:
 
 
 def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
-                    pack_bits: int = 0, axis_name: Optional[str] = None,
-                    platform: Optional[str] = None, n_shards: int = 0,
-                    n_devices: int = 1,
+                    pack_bits: int = 0, platform: Optional[str] = None,
+                    n_shards: int = 0, n_devices: int = 1,
                     partition_read: Optional[str] = None) -> dict:
     """Resolve + record the per-level kernel plan of one tree fit.
 
@@ -233,8 +214,7 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     plan_levels = []
     fellback = []
     for label, n_nodes in levels:
-        sel = resolve_method(n_nodes, nbins, hist_method,
-                             axis_name=axis_name, platform=platform)
+        sel = resolve_method(n_nodes, nbins, hist_method, platform=platform)
         _record_selection(sel, vmem=True)
         plan_levels.append(dict(level=label, n_nodes=int(n_nodes), **sel))
         if sel["fallback"] == "vmem":
@@ -357,131 +337,6 @@ def _hist_segment(codes, node_id, vals, n_nodes: int, nbins: int):
     return hists.reshape(F, n_nodes, nbins, 3).transpose(1, 0, 2, 3)
 
 
-def _host_hist_cb(codes, node_id, vals, n_nodes: int, nbins: int,
-                  pack_bits: int) -> np.ndarray:
-    """The host accumulate loop: scalar ``np.add.at`` per (feature,
-    channel) — numpy's indexed-add fast path, a sequential in-order f32
-    fold bit-identical to the XLA scatter the `segment` path runs.
-    Packed codes are widened per `HOST_UNPACK_CHUNK` rows, so the
-    full-width matrix never materializes."""
-    codes = np.asarray(codes)
-    node_id = np.asarray(node_id, dtype=np.int32)
-    vals = np.asarray(vals)
-    F = codes.shape[1]
-    LB = n_nodes * nbins
-    out = np.zeros((F, LB, 3), np.float32)
-    base_all = node_id * np.int32(nbins)
-    n = (packing.packed_nrows(codes.shape[0], pack_bits) if pack_bits
-         else codes.shape[0])
-    group = packing.GROUP_ROWS.get(pack_bits, 1)
-    gbytes = packing.GROUP_BYTES.get(pack_bits, 1)
-    step = HOST_UNPACK_CHUNK - (HOST_UNPACK_CHUNK % group or 0)
-    for r0 in range(0, n, step):
-        r1 = min(r0 + step, n)
-        if pack_bits:
-            chunk = packing.unpack_host(
-                codes[r0 // group * gbytes: r1 // group * gbytes], pack_bits)
-        else:
-            chunk = codes[r0:r1]
-        base = base_all[r0:r1]
-        for f in range(F):
-            ids = base + chunk[:, f].astype(np.int32)
-            for k in range(3):
-                np.add.at(out[f, :, k], ids, vals[k, r0:r1])
-    return out.reshape(F, n_nodes, nbins, 3).transpose(1, 0, 2, 3)
-
-
-def _hist_host(codes, node_id, vals, n_nodes: int, nbins: int,
-               pack_bits: int):
-    """`pure_callback` wrapper around `_host_hist_cb` (CPU fast path).
-
-    The callback BODY runs on the ONE dedicated host-hist worker thread
-    (round 19): hopping to the worker serializes every host accumulate —
-    warm thread and fit included — so concurrent dispatches can't thrash
-    numpy's indexed-add fast path, and XLA's callback thread just waits
-    on the future. Operands are materialized to numpy BEFORE the hop, on
-    the thread XLA handed us: a device->host conversion from the worker
-    thread would wait on the runtime while the runtime waits on our
-    future. Requires a spare core — `host_callback_safe` gates selection
-    (see the comment block below)."""
-    F = codes.shape[1]
-
-    def cb(codes_, node_id_, vals_):
-        # materialize to numpy HERE, on the thread XLA handed us: a
-        # device->host conversion from the worker thread would wait on
-        # the runtime while the runtime waits on our future
-        codes_ = np.asarray(codes_)
-        node_id_ = np.asarray(node_id_)
-        vals_ = np.asarray(vals_)
-        return _host_worker().submit(
-            _host_hist_cb, codes_, node_id_, vals_,
-            n_nodes=n_nodes, nbins=nbins, pack_bits=pack_bits).result()
-
-    return jax.pure_callback(
-        cb, jax.ShapeDtypeStruct((n_nodes, F, nbins, 3), jnp.float32),
-        codes, node_id, vals)
-
-
-# -- dedicated host-histogram worker (ISSUE 14 satellite) -------------------
-#
-# The in-graph `pure_callback` route has a known failure mode on 1-core
-# hosts, root-caused in round 19 (it was previously blamed on the warm-up
-# thread; a pristine fit with H2O3_WARM_THREAD=0 hangs identically): the
-# XLA CPU runtime splits large ops into parallel tasks on its intra-op
-# pool, and with ONE usable core the pool's only thread is the very thread
-# that ends up blocked inside the callback custom-call — the producer
-# tasks behind it never drain, so `np.asarray` on any computed operand
-# over the task-split threshold (~256 KB) waits forever. Reproduced with a
-# 12-line minimal jit(pure_callback) at 32768x8 f32; operands that are
-# program INPUTS or small reductions are unaffected. `host_callback_safe`
-# below gates the auto host-method selection on a spare core; 1-core
-# hosts keep the in-graph `segment` scatter, which is pinned bit-exact.
-# The STREAMED tree path never goes through pure_callback at all: its
-# per-block host histograms run `_host_hist_cb` directly on ONE dedicated
-# worker thread — same math, no XLA callback machinery to hang, and
-# serialization keeps numpy's indexed-add fast path from thrashing the
-# host — so big CPU fits on 1-core hosts still get the np.add.at win via
-# the out-of-core streaming lane (auto at >= the stream budget).
-
-_HOST_WORKER_LOCK = threading.Lock()
-_HOST_WORKER = [None]
-
-
-def host_callback_safe() -> bool:
-    """True when the CPU runtime has a spare thread to service an
-    in-graph host callback. With one usable core, XLA's intra-op pool
-    cannot make progress on the callback's producer ops while the
-    callback blocks (deadlock — see the comment block above), so the
-    fused path must keep the in-graph `segment` kernel there."""
-    try:
-        n = len(os.sched_getaffinity(0))
-    except (AttributeError, OSError):
-        n = os.cpu_count() or 1
-    return n > 1
-
-
-def _host_worker():
-    if _HOST_WORKER[0] is None:
-        with _HOST_WORKER_LOCK:
-            if _HOST_WORKER[0] is None:
-                from concurrent.futures import ThreadPoolExecutor
-
-                _HOST_WORKER[0] = ThreadPoolExecutor(
-                    max_workers=1, thread_name_prefix="h2o3-host-hist")
-    return _HOST_WORKER[0]
-
-
-def host_hist_direct(codes: np.ndarray, node_id: np.ndarray,
-                     vals: np.ndarray, n_nodes: int, nbins: int,
-                     pack_bits: int) -> np.ndarray:
-    """One host-histogram accumulate, routed through the single dedicated
-    callback worker (never `pure_callback`). Bit-exact with `_hist_host`
-    / the `segment` scatter — the streamed-block host path."""
-    return _host_worker().submit(
-        _host_hist_cb, codes, node_id, vals,
-        n_nodes=n_nodes, nbins=nbins, pack_bits=pack_bits).result()
-
-
 def run_block_kernel(method: str, codes, node_id, vals, n_nodes: int,
                      nbins: int, pack_bits: int = 0,
                      row_chunk: "Optional[int]" = None):
@@ -540,10 +395,8 @@ def _run_kernel(sel: dict, codes, node_id, vals, n_nodes: int, nbins: int,
                 pack_bits: int):
     """One resolved kernel invocation over one contiguous row range."""
     method = sel["method"]
-    if method == "host":
-        return _hist_host(codes, node_id, vals, n_nodes, nbins, pack_bits)
     if pack_bits:
-        # in-graph consumers take dense codes: widen in-graph. The widen is
+        # the kernels take dense codes: widen in-graph. The widen is
         # a pure function of the loop-invariant packed input, so XLA
         # computes it once per program execution and shares the buffer
         # across every level's histogram pass; the RESIDENT matrix stays
@@ -553,19 +406,13 @@ def _run_kernel(sel: dict, codes, node_id, vals, n_nodes: int, nbins: int,
         return _hist_onehot(codes, node_id, vals, n_nodes, nbins)
     if method == "segment":
         return _hist_segment(codes, node_id, vals, n_nodes, nbins)
-    if method == "pallas":
-        from . import hist_pallas
+    # "pallas_factored": `resolve_method` admits no other name
+    from . import hist_pallas
 
-        return hist_pallas.build_histograms_pallas(
-            codes, node_id, vals, n_nodes, nbins)
-    if method == "pallas_factored":
-        from . import hist_pallas
-
-        return hist_pallas.build_histograms_pallas_factored(
-            feature_major(codes), node_id, vals, n_nodes, nbins,
-            row_chunk=sel["row_chunk"],
-        )
-    raise ValueError(f"unknown histogram method {method!r}")
+    return hist_pallas.build_histograms_pallas_factored(
+        feature_major(codes), node_id, vals, n_nodes, nbins,
+        row_chunk=sel["row_chunk"],
+    )
 
 
 def _packed_row_slice(codes, r0: int, r1: int, pack_bits: int):
@@ -599,8 +446,7 @@ def build_histograms(
     cross-host merge (the MRTask.reduce step) when called under shard_map.
 
     With ``pack_bits`` in {4, 5, 6}, `codes` is the `ops.packing` packed
-    matrix; the host and pallas paths consume it directly (per-row-chunk
-    unpack), other paths widen in-graph before accumulating.
+    matrix, widened in-graph before accumulating.
 
     ``n_shard_blocks`` > 0 switches to the shard-invariant blocked
     reduction (see module docstring): this call's rows are split into that
@@ -610,7 +456,7 @@ def build_histograms(
     evenly (padded row counts are multiples of blocks·8).
     """
     vals = jnp.stack([w, g * w, h * w]).astype(jnp.float32)  # (3, N)
-    sel = resolve_method(n_nodes, nbins, method, axis_name=axis_name)
+    sel = resolve_method(n_nodes, nbins, method)
     _record_selection(sel)
     if n_shard_blocks > 0:
         n = node_id.shape[0]

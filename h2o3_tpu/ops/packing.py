@@ -23,12 +23,10 @@ Consumers:
 
 * ``unpack_device`` — whole-matrix widening on device: the in-graph
   histogram kernels and the partition step (below) evaluate it inside the
-  tree program; the legacy ``H2O3_TREE_LEGACY=1`` path ships packed and
-  materializes full width once.
-* ``ops/histogram.py`` — the host callback path unpacks in numpy per
-  64k-row chunk (the full-width matrix never exists); in-graph kernels
-  widen once per jitted tree program (a program-lifetime transient — the
-  resident matrix stays packed).
+  tree program; a full-width resident fit ships packed and materializes
+  full width once.
+* ``ops/histogram.py`` — the kernels widen once per jitted tree program
+  (a program-lifetime transient — the resident matrix stays packed).
 * ``models/tree._row_codes`` — the partition step's per-row
   selected-feature code: a dense select over the feature axis of the SAME
   widened codes (`unpack_device`, shared with the histogram kernels of the
@@ -104,8 +102,8 @@ def pack_host_range(codes: np.ndarray, bits: int, r0: int, r1: int) -> np.ndarra
 
 
 def unpack_host(packed: np.ndarray, bits: int) -> np.ndarray:
-    """Inverse of `pack_host` on host numpy (the histogram callback's
-    per-chunk widening) — bit-exact with `unpack_device`."""
+    """Inverse of `pack_host` on host numpy (the streamed GOSS gather's
+    per-block widening) — bit-exact with `unpack_device`."""
     if bits == 4:
         k = packed.shape[0]
         out = np.empty((2 * k,) + packed.shape[1:], np.uint8)

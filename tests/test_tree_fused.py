@@ -1,13 +1,15 @@
 """Fused GBM hot path (ISSUE 7) — packed-code histograms, single-pass
 split search, overlapped chunk scoring.
 
-Pins: (1) every fused lever is BIT-EXACT against the ``H2O3_TREE_LEGACY=1``
-comparator across the parity matrix (GBM/DRF, mtries, monotone,
-compact-cap, CV fold reuse, overlap on/off); (2) a warm higgs-shaped fit
-re-traces ZERO programs (the ROADMAP item 2 pin, via the PR 6 XLA
-tracker); (3) the histogram kernel auto-dispatch is observable — per-fit
-plans, dispatch counters, and the previously-silent VMEM-pressure
-fallback; (4) the forced-CPU bench floor (slow)."""
+Pins: (1) the packed-resident build is BIT-EXACT against the full-width
+one across the parity matrix — `build_tree` on packed · dense codes, whole
+fits (GBM/DRF, mtries, CV fold reuse) packed · the full-width resident path
+that DART, `checkpoint=`, lossguide and nbins > 256 fits run, overlap on ·
+off; the split search itself is held to its plain reference in
+tests/test_tree_split_sums.py; (2) a warm higgs-shaped fit re-traces ZERO
+programs (the ROADMAP item 2 pin, via the PR 6 XLA tracker); (3) the
+histogram kernel auto-dispatch is observable — per-fit plans, dispatch
+counters, and the previously-silent VMEM-pressure fallback."""
 
 import os
 
@@ -24,10 +26,9 @@ from conftest import make_classification
 
 
 @pytest.fixture()
-def _no_legacy():
-    """Isolate the legacy/overlap env knobs per test."""
-    keys = ("H2O3_TREE_LEGACY", "H2O3_TREE_OVERLAP", "H2O3_HIST_METHOD",
-            "H2O3_HOST_HIST_MIN_ROWS")
+def _tree_env():
+    """Isolate the overlap/kernel env knobs per test."""
+    keys = ("H2O3_TREE_OVERLAP", "H2O3_HIST_METHOD")
     prior = {k: os.environ.pop(k, None) for k in keys}
     yield
     for k, v in prior.items():
@@ -80,42 +81,21 @@ def test_partition_read_rule(F, read):
     assert treelib.partition_read(F) == read
 
 
-def test_host_histogram_bitexact_with_segment_packed_and_dense():
-    """The np.add.at host callback runs the same sequential in-order f32
-    fold as the XLA sorted scatter — bit-exact, packed or dense."""
-    rng = np.random.default_rng(2)
-    N, F, L = 4096, 6, 4
-    node = rng.integers(0, L, N).astype(np.int32)
-    g = rng.normal(size=N).astype(np.float32)
-    h = rng.random(N).astype(np.float32)
-    w = (rng.random(N) > 0.1).astype(np.float32)
-    for bits, B in ((4, 16), (5, 21), (6, 33)):
-        codes = rng.integers(0, B, (N, F)).astype(np.uint8)
-        pk = packing.pack_host(codes, bits)
-        ref = np.asarray(histogram.build_histograms(
-            jnp.asarray(codes), jnp.asarray(node), jnp.asarray(g),
-            jnp.asarray(h), jnp.asarray(w), L, B, method="segment"))
-        for codes_in, pb in ((codes, 0), (pk, bits)):
-            got = np.asarray(histogram.build_histograms(
-                jnp.asarray(codes_in), jnp.asarray(node), jnp.asarray(g),
-                jnp.asarray(h), jnp.asarray(w), L, B, method="host",
-                pack_bits=pb))
-            assert np.array_equal(ref, got), (bits, pb)
-
-
 # -- build_tree: the parity matrix ------------------------------------------
 
 @pytest.mark.parametrize("variant", [
-    "fused", "packed", "packed_fused", "mtries", "monotone",
+    "packed_seed2", "packed", "packed_fused", "mtries", "monotone",
     "alpha_lambda0", "bits4", "bits5", "bits6", "f7", "f130", "compact",
 ])
 def test_build_tree_fused_packed_parity(variant):
-    # the packed variants hold `_row_codes` inside the level loop: every
-    # pack width, a narrow frame (the dense select), a frame over
-    # `_ONEHOT_LOOKUP_MAX` (the gather branch) and the compact levels
+    # packed · dense codes through the same builder. The variants hold
+    # `_row_codes` inside the level loop: every pack width, a narrow frame
+    # (the dense select), a frame over `_ONEHOT_LOOKUP_MAX` (the gather
+    # branch) and the compact levels
     shape = {"bits4": dict(B=16), "bits5": dict(B=21), "bits6": dict(B=33),
              "f7": dict(F=7), "f130": dict(F=130, N=1024),
-             "compact": dict(B=16)}.get(variant, {})
+             "compact": dict(B=16), "packed_seed2": dict(seed=2),
+             }.get(variant, {})
     codes, g, h, w, fm, edges, B = _tree_data(**shape)
     bits = packing.pack_bits_for(B, codes.shape[0])
     if variant.startswith("bits"):
@@ -135,13 +115,8 @@ def test_build_tree_fused_packed_parity(variant):
         # levels 6 and 7 partition in compact slots (`bf[row_slot]` reads)
         kw.update(max_depth=8, min_rows=1.0, compact_cap=32)
     base = treelib.build_tree(jnp.asarray(codes), g, h, w, fm, edges, **kw)
-    fused_kw = dict(kw, fused_split=True)
-    if variant != "fused":
-        got = treelib.build_tree(jnp.asarray(pk), g, h, w, fm, edges,
-                                 pack_bits=bits, **fused_kw)
-    else:
-        got = treelib.build_tree(jnp.asarray(codes), g, h, w, fm, edges,
-                                 **fused_kw)
+    got = treelib.build_tree(jnp.asarray(pk), g, h, w, fm, edges,
+                             pack_bits=bits, **kw)
     assert _leaves_equal(base, got)
 
 
@@ -168,23 +143,23 @@ def _row_gathers_over(text, numels):
 @pytest.mark.parametrize("F,gathers", [(28, False), (130, True)])
 def test_packed_partition_issues_no_gather_into_the_code_matrix(F, gathers):
     """The guard against the gather's quiet return: the tree program at
-    the flagship's structure (5-bit packed codes, F = 28, fused split
-    search, depth 6) reads a row's code by the dense select — no gather
-    whose operand is the code matrix, packed or widened — while a frame
-    over `_ONEHOT_LOOKUP_MAX` still gathers."""
+    the flagship's structure (5-bit packed codes, F = 28, depth 6) reads a
+    row's code by the dense select — no gather whose operand is the code
+    matrix, packed or widened — while a frame over `_ONEHOT_LOOKUP_MAX`
+    still gathers."""
     N, B, depth = 4096, 21, 6
     codes, g, h, w, fm, edges, _ = _tree_data(N=N, F=F, B=B)
     pk = packing.pack_host(codes, 5)
     text = treelib.build_tree.lower(
         jnp.asarray(pk), g, h, w, fm, edges, max_depth=depth, nbins=B,
-        pack_bits=5, fused_split=True, hist_method="segment").as_text()
+        pack_bits=5, hist_method="segment").as_text()
     hits = _row_gathers_over(text, {pk.size, N * F})
     assert bool(hits) == gathers, hits
 
 
 def test_build_tree_compact_cap_parity_and_overflow_flag():
-    """Compact-phase split search + partition on packed/fused match the
-    legacy dense comparator, including the overflow flag the driver's
+    """Compact-phase split search + partition on packed codes match the
+    dense-code build, including the overflow flag the driver's
     dense-rebuild guard consumes."""
     codes, g, h, w, fm, edges, B = _tree_data(N=2048, F=9)
     bits = packing.pack_bits_for(B, codes.shape[0])
@@ -194,8 +169,7 @@ def test_build_tree_compact_cap_parity_and_overflow_flag():
     base = treelib.build_tree(jnp.asarray(codes), g, h, w, fm, edges,
                               compact_cap=64, **kw)
     got = treelib.build_tree(jnp.asarray(pk), g, h, w, fm, edges,
-                             compact_cap=64, pack_bits=bits,
-                             fused_split=True, **kw)
+                             compact_cap=64, pack_bits=bits, **kw)
     assert _leaves_equal(base, got)
     assert int(np.asarray(base[-1])) == int(np.asarray(got[-1]))
     # a cap too small for the live frontier must raise the flag on BOTH
@@ -203,18 +177,17 @@ def test_build_tree_compact_cap_parity_and_overflow_flag():
     *_, ov_l = treelib.build_tree(jnp.asarray(codes), g, h, w, fm, edges,
                                   compact_cap=4, **kw)
     *_, ov_f = treelib.build_tree(jnp.asarray(pk), g, h, w, fm, edges,
-                                  compact_cap=4, pack_bits=bits,
-                                  fused_split=True, **kw)
+                                  compact_cap=4, pack_bits=bits, **kw)
     assert int(np.asarray(ov_l)) > 0
     assert int(np.asarray(ov_l)) == int(np.asarray(ov_f))
 
 
-# -- whole-fit parity against the legacy flag -------------------------------
+# -- whole-fit parity: packed resident codes · full-width resident codes ----
 
 # ONE shared whole-fit shape: every driver-level test below uses the same
-# (row bucket, F, max_depth, nbins) so they all land on a single fused and
-# a single legacy compiled tree program — the fused body is ~2x the trace
-# work per structural config, so the suite pays it once, not per test.
+# (row bucket, F, max_depth, nbins) so they all land on a single packed and
+# a single full-width compiled tree program — the suite pays each trace
+# once, not per test.
 _FIT_N, _FIT_F, _FIT_DEPTH = 4096, 6, 4
 _FIT_X, _FIT_Y = make_classification(n=_FIT_N, f=_FIT_F, seed=7)
 _FIT_NAMES = [f"f{i}" for i in range(_FIT_F)] + ["label"]
@@ -227,25 +200,28 @@ def _frame(X, y, names):
                             names=names).asfactor("label")
 
 
-def _fit_gbm(legacy, X, y, names, overlap=None, **params):
-    from h2o3_tpu.models import dataset_cache
-    from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+def _train(est, X, y, names, overlap=None, full_width=False):
+    """Train on a cold dataset cache. `full_width` sends the fit down the
+    full-width resident path (no sub-byte packing of the resident codes),
+    the one DART, `checkpoint=`, lossguide and nbins > 256 fits run."""
+    from h2o3_tpu.models import dataset_cache, shared_tree
 
     dataset_cache.clear()
-    os.environ.pop("H2O3_TREE_LEGACY", None)
-    if legacy:
-        os.environ["H2O3_TREE_LEGACY"] = "1"
-    if overlap is not None:
-        os.environ["H2O3_TREE_OVERLAP"] = overlap
-    else:
-        os.environ.pop("H2O3_TREE_OVERLAP", None)
-    try:
-        gbm = H2OGradientBoostingEstimator(seed=42, **params)
-        gbm.train(y="label", training_frame=_frame(X, y, names))
-    finally:
-        os.environ.pop("H2O3_TREE_LEGACY", None)
-        os.environ.pop("H2O3_TREE_OVERLAP", None)
-    return gbm
+    with pytest.MonkeyPatch.context() as mp:
+        mp.delenv("H2O3_TREE_OVERLAP", raising=False)
+        if overlap is not None:
+            mp.setenv("H2O3_TREE_OVERLAP", overlap)
+        if full_width:
+            mp.setattr(shared_tree, "_pack_bits_for", lambda nbins, n: 0)
+        est.train(y="label", training_frame=_frame(X, y, names))
+    return est
+
+
+def _fit_gbm(X, y, names, overlap=None, full_width=False, **params):
+    from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+
+    return _train(H2OGradientBoostingEstimator(seed=42, **params), X, y,
+                  names, overlap=overlap, full_width=full_width)
 
 
 def _assert_models_bitexact(a, b):
@@ -263,20 +239,19 @@ def _assert_models_bitexact(a, b):
                                       [r[1] for r in vb])
 
 
-def test_gbm_fit_parity_fused_vs_legacy(cloud1, _no_legacy):
-    """Whole-fit pin: packed codes × fused split × overlapped scoring with
+def test_gbm_fit_parity_packed_vs_full_width(cloud1, _tree_env):
+    """Whole-fit pin: packed resident codes × overlapped scoring with
     early stopping produce the bit-identical forest, gain-based varimp,
-    scoring history, and predictions of the legacy path."""
+    scoring history, and predictions of the full-width resident path."""
     X, y, names = _FIT_X, _FIT_Y, _FIT_NAMES
     params = dict(ntrees=12, max_depth=_FIT_DEPTH, learn_rate=0.1,
                   score_tree_interval=3, stopping_rounds=2,
                   stopping_tolerance=1e-9)
-    # drop the host-kernel row floor so THIS fit exercises the full fused
-    # stack (packed codes + np.add.at host histograms + overlap) end to
-    # end; the other whole-fit tests keep the small-fit segment default
-    os.environ["H2O3_HOST_HIST_MIN_ROWS"] = "1"
-    new = _fit_gbm(False, X, y, names, **params)
-    old = _fit_gbm(True, X, y, names, **params)
+    resident_bits = lambda: histogram.kernel_stats()["plans"][-1]["pack_bits"]
+    new = _fit_gbm(X, y, names, **params)
+    assert resident_bits() in (4, 5, 6)
+    old = _fit_gbm(X, y, names, full_width=True, **params)
+    assert resident_bits() == 0
     _assert_models_bitexact(new, old)
     h_new = [e.get("logloss") for e in new.model.scoring_history]
     h_old = [e.get("logloss") for e in old.model.scoring_history]
@@ -288,70 +263,59 @@ def test_gbm_fit_parity_fused_vs_legacy(cloud1, _no_legacy):
                                   np.asarray(pb.vec("1").data))
 
 
-def test_gbm_fit_parity_overlap_off(cloud1, _no_legacy):
+def test_gbm_fit_parity_overlap_off(cloud1, _tree_env):
     """H2O3_TREE_OVERLAP=0 (no speculative chunk) is bit-identical to the
     overlapped default — overlap is a scheduling change, not a math one."""
     X, y, names = _FIT_X, _FIT_Y, _FIT_NAMES
     params = dict(ntrees=10, max_depth=_FIT_DEPTH, score_tree_interval=2,
                   stopping_rounds=1, stopping_tolerance=1e-9)
-    a = _fit_gbm(False, X, y, names, overlap="1", **params)
-    b = _fit_gbm(False, X, y, names, overlap="0", **params)
+    a = _fit_gbm(X, y, names, overlap="1", **params)
+    b = _fit_gbm(X, y, names, overlap="0", **params)
     _assert_models_bitexact(a, b)
 
 
-def test_early_stop_discards_speculative_chunk(cloud1, _no_legacy):
+def test_early_stop_discards_speculative_chunk(cloud1, _tree_env):
     """When the stopper FIRES with a speculative chunk in flight, the
     chunk is discarded and the pre-dispatch state restored: tree count,
     forest, and the training metrics computed from the restored margins
-    all match the legacy (never-speculated) path bit-for-bit."""
+    all match the never-speculated path (H2O3_TREE_OVERLAP=0) bit for bit."""
     X, y, names = _FIT_X, _FIT_Y, _FIT_NAMES
     # tiny learn rate + huge tolerance → the stopper fires mid-run
     params = dict(ntrees=40, max_depth=_FIT_DEPTH, learn_rate=0.01,
                   score_tree_interval=2, stopping_rounds=1,
                   stopping_tolerance=0.5)
-    new = _fit_gbm(False, X, y, names, **params)
-    old = _fit_gbm(True, X, y, names, **params)
+    new = _fit_gbm(X, y, names, **params)
+    old = _fit_gbm(X, y, names, overlap="0", **params)
     assert new.model.ntrees_built < 40, "stopper must fire for this pin"
     _assert_models_bitexact(new, old)
     np.testing.assert_array_equal(new.model.training_metrics.logloss(),
                                   old.model.training_metrics.logloss())
 
 
-def test_drf_fit_parity_fused_vs_legacy(cloud1, _no_legacy):
-    """DRF: per-node mtries column sampling + OOB scoring through the
-    packed/fused path match the legacy comparator bit-for-bit."""
-    from h2o3_tpu.models import dataset_cache
+def test_drf_fit_parity_packed_vs_full_width(cloud1, _tree_env):
+    """DRF: per-node mtries column sampling + OOB scoring on packed
+    resident codes match the full-width resident path bit for bit."""
     from h2o3_tpu.models.drf import H2ORandomForestEstimator
 
-    X, y, names = _FIT_X, _FIT_Y, _FIT_NAMES
-
-    def fit(legacy):
-        dataset_cache.clear()
-        os.environ.pop("H2O3_TREE_LEGACY", None)
-        if legacy:
-            os.environ["H2O3_TREE_LEGACY"] = "1"
-        try:
-            drf = H2ORandomForestEstimator(ntrees=8, max_depth=_FIT_DEPTH,
-                                           seed=42, score_tree_interval=4)
-            drf.train(y="label", training_frame=_frame(X, y, names))
-        finally:
-            os.environ.pop("H2O3_TREE_LEGACY", None)
-        return drf
+    def fit(full_width):
+        drf = H2ORandomForestEstimator(ntrees=8, max_depth=_FIT_DEPTH,
+                                       seed=42, score_tree_interval=4)
+        return _train(drf, _FIT_X, _FIT_Y, _FIT_NAMES, full_width=full_width)
 
     _assert_models_bitexact(fit(False), fit(True))
 
 
-def test_cv_fold_reuse_parity_fused_vs_legacy(cloud1, _no_legacy):
-    """CV fold reuse (PR 4) composes with the fused path: fold models
+def test_cv_fold_reuse_parity_packed_vs_full_width(cloud1, _tree_env):
+    """CV fold reuse (PR 4) composes with resident packing: fold models
     slice the parent's PACKED artifact and the cross-validated parent is
-    bit-identical to the legacy run's."""
+    bit-identical to the run whose folds slice a full-width one."""
     X, y, names = _FIT_X, _FIT_Y, _FIT_NAMES
     # folds inherit the parent's padded row bucket (_npad_floor), so even
     # the fold fits reuse the shared compiled programs
     params = dict(ntrees=6, max_depth=_FIT_DEPTH, nfolds=2,
                   keep_cross_validation_predictions=True)
-    new = _fit_gbm(False, X, y, names, **params)
-    old = _fit_gbm(True, X, y, names, **params)
+    new = _fit_gbm(X, y, names, **params)
+    old = _fit_gbm(X, y, names, full_width=True, **params)
     _assert_models_bitexact(new, old)
     ma = new.model.cross_validation_metrics
     mb = old.model.cross_validation_metrics
@@ -362,18 +326,18 @@ def test_cv_fold_reuse_parity_fused_vs_legacy(cloud1, _no_legacy):
 
 # -- the warm-fit zero-retrace pin (ROADMAP item 2) -------------------------
 
-def test_warm_fit_retraces_zero(cloud1, _no_legacy):
+def test_warm_fit_retraces_zero(cloud1, _tree_env):
     """A warm higgs-shaped fit (same _StepCfg; scalar hyperparameters may
     differ — they are traced, not static) must trace ZERO new programs and
     re-trace nothing, per the PR 6 per-signature XLA tracker."""
     from h2o3_tpu.runtime import phases
 
     X, y, names = _FIT_X, _FIT_Y, _FIT_NAMES
-    _fit_gbm(False, X, y, names, ntrees=5, max_depth=_FIT_DEPTH,
+    _fit_gbm(X, y, names, ntrees=5, max_depth=_FIT_DEPTH,
              learn_rate=0.1)
     before = phases.xla_counts()
     # warm fit: same structural shape, different traced scalar (learn_rate)
-    _fit_gbm(False, X, y, names, ntrees=5, max_depth=_FIT_DEPTH,
+    _fit_gbm(X, y, names, ntrees=5, max_depth=_FIT_DEPTH,
              learn_rate=0.2)
     after = phases.xla_counts()
     assert after["retraces"] == before["retraces"], \
@@ -384,22 +348,18 @@ def test_warm_fit_retraces_zero(cloud1, _no_legacy):
 
 # -- kernel-selection observability -----------------------------------------
 
-def test_fit_plan_recorded_and_profiler_fold(cloud1, _no_legacy):
+def test_fit_plan_recorded_and_profiler_fold(cloud1, _tree_env):
     X, y = make_classification(n=2048, f=5, seed=17)
     names = [f"f{i}" for i in range(5)] + ["label"]
-    # force the host lane explicitly: auto only picks it past MIN_ROWS
-    # AND with a spare core to service the callback (host_callback_safe —
-    # 1-core hosts keep `segment`), and this test pins the host lane's
-    # plan/dispatch observability, not the selection policy
-    os.environ["H2O3_HIST_METHOD"] = "host"
-    _fit_gbm(False, X, y, names, ntrees=2, max_depth=3)
+    _fit_gbm(X, y, names, ntrees=2, max_depth=3)
     stats = histogram.kernel_stats()
     assert stats["plans"], "fit recorded no kernel plan"
     plan = stats["plans"][-1]
-    assert plan["hist_method"] == "host"      # the fused CPU default
+    assert plan["hist_method"] == "auto"      # as given; resolved per level
     assert plan["pack_bits"] in (4, 5, 6)
-    assert all(lv["method"] == "host" for lv in plan["levels"])
-    assert stats["dispatch"].get("host", 0) > 0
+    # the one rule: a CPU runs the sorted scatter
+    assert all(lv["method"] == "segment" for lv in plan["levels"])
+    assert stats["dispatch"].get("segment", 0) > 0
     from h2o3_tpu.runtime import profiler
 
     fold = profiler.tree_stats()
@@ -411,13 +371,13 @@ def test_fit_plan_recorded_and_profiler_fold(cloud1, _no_legacy):
     assert "h2o3_tree_hist_dispatch_total" in text
 
 
-def test_partition_read_recorded_in_kernel_stats(cloud1, _no_legacy):
+def test_partition_read_recorded_in_kernel_stats(cloud1, _tree_env):
     """Which read the partition took is recorded beside the kernel plan:
     per fit in the plan, cumulatively (trace-time) in the registry, and
     folded into the profiler's `tree` surface."""
     X, y = make_classification(n=2048, f=5, seed=19)
     names = [f"f{i}" for i in range(5)] + ["label"]
-    _fit_gbm(False, X, y, names, ntrees=2, max_depth=3)
+    _fit_gbm(X, y, names, ntrees=2, max_depth=3)
     stats = histogram.kernel_stats()
     assert stats["plans"][-1]["partition_read"] == "select"
     assert stats["partition_read"].get("select", 0) > 0
@@ -437,7 +397,7 @@ def test_partition_read_recorded_in_kernel_stats(cloud1, _no_legacy):
     assert "h2o3_tree_partition_read_total" in metrics_registry.prometheus_text()
 
 
-def test_vmem_fallback_counted_and_logged(_no_legacy):
+def test_vmem_fallback_counted_and_logged(_tree_env):
     """The previously-silent `_factored_row_chunk` < 512 fallback is
     observable: resolve_method reports it, record_fit_plan counts it in
     the registry and logs once per fit."""
@@ -458,12 +418,9 @@ def test_vmem_fallback_counted_and_logged(_no_legacy):
     after = metrics_registry.get("h2o3_tree_hist_vmem_fallbacks").total()
     assert after == before + 1
     assert [lv["fallback"] for lv in plan["levels"]] == [None, "vmem"]
-    # the host callback can never run under a collective program
-    sel = histogram.resolve_method(4, 21, "host", axis_name="hosts")
-    assert sel["method"] == "segment" and sel["fallback"] == "collective"
 
 
-def test_dataset_cache_keys_pack_mode(cloud1, _no_legacy):
+def test_dataset_cache_keys_pack_mode(cloud1, _tree_env):
     """A packed and a full-width consumer never share a device artifact."""
     from h2o3_tpu.frame.frame import Frame
     from h2o3_tpu.models import dataset_cache
@@ -481,31 +438,24 @@ def test_dataset_cache_keys_pack_mode(cloud1, _no_legacy):
     assert len(calls) == 2   # 0-bit and 5-bit miss; second 5-bit hits
 
 
-# -- the forced-CPU bench floor (acceptance) --------------------------------
+@pytest.mark.parametrize("method", ["pallas", "host"])
+def test_removed_hist_methods_are_refused(cloud1, _tree_env, method):
+    """A kernel name that no longer exists is refused with the valid names,
+    from the estimator's `hist_method=` and from H2O3_HIST_METHOD alike,
+    before anything is traced."""
+    from h2o3_tpu.runtime import phases
 
-@pytest.mark.slow
-def test_gbm_cpu_fused_speedup_floor(cloud1, _no_legacy):
-    """BENCH_CONFIG=gbm_cpu acceptance: the fused kernel is ≥1.5× the
-    legacy kernel on the forced-CPU lane (measured ~6-9× on the dev box;
-    the floor absorbs scheduler noise)."""
-    import time
-
-    X, y = make_classification(n=60_000, f=28, seed=42, informative=8)
-    names = [f"f{i}" for i in range(28)] + ["label"]
-    params = dict(ntrees=10, max_depth=6, learn_rate=0.1,
-                  histogram_type="UniformAdaptive")
-
-    def wall(legacy, reps):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            _fit_gbm(legacy, X, y, names, **params)
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    # best-of-2 BOTH ways: each path's rep 1 absorbs its own trace/compile,
-    # so the floor compares warm kernel against warm kernel
-    w_new = wall(False, 2)
-    w_old = wall(True, 2)
-    assert w_old / w_new >= 1.5, \
-        f"fused {w_new:.2f}s vs legacy {w_old:.2f}s — floor 1.5x missed"
+    X, y = make_classification(n=512, f=4, seed=29)
+    names = [f"f{i}" for i in range(4)] + ["label"]
+    _fit_gbm(X, y, names, ntrees=1, max_depth=2)       # frame-side programs
+    before = phases.xla_counts()["traces"]
+    valid = "auto, onehot, segment"
+    with pytest.raises(ValueError, match=valid):
+        _fit_gbm(X, y, names, ntrees=1, max_depth=2, hist_method=method)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("H2O3_HIST_METHOD", method)
+        with pytest.raises(ValueError, match=valid):
+            _fit_gbm(X, y, names, ntrees=1, max_depth=2)
+    assert phases.xla_counts()["traces"] == before
+    with pytest.raises(ValueError, match=valid):
+        histogram.resolve_method(4, 21, method)

@@ -38,8 +38,8 @@ from conftest import make_classification
 @pytest.fixture()
 def _shard_env():
     """Isolate the sharding env knobs per test."""
-    keys = ("H2O3_TREE_SHARD", "H2O3_TREE_SHARD_BLOCKS", "H2O3_TREE_LEGACY",
-            "H2O3_HIST_METHOD", "H2O3_HOST_HIST_MIN_ROWS")
+    keys = ("H2O3_TREE_SHARD", "H2O3_TREE_SHARD_BLOCKS",
+            "H2O3_HIST_METHOD")
     prior = {k: os.environ.pop(k, None) for k in keys}
     yield
     for k, v in prior.items():
@@ -65,14 +65,10 @@ def test_shard_plan_rules(_shard_env):
     os.environ["H2O3_TREE_SHARD_BLOCKS"] = "16"
     assert shared_tree._shard_plan(4, False, tp) == ("mesh", 16)
     os.environ.pop("H2O3_TREE_SHARD_BLOCKS", None)
-    # legacy comparator / lossguide / multiproc keep the psum path
-    os.environ["H2O3_TREE_LEGACY"] = "1"
-    assert shared_tree._shard_plan(8, False, tp)[0] == "mesh_psum"
-    # ...but the escape hatch overrides legacy/lossguide (a broken mesh
-    # must not run THEIR collectives either)...
+    # lossguide keeps the psum path, but the escape hatch overrides it (a
+    # broken mesh must not run ITS collectives either)...
     os.environ["H2O3_TREE_SHARD"] = "0"
     assert shared_tree._shard_plan(8, False, tp) == ("off", 0)
-    os.environ.pop("H2O3_TREE_LEGACY", None)
     assert shared_tree._shard_plan(
         8, False, {"grow_policy": "lossguide"}) == ("off", 0)
     # ...while multi-process clouds ignore it (their rows live on other
@@ -86,27 +82,23 @@ def test_shard_plan_rules(_shard_env):
 def test_fit_plan_records_shards(_shard_env):
     """The /3/Profiler tree fold's per-fit plans carry the shard geometry
     (n_shards / n_devices / pack_bits) — the ISSUE 12 observability
-    satellite — and the collective-safe kernel substitution still holds."""
+    satellite."""
     plan = histogram.record_fit_plan(
         "test:sharded", [("d0", 1), ("d1", 1)], 21, "auto",
-        pack_bits=5, axis_name=cloudlib.ROWS_AXIS, n_shards=8, n_devices=8)
+        pack_bits=5, n_shards=8, n_devices=8)
     assert plan["n_shards"] == 8 and plan["n_devices"] == 8
     assert plan["pack_bits"] == 5
     from h2o3_tpu.runtime import profiler
 
     fold = profiler.tree_stats()
     assert fold["plans"][-1]["n_shards"] == 8
-    # the host callback can never run under a collective program
-    sel = histogram.resolve_method(4, 21, "host", axis_name="hosts")
-    assert sel["method"] == "segment" and sel["fallback"] == "collective"
 
 
 # -- kernel-level shard invariance ------------------------------------------
 
 def test_blocked_histograms_shard_invariant(cloud8, _shard_env):
     """8 devices × 1 block/device == 1 device × 8 blocks, bitwise — for the
-    in-graph segment kernel (mesh lane) AND the np.add.at host callback
-    (forced-CPU lane), packed and dense. The plain single-fold path stays
+    in-graph segment kernel, packed and dense. The plain single-fold path stays
     untouched (last-ulp different), which is exactly why the sharded lane
     needs its own canonical reduction."""
     rng = np.random.default_rng(2)
@@ -137,14 +129,13 @@ def test_blocked_histograms_shard_invariant(cloud8, _shard_env):
             jax.device_put(jnp.asarray(g), rs),
             jax.device_put(jnp.asarray(h), rs),
             jax.device_put(jnp.asarray(w), rs)))
-        for meth in ("segment", "host"):
-            got = np.asarray(jax.jit(
-                lambda c, n_, g_, h_, w_, m=meth: histogram.build_histograms(
-                    c, n_, g_, h_, w_, L, B, method=m, pack_bits=pb,
-                    n_shard_blocks=S)
-            )(jnp.asarray(codes_in), jnp.asarray(node), jnp.asarray(g),
-              jnp.asarray(h), jnp.asarray(w)))
-            assert np.array_equal(h8, got), (pb, meth)
+        got = np.asarray(jax.jit(
+            lambda c, n_, g_, h_, w_: histogram.build_histograms(
+                c, n_, g_, h_, w_, L, B, method="segment", pack_bits=pb,
+                n_shard_blocks=S)
+        )(jnp.asarray(codes_in), jnp.asarray(node), jnp.asarray(g),
+          jnp.asarray(h), jnp.asarray(w)))
+        assert np.array_equal(h8, got), pb
 
 
 def test_build_tree_sharded_parity_combined(cloud8, _shard_env):
@@ -181,7 +172,7 @@ def test_build_tree_sharded_parity_combined(cloud8, _shard_env):
                 max_depth=D, nbins=B, min_rows=2.0,
                 reg_lambda=0.5, reg_alpha=0.25,
                 mtries_rate=jnp.float32(0.6), monotone=jnp.asarray(mono),
-                fused_split=True, pack_bits=bits,
+                pack_bits=bits,
                 axis_name=axis, n_shard_blocks=nblocks)
         return fn
 

@@ -9,7 +9,10 @@ cumsums) these tests FAIL: the tail child's HR comes out as -1.0, HR + lambda
 as 0, its gain as inf, and every search splits 11 rows off the node on
 (feature 1, bin 17) where (feature 0, bin 9) gains 1,760,000; with weighted
 rows a tail child's WR is off by up to a float32 ulp of 7.7e6, several per
-cent of the child. The old code is not kept to prove it."""
+cent of the child. The old code is not kept to prove it.
+
+`plain_level_best` is the reference `_fused_level_best` is held to, bit for
+bit, on random histograms: a flat argmax over (L, F, B) gains."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -70,6 +73,63 @@ def oracle(hist32, min_rows):
             "ok": ok[0], "WR": right(w)[0]}
 
 
+def plain_level_best(hist, node_ok, feat_mask, keep, nbins: int, min_rows,
+                     reg_lambda, reg_alpha, gsum, hsum,
+                     monotone=None, lo_lvl=None, hi_lvl=None):
+    """The tests' reference for `tree._fused_level_best`: the plain split
+    search of a dense or a compact level, gain per (L, F, B) from
+    `_split_sums`, one flat argmax. Same arguments and results, except that
+    the child values are None without `monotone`."""
+    L, F = hist.shape[0], hist.shape[1]
+    WL, GL, HL, WR, GR, HR = treelib._split_sums(hist)
+    G = gsum[:, None, None]
+    H = hsum[:, None, None]
+    # xgboost CalcSplitGain: L1 soft-threshold the gradient sums
+    # before squaring (ThresholdL1); exact no-op at reg_alpha=0
+    tl1 = lambda A: jnp.sign(A) * jnp.maximum(jnp.abs(A) - reg_alpha, 0.0)
+    GLt, GRt, Gt = tl1(GL), tl1(GR), tl1(G)
+    gain = (
+        GLt * GLt / (HL + reg_lambda)
+        + GRt * GRt / (HR + reg_lambda)
+        - Gt * Gt / (H + reg_lambda)
+    )
+    ok = (WL >= min_rows) & (WR >= min_rows)
+    ok = ok & (jnp.arange(nbins)[None, None, :] < nbins - 1)   # no split at NA bin
+    ok = ok & (feat_mask[None, :, None] > 0)
+    ok = ok & node_ok[:, None, None]
+    if monotone is not None:
+        # monotone_constraints (hex/tree Constraints / LightGBM): a
+        # split on feature f with constraint c is admissible only
+        # when c·(value_right − value_left) ≥ 0, where the child
+        # values use the SAME soft-thresholded formula as
+        # materialized node values and are clamped into the node's
+        # inherited bounds. Bound propagation (in `build_tree`) then
+        # guarantees zero violations.
+        vL = jnp.clip(-GLt / (HL + reg_lambda + 1e-12),
+                      lo_lvl[:, None, None], hi_lvl[:, None, None])
+        vR = jnp.clip(-GRt / (HR + reg_lambda + 1e-12),
+                      lo_lvl[:, None, None], hi_lvl[:, None, None])
+        mc = monotone[None, :, None]
+        ok = ok & ((mc == 0) | (mc * (vR - vL) >= 0))
+    if keep is not None:
+        ok = ok & keep[:, :, None]
+    gain = jnp.where(ok, gain, -jnp.inf)
+
+    flat = gain.reshape(L, F * nbins)
+    best = jnp.argmax(flat, axis=1)
+    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+    bf = (best // nbins).astype(jnp.int32)
+    bb = (best % nbins).astype(jnp.int32)
+    vLs = vRs = None
+    if monotone is not None:
+        # child values at the chosen split, gathered from the SAME
+        # vL/vR used by the admissibility check (bound propagation)
+        flat_pick = lambda A: jnp.take_along_axis(
+            A.reshape(L, F * nbins), best[:, None], axis=1)[:, 0]
+        vLs, vRs = flat_pick(vL), flat_pick(vR)
+    return best_gain, bf, bb, vLs, vRs
+
+
 def totals(hist):
     w, g, h = treelib._node_totals(jnp.asarray(hist))
     return g, h
@@ -85,7 +145,7 @@ def via_fused(hist, search=None):
 
 
 def via_flat(hist):
-    return via_fused(hist, treelib._flat_level_best)
+    return via_fused(hist, plain_level_best)
 
 
 def via_search_splits(hist):
@@ -94,18 +154,18 @@ def via_search_splits(hist):
     return int(bf[0]), int(bb[0]), float(bg[0])
 
 
-def via_build_tree(hist, monkeypatch, fused: bool):
+def via_build_tree(hist, monkeypatch):
     """The whole builder, one level deep, on the crafted root histogram."""
     monkeypatch.setattr(treelib, "build_histograms",
                         lambda *a, **k: jnp.asarray(hist))
     # the patched histogram is a constant of the trace: every case gets a
     # row count nothing else has traced
-    n = 24 + int(fused) + 2 * int(float(hist[0, 0, 0, 0]) != 550_000)
+    n = 24 + 2 * int(float(hist[0, 0, 0, 0]) != 550_000)
     tr, _, gains, _ = treelib.build_tree(
         jnp.zeros((n, 3), jnp.uint8), jnp.zeros(n), jnp.ones(n), jnp.ones(n),
         jnp.ones(3, jnp.float32), jnp.zeros((3, B - 2), jnp.float32),
         max_depth=1, nbins=B, min_rows=MIN_ROWS, reg_lambda=LAM,
-        hist_method="onehot", fused_split=fused)
+        hist_method="onehot")
     assert bool(tr.is_split[0])
     return int(tr.feat[0]), int(tr.bin[0]), float(gains.sum())
 
@@ -114,8 +174,7 @@ SEARCHES = {
     "fused": lambda hist, mp: via_fused(hist),
     "flat": lambda hist, mp: via_flat(hist),
     "search_splits": lambda hist, mp: via_search_splits(hist),
-    "build_tree_fused": lambda hist, mp: via_build_tree(hist, mp, True),
-    "build_tree_flat": lambda hist, mp: via_build_tree(hist, mp, False),
+    "build_tree": lambda hist, mp: via_build_tree(hist, mp),
 }
 
 
@@ -161,3 +220,69 @@ def test_every_split_search_takes_the_float64_best(monkeypatch, search,
 def test_the_searches_agree_bit_for_bit():
     hist = crafted(0.7)
     assert via_fused(hist) == via_flat(hist) == via_search_splits(hist)
+
+
+def random_level(seed: int, L: int, F: int = 7):
+    """(L, F, B, 3) float32 histogram of small integer counts, many bins
+    empty: equal gains (ties; feature 5 repeats feature 1) and, without
+    lambda, 0/0 gains are common."""
+    rng = np.random.default_rng(seed)
+    w = rng.integers(0, 4, size=(L, F, B)) * (rng.random((L, F, B)) < 0.6)
+    g = rng.integers(-3, 4, size=(L, F, B)) * (w > 0)
+    h = 0.25 * w
+    hist = np.stack([w, g, h], axis=-1).astype(np.float32)
+    hist[:, 5] = hist[:, 1]     # a twin feature: its every gain is a tie
+    return hist
+
+
+FUSED_CASES = {
+    "plain": dict(L=8),
+    "keep": dict(L=8, keep=True),
+    "monotone": dict(L=4, monotone=True),
+    "alpha_lambda0": dict(L=8, lam=0.0, alpha=0.5, min_rows=0.0),
+    # a compact level: CAP + 1 slots, the trash slot and dead slots masked
+    "compact": dict(L=9, keep=True, dead=(2, 5, 8)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_level_best_matches_plain_search(case):
+    c = FUSED_CASES[case]
+    L, F = c["L"], 7
+    lam, alpha = c.get("lam", LAM), c.get("alpha", 0.0)
+    rng = np.random.default_rng(11)
+    node_ok = np.ones(L, bool)
+    node_ok[list(c.get("dead", ()))] = False
+    keep = mono = lo = hi = None
+    if c.get("keep"):
+        keep = rng.random((L, F)) < 0.5
+        keep[:, 0] |= ~keep.any(axis=1)
+        keep = jnp.asarray(keep)
+    if c.get("monotone"):
+        mono = jnp.asarray(rng.integers(-1, 2, size=F).astype(np.float32))
+        lo = jnp.asarray(rng.uniform(-2.0, -0.1, L).astype(np.float32))
+        hi = jnp.asarray(rng.uniform(0.1, 2.0, L).astype(np.float32))
+    feat_mask = jnp.asarray((np.arange(F) != 3).astype(np.float32))
+    saw_nan = False
+    for seed in range(6):
+        hist = jnp.asarray(random_level(seed, L, F))
+        _, g, h = treelib._node_totals(hist)
+        args = (hist, jnp.asarray(node_ok), feat_mask, keep, B,
+                c.get("min_rows", 2.0), lam, alpha, g, h)
+        kw = dict(monotone=mono, lo_lvl=lo, hi_lvl=hi)
+        got = treelib._fused_level_best(*args, **kw)
+        want = plain_level_best(*args, **kw)
+        for a, b in zip(got[:3], want[:3]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if mono is not None:
+            # the child values feed the bound propagation where a node splits
+            split = np.isfinite(np.asarray(want[0]))
+            assert split.any()
+            for a, b in zip(got[3:], want[3:]):
+                np.testing.assert_array_equal(np.asarray(a)[split],
+                                              np.asarray(b)[split])
+        best = np.asarray(want[0])
+        saw_nan |= bool(np.isnan(best).any())
+        assert (best[~node_ok] == -np.inf).all()
+    if case == "alpha_lambda0":
+        assert saw_nan      # a NaN gain wins, first occurrence, on both

@@ -26,6 +26,7 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from h2o3_tpu.models import block_store as bslib
@@ -37,8 +38,8 @@ from h2o3_tpu.runtime.timeline import Timeline
 from conftest import make_classification
 
 _ENV_KEYS = ("H2O3_TREE_OOC", "H2O3_STREAM_BLOCKS", "H2O3_STREAM_BUDGET_MB",
-             "H2O3_TREE_SHARD", "H2O3_TREE_SHARD_BLOCKS", "H2O3_TREE_LEGACY",
-             "H2O3_HIST_METHOD", "H2O3_HOST_HIST_MIN_ROWS",
+             "H2O3_TREE_SHARD", "H2O3_TREE_SHARD_BLOCKS",
+             "H2O3_HIST_METHOD",
              "H2O3_MEM_BUDGET_MB", "H2O3_MEM_EVICT_PRESSURE",
              "H2O3_STREAM_HOST_BUDGET_MB", "H2O3_TREE_OOC_DISK",
              "H2O3_SPILL_DIR")
@@ -370,11 +371,12 @@ def test_streamed_drf_bitexact_vs_incore(cloud1, _ooc_env):
     _assert_bitexact(a, b)
 
 
-def test_streamed_host_kernel_lane_bitexact(cloud1, _ooc_env):
-    """The host-histogram lane (np.add.at via the ONE dedicated worker,
-    never pure_callback) is bit-exact with the in-core host lane."""
-    env_a = dict(_STREAM_ENV, H2O3_HOST_HIST_MIN_ROWS="1")
-    env_b = dict(_INCORE_ENV, H2O3_HOST_HIST_MIN_ROWS="1")
+def test_streamed_onehot_kernel_lane_bitexact(cloud1, _ooc_env):
+    """The streamed blocks take whatever kernel the fit names: on the
+    one-hot matmul kernel too, each block partial is the in-core blocked
+    reduction's, bit for bit."""
+    env_a = dict(_STREAM_ENV, H2O3_HIST_METHOD="onehot")
+    env_b = dict(_INCORE_ENV, H2O3_HIST_METHOD="onehot")
     params = dict(ntrees=4, max_depth=3, learn_rate=0.2)
     _assert_bitexact(_fit(env_a, **params), _fit(env_b, **params))
 
@@ -634,9 +636,9 @@ def test_streamed_partition_matches_dense_walk(cloud1, bits, B):
     want = 2 * idx + ((codes[np.arange(N), bf[idx]] > bb[idx])
                       & do_split[idx]).astype(np.int32)
     block = packing.pack_host(codes, bits) if bits else codes
-    got = tree_stream._partition_jit(
+    got = jax.jit(tree_stream._partition, static_argnums=(5, 6))(
         jnp.asarray(block), jnp.asarray(idx), jnp.asarray(bf),
-        jnp.asarray(bb), jnp.asarray(do_split), L=L, pack_bits=bits)
+        jnp.asarray(bb), jnp.asarray(do_split), L, bits)
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
@@ -661,12 +663,7 @@ def test_oversubscribed_whole_fit_stays_under_budget(cloud1, _ooc_env):
     assert st["resident_block_peak"] <= budget
     assert st["blocks_evicted"] > 0
     assert float(est.auc()) > 0.75
-    # streamed vs in-core bit-exactness at this scale: the in-core
-    # comparator only picks the host np.add.at kernel when a spare core
-    # can service the callback (`host_callback_safe` — the 1-core
-    # in-graph-callback deadlock this test used to dodge with a raised
-    # MIN_ROWS is now gated out at method selection), and host and
-    # segment are pinned bit-equal, so the pair compares on any host
+    # streamed vs in-core bit-exactness at this scale
     params = dict(ntrees=3, max_depth=4)
     env_a = {"H2O3_TREE_OOC": "1", "H2O3_STREAM_BUDGET_MB": "0.015"}
     env_b = dict(_INCORE_ENV, H2O3_TREE_SHARD_BLOCKS=str(st["blocks"]))
