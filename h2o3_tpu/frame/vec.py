@@ -15,7 +15,7 @@ NaN for real/int (stored f32/f64), -1 for enum codes, None in string pool.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -127,8 +127,78 @@ def _intern_enum(col: np.ndarray, na_tokens=("", "NA", "na", None),
     return Vec(full, "enum", domain=labels)
 
 
+class Rollup(NamedTuple):
+    """What a Vec remembers of its values (`water/fvec/RollupStats.java`):
+    `min` and `max` over the non-NA values (NaN when there are none, and
+    for a ``string`` Vec) and the NA count."""
+    min: float
+    max: float
+    nacnt: int
+
+
+_ROLLUP_BLOCK = 1 << 20
+_ROLLUP_COUNTER = None
+
+
+def _count_where(pred, a: np.ndarray) -> int:
+    """How many elements of `a` satisfy `pred`, block by block: the mask
+    is never as long as the column."""
+    return sum(int(np.count_nonzero(pred(a[i:i + _ROLLUP_BLOCK])))
+               for i in range(0, a.shape[0], _ROLLUP_BLOCK))
+
+
+def _scan(v: "Vec") -> Rollup:
+    """One Vec's rollup by reductions over `v.data` in its own dtype, with
+    no column-sized temporary. A clean column costs two passes: `min()`
+    propagates NaN, so a non-NaN minimum also says the NA count is 0."""
+    nan = float("nan")
+    if v.type == "string":
+        return Rollup(nan, nan, sum(1 for s in v._strings if s is None))
+    a = v.data
+    if a.shape[0] == 0:
+        return Rollup(nan, nan, 0)
+    if v.type == "enum":
+        lo, hi = int(a.min()), int(a.max())
+        if hi < 0:
+            return Rollup(nan, nan, int(a.shape[0]))
+        if lo >= 0:
+            return Rollup(float(lo), float(hi), 0)
+        # NA codes are negative: as uint32 they sort above every level
+        return Rollup(float(a.view(np.uint32).min()), float(hi),
+                      _count_where(lambda c: c < 0, a))
+    lo = a.min()
+    if lo == lo:
+        return Rollup(float(lo), float(a.max()), 0)
+    # fmin / fmax skip NaN, and answer NaN only where nothing else is there
+    return Rollup(float(np.fmin.reduce(a)), float(np.fmax.reduce(a)),
+                  _count_where(np.isnan, a))
+
+
+def _count_rollup(result: str) -> None:
+    """Count one `Vec.rollup()` request, "computed" or "reused": in the
+    registry, and on the open span if it keeps such a tally (`train.resolve`
+    opens with `rollups_computed` and `rollups_reused` at 0)."""
+    global _ROLLUP_COUNTER
+    from ..runtime import tracing
+
+    c = _ROLLUP_COUNTER
+    if c is None:
+        from ..runtime import metrics_registry as _reg
+
+        c = _ROLLUP_COUNTER = _reg.counter(
+            "h2o3_vec_rollup",
+            "Vec rollup (min, max, NA count) requests: computed = scanned "
+            "the column, reused = read what the Vec remembered",
+            labelnames=("result",))
+    c.inc(1.0, result)
+    sp = tracing.current()
+    key = "rollups_" + result
+    if sp is not None and key in sp.attrs:
+        sp.attrs[key] += 1
+
+
 class Vec:
-    __slots__ = ("data", "type", "domain", "_strings")
+    __slots__ = ("data", "type", "domain", "_strings", "_rollup")
 
     def __init__(
         self,
@@ -226,20 +296,49 @@ class Vec:
         return a
 
     # -- stats (the rollups of water/fvec/RollupStats.java) ------------------
-    def mean(self) -> float:
-        return float(np.nanmean(self.numeric_np()))
+    def _memo(self) -> dict:
+        """What this Vec remembers of the array it holds. `data` is bound
+        once, in `__init__`, and never written into (the rule
+        `dataset_cache._frame_key` lives by): a new column is a new Vec,
+        which starts empty. The memo is kept beside the array it was
+        computed from and dropped if `data` is ever another object; a Vec
+        unpickled from before the slot existed has none yet."""
+        src = self._strings if self.type == "string" else self.data
+        m = getattr(self, "_rollup", None)
+        if m is None or m[0] is not src:
+            m = self._rollup = (src, {})
+        return m[1]
 
-    def sd(self) -> float:
-        return float(np.nanstd(self.numeric_np(), ddof=1))
+    def rollup(self) -> Rollup:
+        """min, max and NA count: scanned on the first request, remembered
+        for every later one."""
+        memo = self._memo()
+        r = memo.get("rollup")
+        _count_rollup("computed" if r is None else "reused")
+        if r is None:
+            r = memo["rollup"] = _scan(self)
+        return r
 
     def min(self) -> float:
-        return float(np.nanmin(self.numeric_np()))
+        return self.rollup().min
 
     def max(self) -> float:
-        return float(np.nanmax(self.numeric_np()))
+        return self.rollup().max
 
     def nacnt(self) -> int:
-        return int(self.isna_np().sum())
+        return self.rollup().nacnt
+
+    def mean(self) -> float:
+        memo = self._memo()
+        if "mean" not in memo:
+            memo["mean"] = float(np.nanmean(self.numeric_np()))
+        return memo["mean"]
+
+    def sd(self) -> float:
+        memo = self._memo()
+        if "sd" not in memo:
+            memo["sd"] = float(np.nanstd(self.numeric_np(), ddof=1))
+        return memo["sd"]
 
     def take(self, idx: np.ndarray) -> "Vec":
         if self.type == "string":

@@ -893,7 +893,9 @@ class H2OEstimator:
         # candidate that called, or one minted here
         with tracing.span("train", kind="fit", algo=self.algo,
                           rows=int(training_frame.nrow)) as sp:
-            with tracing.span("train.resolve", kind="fit"):
+            # the Vec rollups the screen reads tally themselves on this span
+            with tracing.span("train.resolve", kind="fit",
+                              rollups_computed=0, rollups_reused=0):
                 x, training_frame, validation_frame, nfolds = self._resolve(
                     x, y, training_frame, validation_frame)
             sp.annotate(predictors=len(x))
@@ -941,13 +943,9 @@ class H2OEstimator:
         if self._is_supervised() and y is not None:
             # rows with a missing response are dropped before training —
             # ModelBuilder.init response filtering (hex/ModelBuilder.java)
-            na = training_frame.vec(y).isna_np()
-            if na.any():
-                training_frame = training_frame.take(np.nonzero(~na)[0])
+            training_frame = _with_response(training_frame, y)
             if validation_frame is not None:
-                nav = validation_frame.vec(y).isna_np()
-                if nav.any():
-                    validation_frame = validation_frame.take(np.nonzero(~nav)[0])
+                validation_frame = _with_response(validation_frame, y)
 
         # a REST-created Job (h_train) rides through so /3/Jobs progress and
         # cancellation act on THE job driving this estimator
@@ -1193,11 +1191,17 @@ def warn_host_solver(algo: str, n_rows: int, bound: int = 500_000) -> None:
 
 
 def _is_const(v: Vec) -> bool:
-    if v.type == "string":
-        return False
-    a = v.numeric_np()
-    fin = a[~np.isnan(a)]
-    return fin.size > 0 and float(fin.min()) == float(fin.max())
+    r = v.rollup()
+    return r.nacnt < len(v) and r.min == r.max
+
+
+def _with_response(fr: Frame, y: str) -> Frame:
+    """`fr` without the rows whose response is NA; `fr` itself when the
+    response's rollup says there are none."""
+    v = fr.vec(y)
+    if v.nacnt() == 0:
+        return fr
+    return fr.take(np.nonzero(~v.isna_np())[0])
 
 
 def response_info(yvec: Vec):
