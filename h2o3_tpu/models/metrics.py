@@ -425,10 +425,14 @@ def ndcg_at_k(y: np.ndarray, score: np.ndarray, qid: np.ndarray, k: int = 10) ->
     """NDCG@k grouped by query — the lambdarank objective's eval metric
     (XGBoost `rank:ndcg`, used by the MSLR-WEB30K baseline config)."""
     total, nq = 0.0, 0
-    for q in np.unique(qid):
-        m = qid == q
-        rel = np.asarray(y)[m]
-        s = np.asarray(score)[m]
+    y, score, qid = np.asarray(y), np.asarray(score), np.asarray(qid)
+    # one stable sort by query, then a slice a query (queries in ascending
+    # id, rows in frame order): a mask a query reads all N rows Q times
+    by_q = np.argsort(qid, kind="mergesort")
+    cuts = np.flatnonzero(np.diff(qid[by_q])) + 1
+    for m in np.split(by_q, cuts):
+        rel = y[m]
+        s = score[m]
         if len(rel) < 2:
             continue
         order = np.argsort(-s, kind="mergesort")

@@ -826,14 +826,17 @@ def _build_tree_step_fns(cfg: _StepCfg, cloud):
             oob_cnt = oob_cnt + oob_mask
         return margins, oob_sum, oob_cnt, _pack(stacked, covers), gains, ov
 
-    single_jit = jax.jit(
-        lambda margins, codes_a, y_a, w_a, rate_a, edges_a, mono, hp, key, m, g_ext, h_ext: (
-            lambda r: (r[0], _pack(r[1], r[2]), r[3])
-        )(_one_tree(margins, codes_a, y_a, w_a, rate_a, edges_a, mono, hp,
-                    jax.random.fold_in(key, m), m, g_ext, h_ext)),
-        donate_argnums=(0,),
-    )
-    return tree_jit, single_jit
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def single_tree_jit(margins, codes_a, y_a, w_a, rate_a, edges_a, mono,
+                        hp, key, m, g_ext, h_ext):
+        """One tree from gradients the caller computed (a custom
+        objective's round): the trace calls it `jit_single_tree_jit`."""
+        margins, stacked, covers, gains, _, _, _ = _one_tree(
+            margins, codes_a, y_a, w_a, rate_a, edges_a, mono, hp,
+            jax.random.fold_in(key, m), m, g_ext, h_ext)
+        return margins, _pack(stacked, covers), gains
+
+    return tree_jit, single_tree_jit
 
 
 _DEV_PACKS: List = []  # weakrefs of models holding HBM forest packs (FIFO)
@@ -2331,6 +2334,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         # and margins_v is fast-forwarded through the restored forest.
         valid_state = None
         if valid is not None:
+            _ph.stage("design.validation")
             Xv, _, _ = frame_to_matrix(valid, x, expected_domains=bm.domains)
             codes_np_v = bin_apply(bm, Xv)
             yvv = valid.vec(y)
@@ -2360,7 +2364,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     ).astype(jnp.float32),
                     out_shardings=rs_v)(np.asarray(f0).reshape(-1))
             else:
-                codes_v = jnp.asarray(codes_np_v)
+                codes_v = _phases_acct.accounted_h2d(
+                    lambda: jnp.asarray(codes_np_v), codes_np_v.nbytes)
                 y_dev_v = jnp.asarray(ykv)
                 vmask_d = jnp.ones(n_v, jnp.float32)
                 margins_v = jnp.broadcast_to(
@@ -2442,7 +2447,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
             plan_tag, plan_levels, nbins, cfg.hist_method,
             pack_bits=cfg.pack_bits,
             n_shards=cfg.n_shards, n_devices=ndev_eff,
-            partition_read=plan_read)
+            partition_read=plan_read,
+            rank=getattr(custom_obj, "rank_plan", None))
         # per-lane collective skew of THIS fit (ISSUE 13): fences recorded
         # after this sequence point belong to this fit (training is
         # serialized on meshes via training_guard)
@@ -3210,9 +3216,15 @@ class H2OSharedTreeEstimator(H2OEstimator):
             # identical everywhere; pods undo the canonical relayout first)
             margins_np = unpadr(
                 distdata.local_shard(margins)).astype(np.float64)
-        elif not device_auc:
+        elif not device_auc or custom_obj is not None:
             margins_np = np.asarray(margins[:n]).astype(np.float64)
         _ph.mark("margins_D2H")
+        if custom_obj is not None:
+            # a custom objective's caller reads its own closing metric off
+            # the same final margins (XGBoost ranking: NDCG), as the
+            # training metrics below do, with no second matrix and no
+            # re-predict; the caller takes the attribute and clears it
+            self._final_margins = margins_np
         if self._mode == "drf" and row_sampled and n_prior > 0:
             # checkpoint continuation: the prior forest's per-tree sample
             # masks are gone, so OOB accounting cannot be reconstructed —

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..frame.frame import Frame
+from ..runtime import phases as _phases
+from ..runtime import tracing as _tracing
 from .glm import GLMModel as _GLMModelBase
 from .metrics import ndcg_at_k
 from .shared_tree import H2OSharedTreeEstimator, SharedTreeModel
@@ -49,7 +51,7 @@ class H2OXGBoostEstimator(H2OSharedTreeEstimator):
         ntrees=50,
         max_depth=6,
         min_rows=1.0,                 # = min_child_weight
-        min_child_weight=1.0,
+        min_child_weight=None,
         learn_rate=0.3,               # = eta
         eta=None,
         sample_rate=1.0,              # = subsample
@@ -202,29 +204,33 @@ class H2OXGBoostEstimator(H2OSharedTreeEstimator):
                 )
             from ..parallel import distdata
 
-            # the objective contract is GLOBAL rows in global order: on a
-            # multi-process cloud, gather qid/rel once so query groups that
-            # span ingest-shard boundaries stay whole (upstream rabit gets
-            # this for free from its single DMatrix; here the gather is the
-            # equivalent one-time cost)
-            qid = distdata.allgather_rows(
-                train.vec(gcol).numeric_np().astype(np.int64))
-            rel = distdata.allgather_rows(
-                train.vec(y).numeric_np().astype(np.float64))
-            x = [n for n in x if n != gcol]
-            self._objective_fn = _make_lambdarank(
-                qid, rel, int(self._parms.get("ndcg_k", 10)))
+            k = int(self._parms.get("ndcg_k", 10))
+            with _tracing.span("fit.objective", kind="fit") as sp:
+                # the objective contract is GLOBAL rows in global order: on
+                # a multi-process cloud, gather qid/rel once so query groups
+                # that span ingest-shard boundaries stay whole (upstream
+                # rabit gets this for free from its single DMatrix; here the
+                # gather is the equivalent one-time cost)
+                qid = distdata.allgather_rows(
+                    train.vec(gcol).numeric_np().astype(np.int64))
+                rel = distdata.allgather_rows(
+                    train.vec(y).numeric_np().astype(np.float64))
+                x = [n for n in x if n != gcol]
+                self._objective_fn = _make_lambdarank(qid, rel, k)
+                sp.annotate(**self._objective_fn.rank_plan)
             try:
                 model = super()._fit(x, y, train, valid)
+                margins = self._final_margins
             finally:
-                self._objective_fn = None
-            # NDCG as the headline metric for ranking models (global rows)
-            scores = distdata.allgather_rows(
-                model._margins(model._matrix(train))[:, 0])
+                self._objective_fn = self._final_margins = None
+            # NDCG as the headline metric for ranking models (global rows),
+            # from the fit's own final training margins, as every other
+            # training metric is: the forest is not scored a second time
+            with _tracing.span("fit.ndcg", kind="fit"):
+                scores = distdata.allgather_rows(margins[:, 0])
+                model.training_metrics.ndcg = ndcg_at_k(rel, scores, qid, k)
             model.training_metrics.description = (
-                f"NDCG@{self._parms.get('ndcg_k', 10)}="
-                f"{ndcg_at_k(rel, scores, qid, int(self._parms.get('ndcg_k', 10))):.5f}"
-            )
+                f"NDCG@{k}={model.training_metrics.ndcg:.5f}")
             return model
         return super()._fit(x, y, train, valid)
 
@@ -325,9 +331,14 @@ class H2OXGBoostEstimator(H2OSharedTreeEstimator):
             return model._score(frame)
         return super()._cv_predict(model, frame)
 
-    def ndcg(self, frame: Frame, k: Optional[int] = None) -> float:
+    def ndcg(self, frame: Optional[Frame] = None,
+             k: Optional[int] = None) -> float:
+        """NDCG@k of `frame` by query group; with no frame, the training
+        NDCG@ndcg_k the fit reported (`training_metrics.ndcg`)."""
         from ..parallel import distdata
 
+        if frame is None:
+            return float(self.model.training_metrics.ndcg)
         gcol = self._parms.get("group_column") or "qid"
         qid = distdata.allgather_rows(
             frame.vec(gcol).numeric_np().astype(np.int64))
@@ -405,52 +416,75 @@ def _make_lambdarank(qid: np.ndarray, rel: np.ndarray, k: int):
     single device dispatch.) Ranks use pairwise comparison counts with an
     index tiebreak — equivalent to a stable sort rank."""
     N = len(qid)
-    order = np.argsort(qid, kind="mergesort")
-    qs = qid[order]
-    starts = np.flatnonzero(np.r_[True, qs[1:] != qs[:-1]])
-    ends = np.r_[starts[1:], len(qs)]
-    Q = len(starts)
-    G = int((ends - starts).max()) if Q else 1
-    idx_mat = np.full((Q, G), N, np.int64)      # N = pad slot
-    for qi, (s, e) in enumerate(zip(starts, ends)):
-        idx_mat[qi, : e - s] = order[s:e]
-    gains = (2.0 ** rel - 1.0).astype(np.float64)
-    rel_pad = np.concatenate([rel.astype(np.float64), [0.0]])
-    gain_pad = np.concatenate([gains, [0.0]])
-    rmat = rel_pad[idx_mat]                     # (Q, G)
-    gmat = gain_pad[idx_mat]
-    valid = (idx_mat < N)
-    # per-query ideal DCG@k (static — relevance doesn't change per round)
-    idcg = np.zeros(Q)
-    for qi in range(Q):
-        ideal = np.sort(rmat[qi][valid[qi]])[::-1]
-        idcg[qi] = ((2.0 ** ideal - 1)
-                    / np.log2(np.arange(2, len(ideal) + 2)))[:k].sum()
-    inv_idcg = np.where(idcg > 0, 1.0 / np.maximum(idcg, 1e-12), 0.0)
+    with _tracing.span("objective.groups", kind="fit"):
+        order = np.argsort(qid, kind="mergesort")
+        qs = qid[order]
+        starts = np.flatnonzero(np.r_[True, qs[1:] != qs[:-1]])
+        ends = np.r_[starts[1:], len(qs)]
+        sizes = ends - starts
+        Q = len(starts)
+        G = int(sizes.max()) if Q else 1
+        idx_mat = np.full((Q, G), N, np.int64)      # N = pad slot
+        for qi, (s, e) in enumerate(zip(starts, ends)):
+            idx_mat[qi, : e - s] = order[s:e]
+        gains = (2.0 ** rel - 1.0).astype(np.float64)
+        rel_pad = np.concatenate([rel.astype(np.float64), [0.0]])
+        gain_pad = np.concatenate([gains, [0.0]])
+        rmat = rel_pad[idx_mat]                     # (Q, G)
+        gmat = gain_pad[idx_mat]
+        valid = (idx_mat < N)
+        # the ordered pairs the objective is a sum over (r_i > r_j): half of
+        # what a query's n² leaves once its equal-relevance pairs are out
+        levels, lvl = np.unique(rel, return_inverse=True)
+        _, ties = np.unique(
+            np.repeat(np.arange(Q), sizes) * len(levels) + lvl[order],
+            return_counts=True)
+        pairs = (int((sizes.astype(np.int64) ** 2).sum())
+                 - int((ties.astype(np.int64) ** 2).sum())) // 2
+    with _tracing.span("objective.idcg", kind="fit"):
+        # per-query ideal DCG@k (static — relevance doesn't change per round)
+        idcg = np.zeros(Q)
+        for qi in range(Q):
+            ideal = np.sort(rmat[qi][valid[qi]])[::-1]
+            idcg[qi] = ((2.0 ** ideal - 1)
+                        / np.log2(np.arange(2, len(ideal) + 2)))[:k].sum()
+        inv_idcg = np.where(idcg > 0, 1.0 / np.maximum(idcg, 1e-12), 0.0)
 
     # bound the (qb, G, G) pairwise block to ~2^27 elements: queries are
     # processed in lax.map chunks, so one huge group (MSLR has ~1250-doc
     # queries) cannot inflate memory to Q·G² — only its own chunk's
     qb = max(1, min(Q, (1 << 27) // max(G * G, 1)))
     Qpad = ((Q + qb - 1) // qb) * qb
-    if Qpad != Q:
-        idx_mat = np.concatenate(
-            [idx_mat, np.full((Qpad - Q, G), N, np.int64)])
-        rmat = np.concatenate([rmat, np.zeros((Qpad - Q, G))])
-        gmat = np.concatenate([gmat, np.zeros((Qpad - Q, G))])
-        valid = np.concatenate([valid, np.zeros((Qpad - Q, G), bool)])
-        inv_idcg = np.concatenate([inv_idcg, np.zeros(Qpad - Q)])
+    rank_plan = dict(queries=int(Q), group_max=int(G),
+                     group_mean=float(N / max(Q, 1)), pairs=int(pairs),
+                     pair_slots=int(Qpad * G * G), q_chunk=int(qb))
+    with _tracing.span("objective.upload", kind="fit") as sp:
+        if Qpad != Q:
+            idx_mat = np.concatenate(
+                [idx_mat, np.full((Qpad - Q, G), N, np.int64)])
+            rmat = np.concatenate([rmat, np.zeros((Qpad - Q, G))])
+            gmat = np.concatenate([gmat, np.zeros((Qpad - Q, G))])
+            valid = np.concatenate([valid, np.zeros((Qpad - Q, G), bool)])
+            inv_idcg = np.concatenate([inv_idcg, np.zeros(Qpad - Q)])
 
-    idx_d = jnp.asarray(idx_mat, jnp.int32)
-    rmat_d = jnp.asarray(rmat, jnp.float32)
-    gmat_d = jnp.asarray(gmat, jnp.float32)
-    valid_d = jnp.asarray(valid)
-    inv_idcg_d = jnp.asarray(inv_idcg, jnp.float32)
+        def up(a, dtype=None):
+            return _phases.accounted_h2d(
+                lambda: jnp.asarray(a, dtype),
+                a.size * np.dtype(dtype or a.dtype).itemsize)
+
+        idx_d = up(idx_mat, jnp.int32)
+        rmat_d = up(rmat, jnp.float32)
+        gmat_d = up(gmat, jnp.float32)
+        valid_d = up(valid)
+        inv_idcg_d = up(inv_idcg, jnp.float32)
+        sp.annotate(bytes_h2d=int(sum(
+            a.nbytes for a in (idx_d, rmat_d, gmat_d, valid_d, inv_idcg_d))))
 
     def objective(margin_dev, y_dev):
         return _lambdarank_pass(margin_dev, idx_d, rmat_d, gmat_d, valid_d,
                                 inv_idcg_d, n_rows=N, q_chunk=qb)
 
+    objective.rank_plan = rank_plan
     return objective
 
 
@@ -475,26 +509,29 @@ def _lambdarank_pass(margin, idx, rmat, gmat, valid, inv_idcg,
 
     def chunk(args):
         ii, rr, gg, vv, inv = args
-        sc = s_pad[ii]                                      # (qb, G)
-        sc = jnp.where(vv, sc, -jnp.inf)
-        # rank = #better-scored + #equal-scored-earlier (stable-sort rank)
-        gt = (sc[:, :, None] < sc[:, None, :]) & vv[:, None, :]
-        eq = (sc[:, :, None] == sc[:, None, :]) & vv[:, None, :]
-        earlier = jnp.arange(G)[None, :] < jnp.arange(G)[:, None]  # [i,j]=j<i
-        rk = gt.sum(axis=2) + (eq & earlier[None, :, :]).sum(axis=2)
-        disc = jnp.where(vv, 1.0 / jnp.log2(rk.astype(jnp.float32) + 2.0), 0.0)
-        dG = gg[:, :, None] - gg[:, None, :]
-        dD = disc[:, :, None] - disc[:, None, :]
-        delta = jnp.abs(dG * dD) * inv[:, None, None]
-        sij = jnp.where(vv, sc, 0.0)
-        sij = sij[:, :, None] - sij[:, None, :]
-        rho = jax.nn.sigmoid(-jnp.clip(sij, -35, 35))
-        pair_ok = (rr[:, :, None] > rr[:, None, :]) \
-            & vv[:, :, None] & vv[:, None, :]
-        lam = jnp.where(pair_ok, rho * delta, 0.0)
-        hess = jnp.where(pair_ok, rho * (1 - rho) * delta, 0.0)
-        g_q = -(lam.sum(axis=2) - lam.sum(axis=1))          # (qb, G)
-        h_q = hess.sum(axis=2) + hess.sum(axis=1)
+        with jax.named_scope("rank.scores"):
+            sc = s_pad[ii]                                  # (qb, G)
+            sc = jnp.where(vv, sc, -jnp.inf)
+        with jax.named_scope("rank.pairs"):
+            # rank = #better-scored + #equal-scored-earlier (stable-sort rank)
+            gt = (sc[:, :, None] < sc[:, None, :]) & vv[:, None, :]
+            eq = (sc[:, :, None] == sc[:, None, :]) & vv[:, None, :]
+            earlier = jnp.arange(G)[None, :] < jnp.arange(G)[:, None]  # j<i
+            rk = gt.sum(axis=2) + (eq & earlier[None, :, :]).sum(axis=2)
+            disc = jnp.where(
+                vv, 1.0 / jnp.log2(rk.astype(jnp.float32) + 2.0), 0.0)
+            dG = gg[:, :, None] - gg[:, None, :]
+            dD = disc[:, :, None] - disc[:, None, :]
+            delta = jnp.abs(dG * dD) * inv[:, None, None]
+            sij = jnp.where(vv, sc, 0.0)
+            sij = sij[:, :, None] - sij[:, None, :]
+            rho = jax.nn.sigmoid(-jnp.clip(sij, -35, 35))
+            pair_ok = (rr[:, :, None] > rr[:, None, :]) \
+                & vv[:, :, None] & vv[:, None, :]
+            lam = jnp.where(pair_ok, rho * delta, 0.0)
+            hess = jnp.where(pair_ok, rho * (1 - rho) * delta, 0.0)
+            g_q = -(lam.sum(axis=2) - lam.sum(axis=1))      # (qb, G)
+            h_q = hess.sum(axis=2) + hess.sum(axis=1)
         return g_q, h_q
 
     g_b, h_b = jax.lax.map(chunk, (
@@ -502,10 +539,11 @@ def _lambdarank_pass(margin, idx, rmat, gmat, valid, inv_idcg,
         reshape(valid), reshape(inv_idcg)))
     flat_idx = idx_sent.reshape(-1)
     M = margin.shape[0]
-    g = jax.ops.segment_sum(g_b.reshape(-1), flat_idx,
-                            num_segments=n_rows + 1)[:n_rows]
-    h = jax.ops.segment_sum(h_b.reshape(-1), flat_idx,
-                            num_segments=n_rows + 1)[:n_rows]
+    with jax.named_scope("rank.scatter"):
+        g = jax.ops.segment_sum(g_b.reshape(-1), flat_idx,
+                                num_segments=n_rows + 1)[:n_rows]
+        h = jax.ops.segment_sum(h_b.reshape(-1), flat_idx,
+                                num_segments=n_rows + 1)[:n_rows]
     g_full = jnp.zeros(M, jnp.float32).at[:n_rows].set(g.astype(jnp.float32))
     h_full = jnp.full(M, 1e-6, jnp.float32).at[:n_rows].set(
         jnp.maximum(h, 1e-6).astype(jnp.float32))

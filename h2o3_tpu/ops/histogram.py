@@ -199,7 +199,8 @@ def record_partition_read(read: str) -> None:
 def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
                     pack_bits: int = 0, platform: Optional[str] = None,
                     n_shards: int = 0, n_devices: int = 1,
-                    partition_read: Optional[str] = None) -> dict:
+                    partition_read: Optional[str] = None,
+                    rank: Optional[dict] = None) -> dict:
     """Resolve + record the per-level kernel plan of one tree fit.
 
     `levels` is a sequence of (label, n_nodes) histogram passes the fit
@@ -208,7 +209,10 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     path), counts every level's selection in the registry, and keeps the
     plan in a bounded ring surfaced at /3/Profiler. `partition_read` is
     how the fit's levels read a row's split-feature code
-    (`tree.partition_read`; None for a fit without a level partition)."""
+    (`tree.partition_read`; None for a fit without a level partition).
+    `rank` is a pairwise ranking objective's own plan (`queries`,
+    `group_max`, `group_mean`, `pairs`, `pair_slots`, `q_chunk`:
+    `models.xgboost._make_lambdarank`), kept under the key `rank`."""
     import time as _time
 
     plan_levels = []
@@ -223,6 +227,8 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
                 hist_method=hist_method, pack_bits=int(pack_bits),
                 n_shards=int(n_shards), n_devices=int(n_devices),
                 partition_read=partition_read, levels=plan_levels)
+    if rank is not None:
+        plan["rank"] = dict(rank)
     if fellback:
         from ..runtime.log import Log
 

@@ -53,6 +53,10 @@ def test_xgboost_lambdarank_ndcg(cloud1):
     ndcg = xgb.ndcg(fr)
     # random ordering gives much lower ndcg; learned model should be high
     assert ndcg > 0.8
+    # the fit reports it as a number too, beside the description's string
+    reported = xgb.model.training_metrics.ndcg
+    assert isinstance(reported, float) and reported == xgb.ndcg()
+    assert reported == pytest.approx(ndcg, abs=1e-6)
 
 
 def test_grid_search_cartesian(cloud1):
