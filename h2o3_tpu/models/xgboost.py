@@ -217,7 +217,8 @@ class H2OXGBoostEstimator(H2OSharedTreeEstimator):
                     train.vec(y).numeric_np().astype(np.float64))
                 x = [n for n in x if n != gcol]
                 self._objective_fn = _make_lambdarank(qid, rel, k)
-                sp.annotate(**self._objective_fn.rank_plan)
+                plan = dict(self._objective_fn.rank_plan)
+                sp.annotate(n_classes=len(plan.pop("classes")), **plan)
             try:
                 model = super()._fit(x, y, train, valid)
                 margins = self._final_margins
@@ -402,6 +403,35 @@ def _gblinear_train(Xd, yd, wd, *, family: str, n_class: int, rounds: int,
     return Wt.T                                     # (K, p)
 
 
+# One lax.map chunk of the pairwise pass holds at most this many pair slots
+# (a `(q_chunk, width, width)` block), so one huge query (MSLR has ~1250-doc
+# queries) cannot inflate memory to its class's whole queries x width².
+_PAIR_BLOCK = 1 << 27
+_LANES = 128                # widths are multiples of the chip's lane tile
+_MAX_CLASSES = 8            # programs a pass compiles, whatever the frame
+
+
+def _class_widths(sizes: np.ndarray) -> np.ndarray:
+    """The widths queries are padded to, from the query sizes alone.
+
+    Multiples of the chip's 128-lane tile, doubling from 128 (128, 256, 512,
+    ...) with the top width at the largest query rounded up to a multiple of
+    128; of more than `_MAX_CLASSES` the smallest go (their queries join the
+    smallest width kept), and a width no query falls under is dropped. A
+    query belongs to the smallest width that holds it, so queries of one
+    size (fixed candidate lists) make ONE class: the (Q, G, G) program of a
+    frame padded to its largest query, G rounded up to the tile (250
+    documents a query run as 256: at most 5 % more slots)."""
+    top = -(-max(int(sizes.max()), 1) // _LANES) * _LANES
+    widths = [_LANES]
+    while widths[-1] * 2 < top:
+        widths.append(widths[-1] * 2)
+    if widths[-1] < top:
+        widths.append(top)
+    widths = np.asarray(widths[-_MAX_CLASSES:], np.int64)
+    return widths[np.unique(np.searchsorted(widths, sizes))]
+
+
 def _make_lambdarank(qid: np.ndarray, rel: np.ndarray, k: int):
     """Pairwise lambdarank (g, h) — xgboost `rank:ndcg`.
 
@@ -409,106 +439,120 @@ def _make_lambdarank(qid: np.ndarray, rel: np.ndarray, k: int):
     λ = -σ(-(s_i - s_j)) · |ΔNDCG_ij| to g_i (and +λ to g_j); h gets
     σ(1-σ)|ΔNDCG|.
 
-    TPU-first: queries are padded to a common group size and the whole
-    pairwise pass runs as ONE jitted program per boosting round — a (Q, G,
-    G) batched pairwise block, scattered back to rows by segment_sum. (A
-    per-query host loop costs ~1 s per tree on MSLR-sized data; this is a
-    single device dispatch.) Ranks use pairwise comparison counts with an
-    index tiebreak — equivalent to a stable sort rank."""
+    TPU-first: queries are grouped into a few size classes
+    (`_class_widths`), each padded to its class's width and not to the
+    largest query of the frame, and the whole pairwise pass runs as ONE
+    jitted program per boosting round — per class a (Q_c, W_c, W_c) batched
+    pairwise block in lax.map chunks, read back to rows through each row's
+    one slot. (A per-query host loop costs ~1 s per tree on MSLR-sized data;
+    this is a single device dispatch.) Ranks use pairwise comparison counts
+    with an index tiebreak — equivalent to a stable sort rank. Rows may come
+    in any order, a query's need not be contiguous."""
     N = len(qid)
     with _tracing.span("objective.groups", kind="fit"):
         order = np.argsort(qid, kind="mergesort")
         qs = qid[order]
         starts = np.flatnonzero(np.r_[True, qs[1:] != qs[:-1]])
-        ends = np.r_[starts[1:], len(qs)]
-        sizes = ends - starts
+        sizes = np.diff(np.r_[starts, N])
         Q = len(starts)
-        G = int(sizes.max()) if Q else 1
-        idx_mat = np.full((Q, G), N, np.int64)      # N = pad slot
-        for qi, (s, e) in enumerate(zip(starts, ends)):
-            idx_mat[qi, : e - s] = order[s:e]
+        of_query = np.repeat(np.arange(Q), sizes)
+        widths = _class_widths(sizes)
+        cls = np.searchsorted(widths, sizes)
+        # a class's queries keep their frame order, padded to a whole number
+        # of chunks by choosing the chunk from the count (12,500 queries in
+        # two chunks are 2 x 6,250, not 2 x 8,192)
+        classes, layout, first_slot = [], [], np.zeros(Q, np.int64)
+        n_slots = 0
+        for c, W in enumerate(widths):
+            mem = np.flatnonzero(cls == c)
+            n_chunks = -(-len(mem) // max(1, _PAIR_BLOCK // int(W * W)))
+            q_chunk = -(-len(mem) // n_chunks)
+            classes.append(dict(width=int(W), queries=len(mem),
+                                padded_queries=n_chunks * q_chunk,
+                                q_chunk=q_chunk))
+            layout.append((mem, n_slots, (n_chunks, q_chunk, int(W))))
+            first_slot[mem] = n_slots + np.arange(len(mem)) * W
+            n_slots += n_chunks * q_chunk * int(W)
+        # every row owns exactly one slot: its query's first + its place
+        slot = np.empty(N, np.int64)
+        slot[order] = first_slot[of_query] + np.arange(N) - starts[of_query]
+        idx = np.full(n_slots, N, np.int64)         # N = pad slot
+        idx[slot] = np.arange(N)
         gains = (2.0 ** rel - 1.0).astype(np.float64)
-        rel_pad = np.concatenate([rel.astype(np.float64), [0.0]])
-        gain_pad = np.concatenate([gains, [0.0]])
-        rmat = rel_pad[idx_mat]                     # (Q, G)
-        gmat = gain_pad[idx_mat]
-        valid = (idx_mat < N)
+        rflat = np.concatenate([rel.astype(np.float64), [0.0]])[idx]
+        gflat = np.concatenate([gains, [0.0]])[idx]
         # the ordered pairs the objective is a sum over (r_i > r_j): half of
         # what a query's n² leaves once its equal-relevance pairs are out
         levels, lvl = np.unique(rel, return_inverse=True)
-        _, ties = np.unique(
-            np.repeat(np.arange(Q), sizes) * len(levels) + lvl[order],
-            return_counts=True)
+        _, ties = np.unique(of_query * len(levels) + lvl[order],
+                            return_counts=True)
         pairs = (int((sizes.astype(np.int64) ** 2).sum())
                  - int((ties.astype(np.int64) ** 2).sum())) // 2
     with _tracing.span("objective.idcg", kind="fit"):
         # per-query ideal DCG@k (static — relevance doesn't change per round)
         idcg = np.zeros(Q)
-        for qi in range(Q):
-            ideal = np.sort(rmat[qi][valid[qi]])[::-1]
+        for qi, (s, n) in enumerate(zip(starts, sizes)):
+            ideal = np.sort(rel[order[s:s + n]])[::-1]
             idcg[qi] = ((2.0 ** ideal - 1)
                         / np.log2(np.arange(2, len(ideal) + 2)))[:k].sum()
         inv_idcg = np.where(idcg > 0, 1.0 / np.maximum(idcg, 1e-12), 0.0)
 
-    # bound the (qb, G, G) pairwise block to ~2^27 elements: queries are
-    # processed in lax.map chunks, so one huge group (MSLR has ~1250-doc
-    # queries) cannot inflate memory to Q·G² — only its own chunk's
-    qb = max(1, min(Q, (1 << 27) // max(G * G, 1)))
-    Qpad = ((Q + qb - 1) // qb) * qb
-    rank_plan = dict(queries=int(Q), group_max=int(G),
-                     group_mean=float(N / max(Q, 1)), pairs=int(pairs),
-                     pair_slots=int(Qpad * G * G), q_chunk=int(qb))
+    rank_plan = dict(
+        queries=int(Q), group_max=int(sizes.max()),
+        group_mean=float(N / Q), pairs=int(pairs),
+        pair_slots=int(sum(c["padded_queries"] * c["width"] ** 2
+                           for c in classes)),
+        classes=classes)
     with _tracing.span("objective.upload", kind="fit") as sp:
-        if Qpad != Q:
-            idx_mat = np.concatenate(
-                [idx_mat, np.full((Qpad - Q, G), N, np.int64)])
-            rmat = np.concatenate([rmat, np.zeros((Qpad - Q, G))])
-            gmat = np.concatenate([gmat, np.zeros((Qpad - Q, G))])
-            valid = np.concatenate([valid, np.zeros((Qpad - Q, G), bool)])
-            inv_idcg = np.concatenate([inv_idcg, np.zeros(Qpad - Q)])
-
-        def up(a, dtype=None):
+        def up(a, dtype):
             return _phases.accounted_h2d(
                 lambda: jnp.asarray(a, dtype),
-                a.size * np.dtype(dtype or a.dtype).itemsize)
+                a.size * np.dtype(dtype).itemsize)
 
-        idx_d = up(idx_mat, jnp.int32)
-        rmat_d = up(rmat, jnp.float32)
-        gmat_d = up(gmat, jnp.float32)
-        valid_d = up(valid)
-        inv_idcg_d = up(inv_idcg, jnp.float32)
+        class_d = []
+        for mem, lo, shape in layout:
+            def part(a):
+                return a[lo:lo + int(np.prod(shape))].reshape(shape)
+
+            inv = np.zeros(shape[:2])
+            inv.reshape(-1)[: len(mem)] = inv_idcg[mem]
+            class_d.append((up(part(idx), jnp.int32),
+                            up(part(rflat), jnp.float32),
+                            up(part(gflat), jnp.float32),
+                            up(inv, jnp.float32)))
+        class_d = tuple(class_d)
+        slot_d = up(slot, jnp.int32)
         sp.annotate(bytes_h2d=int(sum(
-            a.nbytes for a in (idx_d, rmat_d, gmat_d, valid_d, inv_idcg_d))))
+            a.nbytes for a in jax.tree_util.tree_leaves((class_d, slot_d)))))
 
     def objective(margin_dev, y_dev):
-        return _lambdarank_pass(margin_dev, idx_d, rmat_d, gmat_d, valid_d,
-                                inv_idcg_d, n_rows=N, q_chunk=qb)
+        return _lambdarank_pass(margin_dev, class_d, slot_d)
 
     objective.rank_plan = rank_plan
     return objective
 
 
-@functools.partial(jax.jit, static_argnames=("n_rows", "q_chunk"))
-def _lambdarank_pass(margin, idx, rmat, gmat, valid, inv_idcg,
-                     n_rows: int, q_chunk: int):
+@jax.jit
+def _lambdarank_pass(margin, classes, slot):
     """One lambdarank (g, h) pass over all (padded) query groups.
 
-    Group tensors arrive as ARGUMENTS (not closure captures) so the HLO
-    carries no data literals and the persistent compilation cache keys on
-    shapes only — the same convention as the tree builder's _one_tree.
-    Returns g/h padded with zeros to len(margin) (the tree build's padded
-    row count)."""
-    Qp, G = idx.shape
-    nb = Qp // q_chunk
-    reshape = lambda a: a.reshape((nb, q_chunk) + a.shape[1:])
-    s_pad = jnp.concatenate(
-        [margin.astype(jnp.float32), jnp.zeros(1, jnp.float32)])
+    `classes` holds, per size class, the group tensors `(idx, rel, gain)` of
+    shape (n_chunks, q_chunk, width) and `inv_idcg` of (n_chunks, q_chunk);
+    `slot` is each row's place in the classes' slots laid end to end. They
+    arrive as ARGUMENTS (not closure captures) so the HLO carries no data
+    literals and the persistent compilation cache keys on shapes only — the
+    same convention as the tree builder's _one_tree. Returns g/h padded
+    with zeros to len(margin) (the tree build's padded row count)."""
+    n_rows = slot.shape[0]
     # pad slots (idx == n_rows) read the sentinel; real pad rows of the
     # margin vector are never referenced by idx (idx < n_rows)
-    idx_sent = jnp.minimum(idx, n_rows)
+    s_pad = jnp.concatenate(
+        [margin[:n_rows].astype(jnp.float32), jnp.zeros(1, jnp.float32)])
 
     def chunk(args):
-        ii, rr, gg, vv, inv = args
+        ii, rr, gg, inv = args
+        G = ii.shape[1]
+        vv = ii < n_rows
         with jax.named_scope("rank.scores"):
             sc = s_pad[ii]                                  # (qb, G)
             sc = jnp.where(vv, sc, -jnp.inf)
@@ -534,19 +578,14 @@ def _lambdarank_pass(margin, idx, rmat, gmat, valid, inv_idcg,
             h_q = hess.sum(axis=2) + hess.sum(axis=1)
         return g_q, h_q
 
-    g_b, h_b = jax.lax.map(chunk, (
-        reshape(idx_sent), reshape(rmat), reshape(gmat),
-        reshape(valid), reshape(inv_idcg)))
-    flat_idx = idx_sent.reshape(-1)
+    per_class = [jax.lax.map(chunk, group) for group in classes]
+    with jax.named_scope("rank.rows"):
+        g = jnp.concatenate([g_c.reshape(-1) for g_c, _ in per_class])[slot]
+        h = jnp.concatenate([h_c.reshape(-1) for _, h_c in per_class])[slot]
     M = margin.shape[0]
-    with jax.named_scope("rank.scatter"):
-        g = jax.ops.segment_sum(g_b.reshape(-1), flat_idx,
-                                num_segments=n_rows + 1)[:n_rows]
-        h = jax.ops.segment_sum(h_b.reshape(-1), flat_idx,
-                                num_segments=n_rows + 1)[:n_rows]
-    g_full = jnp.zeros(M, jnp.float32).at[:n_rows].set(g.astype(jnp.float32))
+    g_full = jnp.zeros(M, jnp.float32).at[:n_rows].set(g)
     h_full = jnp.full(M, 1e-6, jnp.float32).at[:n_rows].set(
-        jnp.maximum(h, 1e-6).astype(jnp.float32))
+        jnp.maximum(h, 1e-6))
     return g_full, h_full
 
 

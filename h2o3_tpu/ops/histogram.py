@@ -210,9 +210,12 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     plan in a bounded ring surfaced at /3/Profiler. `partition_read` is
     how the fit's levels read a row's split-feature code
     (`tree.partition_read`; None for a fit without a level partition).
-    `rank` is a pairwise ranking objective's own plan (`queries`,
-    `group_max`, `group_mean`, `pairs`, `pair_slots`, `q_chunk`:
-    `models.xgboost._make_lambdarank`), kept under the key `rank`."""
+    `rank` is a pairwise ranking objective's own plan
+    (`models.xgboost._make_lambdarank`), kept under the key `rank`:
+    `queries`, `group_max`, `group_mean`, `pairs` (the real ordered pairs),
+    `pair_slots` (every slot the pass computes: over the size classes,
+    padded queries x width², padding queries included) and `classes`, one
+    dict of `width`, `queries`, `padded_queries`, `q_chunk` a class."""
     import time as _time
 
     plan_levels = []

@@ -18,8 +18,10 @@ import pytest
 
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models import tree as treelib
+from h2o3_tpu.models import xgboost as xgb
 from h2o3_tpu.models.xgboost import H2OXGBoostEstimator, _make_lambdarank
 from h2o3_tpu.ops.histogram import kernel_stats
+from h2o3_tpu.runtime import phases, tracing
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -77,11 +79,13 @@ def fitted(bench):
     est = algo.make_estimator(cfg, OVERRIDES)
     frame = algo.make_frame(algo.make_columns(data))
     mesh.init(jax.devices()[:1])      # `cloud1`, which is a test's to ask for
+    tracing.clear()
     algo.train(est, frame)
     mesh.reset()
     plan = kernel_stats()["plans"][-1]
+    (span,) = [s for s in tracing.spans() if s["name"] == "fit.objective"]
     return (data, est, frame, plan, algo.result(cfg, est, OVERRIDES),
-            ref.prepare(cfg, data))
+            ref.prepare(cfg, data), span["attrs"])
 
 
 def program_grads(qid, rel, margin):
@@ -110,7 +114,7 @@ def test_first_round_gradients_of_every_row(bench, fitted):
 
 def test_the_forest_follows_the_plain_reference(bench, fitted):
     cfg, _, ref = bench
-    data, est, frame, plan, result, prep = fitted
+    data, est, frame, plan, result, prep, _ = fitted
     assert result["feat"].shape == (3, 127) and result["is_split"][:, 0].all()
     numbers = ref.compare(cfg, prep, result)
     assert numbers["edges_gap"] < 1e-12
@@ -134,14 +138,27 @@ def test_the_forest_follows_the_plain_reference(bench, fitted):
 
 def test_the_fit_says_what_it_ran(cloud1, bench, fitted):
     _, _, ref = bench
-    data, est, frame, plan, result, prep = fitted
+    data, est, frame, plan, result, prep, span = fitted
     assert treelib.partition_read(136) == "gather"
     assert treelib.partition_read(28) == "select"
     assert plan["partition_read"] == "gather" and plan["nbins"] == 256
     rank = plan["rank"]
     assert rank["queries"] == 200 and rank["group_max"] == 250
     assert rank["pairs"] == prep.queries.pairs() <= rank["pair_slots"]
-    assert rank["pair_slots"] == 200 * 250 * 250 and rank["q_chunk"] == 200
+    # 1 to 250 documents a query: two size classes, each query in the
+    # smallest that holds it, one chunk each and no padding query
+    sizes = np.bincount(np.unique(data["qid"], return_inverse=True)[1])
+    small = int((sizes <= 128).sum())
+    assert rank["classes"] == [
+        dict(width=128, queries=small, padded_queries=small, q_chunk=small),
+        dict(width=256, queries=200 - small, padded_queries=200 - small,
+             q_chunk=200 - small)]
+    assert 0 < small < 200 and "q_chunk" not in rank
+    assert rank["pair_slots"] == small * 128 ** 2 + (200 - small) * 256 ** 2
+    assert rank["pair_slots"] < 200 * 250 * 250
+    # the span says the same, with the classes as a count
+    assert span == dict({k: v for k, v in rank.items() if k != "classes"},
+                        n_classes=2)
     # the reported NDCG is a number, the one ndcg(frame) computes
     reported = est.model.training_metrics.ndcg
     assert reported == est.ndcg() == pytest.approx(est.ndcg(frame), abs=1e-12)
@@ -185,9 +202,9 @@ def test_gradients_of_a_special_query(bench, name):
         assert np.all(g[special] == 0) and np.allclose(h[special], 1e-6)
 
 
-def test_padding_to_the_largest_group_leaks_nothing(bench):
-    """Ragged queries padded to the largest G give each query the gradients
-    it has among queries of its own size, and alone."""
+def test_padding_to_a_class_width_leaks_nothing(bench):
+    """Ragged queries padded to their class's width give each query the
+    gradients it has among queries of its own size, and alone."""
     rng = np.random.default_rng(9)
     sizes = np.array([20, 7, 20, 3, 1, 20])
     qid = np.repeat(np.arange(len(sizes)), sizes)
@@ -198,6 +215,144 @@ def test_padding_to_the_largest_group_leaks_nothing(bench):
         ga, ha = program_grads(qid[keep], rel[keep], margin[keep])
         assert np.allclose(g[keep], ga, rtol=1e-6, atol=1e-9)
         assert np.allclose(h[keep], ha, rtol=1e-6, atol=1e-9)
+
+
+# Query sizes that cross size classes (the cases above all sit under 128
+# documents a query, so in one class).
+SIZE_MIXES = {
+    "every_class_edge": [5, 127, 128, 129, 300, 1, 700],
+    "one_size": [40] * 9,
+    "one_large_among_small": [12, 1100, 30, 7, 3],
+}
+
+
+def _mix(name):
+    """(qid, relevance, margins, sizes) of a mix's queries, rows contiguous
+    and qid ascending."""
+    sizes = np.array(SIZE_MIXES[name])
+    rng = np.random.default_rng(len(sizes) + int(sizes.sum()))
+    qid = np.repeat(3 + 7 * np.arange(len(sizes)), sizes)
+    rel = rng.integers(0, 5, len(qid)).astype(float)
+    return qid, rel, rng.normal(size=len(qid)), sizes
+
+
+def _close(a, b):
+    """As `GRAD_TOL` reads it: a share of the largest value."""
+    return np.abs(a - b).max() <= GRAD_TOL * max(np.abs(b).max(), 1e-6)
+
+
+@pytest.mark.parametrize("name", list(SIZE_MIXES))
+def test_gradients_across_size_classes(bench, name):
+    _, _, ref = bench
+    qid, rel, margin, sizes = _mix(name)
+    g, h = program_grads(qid, rel, margin)
+    G, H = reference_grads(ref, qid, rel, margin)
+    assert np.abs(G).max() > 0.1
+    for q in np.unique(qid):
+        keep = qid == q
+        assert _close(g[keep], G[keep]) and _close(h[keep], H[keep])
+    # and a query of each size has what it gets alone, in its own class
+    for q in np.unique(qid)[np.unique(sizes, return_index=True)[1]]:
+        keep = qid == q
+        ga, ha = program_grads(qid[keep], rel[keep], margin[keep])
+        assert _close(g[keep], ga) and _close(h[keep], ha)
+
+
+@pytest.mark.parametrize("name", list(SIZE_MIXES))
+def test_rows_in_any_order_get_their_own_gradients(name):
+    """The same rows shuffled, so that queries interleave and a query's
+    rows lie apart, and then with the queries' ids renumbered in another
+    order: (g, h) row for row."""
+    qid, rel, margin, sizes = _mix(name)
+    g, h = program_grads(qid, rel, margin)
+    rng = np.random.default_rng(17)
+    # frame order breaks ties of the margins, and these have none
+    rows = rng.permutation(len(qid))
+    gs, hs = program_grads(qid[rows], rel[rows], margin[rows])
+    assert _close(gs, g[rows]) and _close(hs, h[rows])
+    renumbered = rng.permutation(len(sizes))[(qid - 3) // 7]
+    gr, hr = program_grads(renumbered[rows], rel[rows], margin[rows])
+    assert _close(gr, g[rows]) and _close(hr, h[rows])
+
+
+@pytest.mark.parametrize("sizes, widths", [
+    ([5, 127, 128, 129, 300, 1, 700], [128, 256, 512, 768]),
+    ([40] * 9, [128]),                     # one size: one class
+    ([250] * 4, [256]),                    # the program of PR 30, G to a tile
+    ([12, 1100, 30, 7, 3], [128, 1152]),   # no query there: no class
+    ([1, 1251, 120, 500, 200, 1000], [128, 256, 512, 1024, 1280]),
+    ([129, 100_000], [1024, 100_096]),     # of 8 widths 1,024 is the least
+])
+def test_widths_follow_from_the_sizes_alone(sizes, widths):
+    assert list(xgb._class_widths(np.array(sizes))) == widths
+    assert len(xgb._class_widths(np.arange(1, 10 ** 6, 997))) == 8
+
+
+@pytest.mark.parametrize("name", list(SIZE_MIXES))
+def test_the_plan_counts_the_slots_the_program_holds(monkeypatch, name):
+    seen = []
+    monkeypatch.setattr(
+        xgb, "_lambdarank_pass",
+        lambda margin, classes, slot: seen.append((classes, slot)))
+    _, rel, margin, sizes = _mix(name)
+    plans = []
+    for base in (0, 1000):      # not from the ids, nor from the relevance
+        qid = np.repeat(base + np.arange(len(sizes)), sizes)
+        objective = _make_lambdarank(qid, np.roll(rel, base), 10)
+        objective(margin, None)
+        plans.append(objective.rank_plan)
+    assert plans[0]["classes"] == plans[1]["classes"]
+    plan = plans[0]
+    assert ([c["width"] for c in plan["classes"]]
+            == list(xgb._class_widths(sizes)))
+    assert sum(c["queries"] for c in plan["classes"]) == len(sizes)
+    # what the plan says is what the program's arguments hold
+    classes, slot = seen[-1]
+    held = 0
+    for c, (idx, rmat, gmat, inv) in zip(plan["classes"], classes):
+        chunks, q_chunk, width = idx.shape
+        assert (q_chunk, width) == (c["q_chunk"], c["width"])
+        assert chunks * q_chunk == c["padded_queries"] >= c["queries"]
+        assert q_chunk * width * width <= xgb._PAIR_BLOCK
+        assert rmat.shape == gmat.shape == idx.shape
+        assert inv.shape == idx.shape[:2]
+        held += idx.size * width
+    assert plan["pair_slots"] == held >= plan["pairs"]
+    # every row owns one slot, and no two the same
+    assert len(np.unique(np.asarray(slot))) == len(slot) == sizes.sum()
+
+
+def test_a_class_is_padded_to_its_chunks_not_to_a_fixed_chunk(monkeypatch,
+                                                              bench):
+    """More queries than one block holds: the chunk comes from the count."""
+    _, _, ref = bench
+    monkeypatch.setattr(xgb, "_PAIR_BLOCK", 4 * 128 * 128)
+    sizes = np.array([3] * 9 + [200])
+    qid = np.repeat(np.arange(10), sizes)
+    rng = np.random.default_rng(2)
+    rel = rng.integers(0, 5, len(qid)).astype(float)
+    margin = rng.normal(size=len(qid))
+    objective = _make_lambdarank(qid, rel, 10)
+    # 9 queries where a block holds 4: three chunks of 3, not 12 slots
+    assert objective.rank_plan["classes"] == [
+        dict(width=128, queries=9, padded_queries=9, q_chunk=3),
+        dict(width=256, queries=1, padded_queries=1, q_chunk=1)]
+    g, h = program_grads(qid, rel, margin)
+    G, H = reference_grads(ref, qid, rel, margin)
+    assert _close(g, G) and _close(h, H)
+
+
+def test_a_second_objective_on_the_same_sizes_compiles_nothing():
+    phases.install_listener()
+    qid, rel, margin, _ = _mix("every_class_edge")
+    program_grads(qid, rel, margin)
+    before = phases.xla_counts()
+    # other relevance, other margins, other ids: the same shapes
+    g, _ = program_grads(qid + 5, rel[::-1].copy(), -margin)
+    after = phases.xla_counts()
+    assert np.abs(g).max() > 0
+    assert (after["compiles"], after["traces"]) == (before["compiles"],
+                                                    before["traces"])
 
 
 def test_the_same_rows_in_shuffled_query_order_give_the_same_forest(cloud1):
