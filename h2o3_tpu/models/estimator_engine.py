@@ -262,32 +262,28 @@ def design_matrix(frame, x, *, standardize: bool, use_all: bool = False,
     run (small-range integer columns travel at 1-2 bytes/value, the dense
     one-hot never crosses the link), now cached so a sweep expands and
     uploads once. Bitwise the same artifact those estimators built per-fit
-    before."""
+    before. On a mesh (``n_devices > 1``, one process) the statistics are
+    fitted from the compact columns exactly as on one device and the packs
+    go up row-sharded: the dense host design is never built, so a frame
+    whose design no single chip (and no host buffer) holds still fits."""
     from .model_base import DataInfo
 
     ua = _expansion_key(frame, x, use_all)
     npad = pad_rows(frame.nrow, n_shards)
 
     def build():
-        import jax
-
         dinfo = DataInfo(frame, x, standardize=standardize,
                          use_all_factor_levels=ua, impute_missing=True)
+        # the two lanes differ in where the rows land, not in how the
+        # statistics are fitted
         if n_devices > 1:
             from ..parallel import mesh as cloudlib
 
-            cloud = cloudlib.cloud()
-            # stats fit on host first (device_design sharded assembly
-            # requires fitted stats); compact packs shard straight from
-            # host — no unsharded intermediate on device 0
-            dinfo.fit_transform(frame)
-            Xd = dinfo.device_design(frame, fit=False,
-                                     add_intercept=add_intercept,
-                                     cloud=cloud, quota=npad)
+            layout = dict(cloud=cloudlib.cloud(), quota=npad)
         else:
-            Xd = dinfo.device_design(frame, fit=True,
-                                     add_intercept=add_intercept,
-                                     row_bucket=n_shards or 0)
+            layout = dict(row_bucket=n_shards or 0)
+        Xd = dinfo.device_design(frame, fit=True,
+                                 add_intercept=add_intercept, **layout)
         nbytes = int(np.prod(Xd.shape)) * Xd.dtype.itemsize
         return (dinfo, Xd), nbytes, "device"
 

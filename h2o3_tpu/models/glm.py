@@ -265,6 +265,20 @@ def _glm_path_device(X, y, w, Xe, ye, we, lams, alpha, n_obs, beta0,
     return betas, devs
 
 
+def _block_partials(X, Ww, z, local_blocks: int):
+    """(local_blocks, P, P+1): a lane's share of the Gram, one partial per
+    ordered row block. ONE augmented gemm per block — (WwX)' @ [X | z]
+    yields gram AND xy from the same dot: the gemm-shaped form lowers
+    identically inside a lane's shard_map body and inside the S-block
+    single-device program (a separate gemv for xy did NOT — its accumulation
+    fused differently per context), which is what makes blocks==mesh
+    bit-identical."""
+    Xw = X * Ww[:, None]
+    Xz = jnp.concatenate([X, z[:, None]], axis=1)
+    return jnp.stack([jnp.matmul(Xw[s].T, Xz[s], precision=_HI)
+                      for s in _est.block_slices(X.shape[0], local_blocks)])
+
+
 def _irls_device_fn(cloud, shard_mode: str, n_shards: int, family: str,
                     non_negative: bool, one_step: bool):
     """The fused single-λ IRLS fit as ONE device program (ISSUE 15):
@@ -303,24 +317,23 @@ def _irls_device_fn(cloud, shard_mode: str, n_shards: int, family: str,
             # (`jit_inner`) is what the benchmark's readers key on
             @jax.named_scope("irls.gram")
             def gram_xy(b):
-                eta = jnp.matmul(X, b, precision=_HI)
+                if local_blocks:
+                    # a float32 multiply and a sum along each row, whose
+                    # order no row count changes: a matvec picks its kernel
+                    # by the lane's rows, and blocks != mesh in eta's last
+                    # bit (seen on the CPU's eight devices)
+                    eta = jnp.sum(X * b[None, :], axis=1)
+                else:
+                    eta = jnp.matmul(X, b, precision=_HI)
                 mu = _linkinv(family, eta)
                 W, z = _irls_weights(family, eta, mu, y, tweedie_p)
                 Ww = W * w
                 if local_blocks:
-                    # ONE augmented gemm per block — (WwX)' @ [X | z]
-                    # yields gram AND xy from the same dot: the
-                    # gemm-shaped form lowers identically inside a lane's
-                    # shard_map body and inside the S-block single-device
-                    # program (a separate gemv for xy did NOT — its
-                    # accumulation fused differently per context), which
-                    # is what makes blocks==mesh bit-identical
-                    Xw = X * Ww[:, None]
-                    Xz = jnp.concatenate([X, z[:, None]], axis=1)
-                    sl = _est.block_slices(X.shape[0], local_blocks)
-                    gz = _est.fold_blocks(
-                        jnp.stack([jnp.matmul(Xw[s].T, Xz[s], precision=_HI)
-                                   for s in sl]), axis)
+                    parts = _block_partials(X, Ww, z, local_blocks)
+                    # the all-gather of the lanes' partials and the ordered
+                    # left-to-right sum (on one device: the sum alone)
+                    with jax.named_scope("irls.fold"):
+                        gz = _est.fold_blocks(parts, axis)
                     return gz[:, :-1], gz[:, -1]
                 return (jnp.einsum("np,n,nq->pq", X, Ww, X, precision=_HI),
                         jnp.einsum("np,n->p", X, Ww * z, precision=_HI))
@@ -404,13 +417,17 @@ def attach_linear_artifacts(model: "GLMModel", train, valid, Xd,
     """Training/validation metrics + |coefficient| varimp for a fitted
     linear model — shared by GLM and the XGBoost gblinear booster.
 
-    Reuses the training design matrix already in HBM for training metrics —
-    single-device only: a row-sharded Xd may span non-addressable devices
-    (multi-host mesh) and padded tail rows would corrupt metrics."""
+    Reuses the training design matrix already in HBM for training metrics:
+    on one device when it holds the frame's rows and no more, and on a
+    one-process mesh always — there a second, unsharded design may be what
+    no single chip holds, every shard is addressable, and the zero-weight
+    pad rows sit at the tail, which `_make_metrics` slices off on the host.
+    A multi-process Xd spans devices this process cannot read."""
+    reuse = (int(Xd.shape[0]) == n if cloud_size == 1
+             else not distdata.multiprocess())
     with tracing.span("fit.metrics", kind="fit"):
         model.training_metrics = model._make_metrics(
-            train,
-            Xd=Xd if (cloud_size == 1 and int(Xd.shape[0]) == n) else None)
+            train, Xd=Xd if reuse else None)
         if valid is not None:
             model.validation_metrics = model._make_metrics(valid)
         # GLM varimp = |standardized coefficient| magnitudes
@@ -577,10 +594,12 @@ class GLMModel(H2OModel):
         mu_dev = self._score_dev(frame, Xd=Xd)
         with tracing.span("metrics.d2h", kind="fit"):
             # the wait for the scoring program and the n-sized transfer
-            out = np.asarray(mu_dev, np.float64)[: frame.nrow]
+            out = np.asarray(mu_dev)[: frame.nrow]
         yv = frame.vec(self.y)
         if self.family in ("binomial", "quasibinomial"):
+            # as the device made them: `make` widens into its own buffer
             return ModelMetricsBinomial.make(np.asarray(yv.data), out)
+        out = np.asarray(out, np.float64)
         if self.family == "multinomial":
             return ModelMetricsMultinomial.make(np.asarray(yv.data), out)
         return ModelMetricsRegression.make(yv.numeric_np(), out)
@@ -643,11 +662,8 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
         std_flag = bool(p.get("standardize", True))
         n = train.nrow
         with tracing.span("fit.response", kind="fit"):
-            w = (
-                train.vec(p["weights_column"]).numeric_np()
-                if p.get("weights_column")
-                else np.ones(n)
-            ).astype(np.float32)
+            w = (train.vec(p["weights_column"]).numeric_np().astype(np.float32)
+                 if p.get("weights_column") else np.ones(n, np.float32))
 
             if family in ("binomial", "quasibinomial", "fractionalbinomial"):
                 yarr = np.asarray(yvec.data, np.float32) if yvec.type == "enum" else yvec.numeric_np().astype(np.float32)
@@ -750,17 +766,20 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
                 n_shards=n_shards, n_devices=ndev_eff)
             npad = int(Xd.shape[0])
             if npad != n or ndev_eff > 1:
-                ypad = np.concatenate([np.asarray(
-                    yarr if family != "multinomial"
-                    else yarr.astype(np.float32), np.float32),
-                    np.zeros(npad - n, np.float32)])
-                wpad = np.concatenate([w, np.zeros(npad - n, np.float32)])
-                if ndev_eff > 1:
-                    rs = cloud.row_sharding()
-                    yd = jax.device_put(jnp.asarray(ypad), rs)
-                    wd = jax.device_put(jnp.asarray(wpad), rs)
-                else:
-                    yd, wd = jnp.asarray(ypad), jnp.asarray(wpad)
+                # the response and the weights on the design's grid: zero
+                # weight on the pad rows, a lane's rows on its own device
+                with tracing.span("fit.response", kind="fit"):
+                    ypad, wpad = np.asarray(yarr, np.float32), w
+                    if npad != n:
+                        tail = np.zeros(npad - n, np.float32)
+                        ypad = np.concatenate([ypad, tail])
+                        wpad = np.concatenate([wpad, tail])
+                    if ndev_eff > 1:
+                        rs = cloud.row_sharding()
+                        yd = jax.device_put(ypad, rs)
+                        wd = jax.device_put(wpad, rs)
+                    else:
+                        yd, wd = jnp.asarray(ypad), jnp.asarray(wpad)
         else:
             with tracing.span("fit.design", kind="fit", cache="off"):
                 if cloud.size > 1 and n >= cloud.size:
@@ -890,7 +909,9 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
             n_shards=n_shards if fitplan.get("path") in (
                 "fused", "fused_blocks", "fused_mesh") else 0,
             n_devices=cloud.size if shard_mode == "mesh" else 1,
-            family=family)
+            family=family,
+            **{k: fitplan[k] for k in ("rows_per_device", "local_blocks",
+                                       "fold_bytes") if k in fitplan})
         model = GLMModel(self, x, y, dinfo, family, beta, domain,
                          lambda_best=lam_best, stderr=stderr, full_path=full_path)
         model.covmat = cov  # (p+1)² dispersion-scaled covariance (p-values)
@@ -986,9 +1007,18 @@ class H2OGeneralizedLinearEstimator(H2OEstimator):
             fitplan.update(path="host_fallback")
             return self._irls(Xd, yd, wd, family, lam, alpha, max_iter,
                               beta_eps, tweedie_p)
+        # how the fit was laid out: a lane's rows, the ordered blocks it
+        # sums, and what the fold gathers onto every lane an iteration
+        # (all n_shards (P, P+1) float32 partials; nothing on one device)
+        local_blocks, axis = _est.local_plan(cloud, shard_mode, n_shards)
+        lanes = cloud.size if axis is not None else 1
         fitplan.update(
             path={"mesh": "fused_mesh", "blocks": "fused_blocks"}.get(
                 shard_mode, "fused"),
+            rows_per_device=int(Xd.shape[0]) // lanes,
+            local_blocks=int(local_blocks),
+            fold_bytes=(int(n_shards) * pdim * (pdim + 1) * 4
+                        if axis is not None else 0),
             iterations=iters,
             converged=bool(one_step or float(delta_d) < beta_eps
                            or iters < max_iter))

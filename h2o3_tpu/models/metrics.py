@@ -25,7 +25,7 @@ from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
-from ..runtime import tracing
+from ..runtime import tracing, workspace
 
 MAX_AUC_BINS = 400  # AUC2.NBINS
 
@@ -39,44 +39,60 @@ class ScoreOrder(NamedTuple):
     path: str        # how the permutation was made: "packed32" | "argsort"
 
 
-def _stable_order(p: np.ndarray):
+def _stable_order(p: np.ndarray, take=workspace.fresh):
     """`(order, p[order], path)` with `order` the stable ascending argsort
     of float64 `p`. Scores that float32 holds exactly and that carry no sign
     bit order like their bit patterns, so there the permutation comes from
     one sort of the uint64 keys `bits << 32 | row` (the row breaks ties in
-    row order): the same permutation, several times sooner."""
+    row order): the same permutation, several times sooner. `take` makes
+    the row-sized arrays (`runtime/workspace.py`); what is n-sized besides
+    is made a block at a time."""
     n = len(p)
+    p32 = take("order.p32", n, np.float32)
     with np.errstate(over="ignore"):
-        p32 = p.astype(np.float32)
+        np.copyto(p32, p, casting="same_kind")
     if (0 < n < 2 ** 32 and p32.view(np.int32).min() >= 0
             and np.array_equal(p32, p)):
-        key = p32.view(np.uint32).astype(np.uint64)
+        key = take("order.key", n, np.uint64)
+        np.copyto(key, p32.view(np.uint32))
         key <<= np.uint64(32)
-        key |= np.arange(n, dtype=np.uint64)
+        for b in workspace.blocks(n):
+            key[b] |= np.arange(b.start, b.stop, dtype=np.uint64)
         key.sort()
-        order = (key & np.uint64(0xFFFFFFFF)).view(np.int64)
-        key >>= np.uint64(32)
-        ps = key.astype(np.uint32).view(np.float32).astype(np.float64)
-        return order, ps, "packed32"
+        ps = take("order.ps", n, np.float64)
+        for b in workspace.blocks(n):
+            ps[b] = (key[b] >> np.uint64(32)).astype(np.uint32).view(
+                np.float32)
+        key &= np.uint64(0xFFFFFFFF)
+        return key.view(np.int64), ps, "packed32"
     order = np.argsort(p, kind="stable")
     return order, p[order], "argsort"
 
 
-def order_scores(y: np.ndarray, p: np.ndarray) -> ScoreOrder:
+def order_scores(y: np.ndarray, p: np.ndarray,
+                 take=workspace.fresh) -> ScoreOrder:
     """The one O(n log n) step of the binomial metrics."""
-    order, ps, path = _stable_order(np.asarray(p, np.float64))
-    cum = np.zeros(len(ps) + 1)
-    np.cumsum(np.asarray(y, np.float64)[order], out=cum[1:])
+    order, ps, path = _stable_order(np.asarray(p, np.float64), take)
+    cum = take("order.cum", len(ps) + 1, np.float64)
+    cum[0] = 0.0
+    np.take(np.asarray(y, np.float64), order, out=cum[1:], mode="clip")
+    np.cumsum(cum[1:], out=cum[1:])
     return ScoreOrder(ps, cum, path)
 
 
 def roc_curve_binned(y: np.ndarray, p: np.ndarray, nbins: int = MAX_AUC_BINS,
-                     ordering: Optional[ScoreOrder] = None):
+                     ordering: Optional[ScoreOrder] = None,
+                     take=workspace.fresh):
     """AUC2's design: histogram scores into <=400 threshold bins, then sweep.
     `ordering`, where the caller has one, is `order_scores(y, p)`."""
     ps, cum, _ = ordering or order_scores(y, p)
     n = len(ps)
-    qs = np.unique(np.quantile(ps, np.linspace(0, 1, nbins)))
+    # np.quantile partitions a copy of its input: the copy is made here,
+    # where `take` decides whose memory it is
+    work = take("roc.work", n, np.float64)
+    np.copyto(work, ps)
+    qs = np.unique(np.quantile(work, np.linspace(0, 1, nbins),
+                               overwrite_input=True))
     # bin b holds the scores in (qs[b-1], qs[b]] — searchsorted(qs, p,
     # "left") — and is counted between the places of its two edges in the
     # sorted scores; the last bin, above the maximum, stays empty
@@ -93,7 +109,8 @@ def roc_curve_binned(y: np.ndarray, p: np.ndarray, nbins: int = MAX_AUC_BINS,
 
 
 def auc_exact(y: np.ndarray, p: np.ndarray,
-              ordering: Optional[ScoreOrder] = None) -> float:
+              ordering: Optional[ScoreOrder] = None,
+              take=workspace.fresh) -> float:
     """Exact rank AUC (ties handled) — matches AUC2 in the limit of one bin
     per distinct score. `ordering` as for `roc_curve_binned`."""
     ps, cum, _ = ordering or order_scores(y, p)
@@ -105,9 +122,22 @@ def auc_exact(y: np.ndarray, p: np.ndarray,
     # a run of tied scores [start, end) shares the average of its ranks
     # start+1 .. end, so the positives' rank sum is a sum over runs, not
     # over rows: `edge` holds every run's start and, one on, its end
-    edge = np.flatnonzero(np.concatenate(([True], ps[1:] != ps[:-1], [True])))
-    ranks = np.diff(cum[edge])          # each run's positives, times ...
-    ranks *= edge[:-1] + edge[1:]       # ... twice its average rank, less 1
+    ne = take("auc.ne", n + 1, np.bool_)
+    ne[0] = ne[n] = True
+    np.not_equal(ps[1:], ps[:-1], out=ne[1:n])
+    m = int(np.count_nonzero(ne))
+    edge = take("auc.edge", m, np.intp)
+    k = 0
+    for b in workspace.blocks(n + 1):
+        at = np.flatnonzero(ne[b])
+        at += b.start
+        edge[k:k + len(at)] = at
+        k += len(at)
+    ranks = take("auc.ranks", m - 1, np.float64)
+    for b in workspace.blocks(m - 1):
+        lo, hi = edge[b], edge[b.start + 1:b.stop + 1]
+        np.subtract(cum[hi], cum[lo], out=ranks[b])  # a run's positives ...
+        ranks[b] *= lo + hi         # ... times twice its average rank, less 1
     rank_sum = (ranks.sum() + npos) / 2
     return float((rank_sum - npos * (npos + 1) / 2) / (npos * nneg))
 
@@ -216,6 +246,21 @@ def gains_lift_table(y: np.ndarray, p: np.ndarray, groups: int = 16,
     return rows
 
 
+# `ModelMetricsBinomial.make`'s row-sized arrays by stage. Those of different
+# stages are never alive together and share a buffer, so a thread that scores
+# n rows keeps seven of 8n bytes: y, p, the sorted scores, the running sums
+# and these three.
+_MAKE_SHARED = {
+    "order.key": "w0", "auc.edge": "w0", "logloss.a": "w0", "roc.work": "w0",
+    "order.p32": "w1", "auc.ranks": "w1", "logloss.b": "w1",
+    "auc.ne": "w2", "logloss.c": "w2", "confusion": "w2",
+}
+
+
+def _make_buffers(name: str, n: int, dtype) -> np.ndarray:
+    return workspace.take("metrics." + _MAKE_SHARED.get(name, name), n, dtype)
+
+
 @dataclass
 class ModelMetricsBinomial(ModelMetricsBase):
     auc: float = float("nan")
@@ -242,29 +287,57 @@ class ModelMetricsBinomial(ModelMetricsBase):
         # ONE ordering of the scores (`metrics.order`), then the three order
         # statistics read off it, each a child span of the fit's
         # `fit.metrics` (docs/observability.md): the exact AUC, the binned
-        # threshold sweep, the gains/lift table
-        y = np.asarray(y, np.float64)
-        p = np.clip(np.asarray(p, np.float64), 1e-15, 1 - 1e-15)
+        # threshold sweep, the gains/lift table. Every row-sized array of it
+        # lives in this thread's work buffers (`runtime/workspace.py`), and
+        # nothing row-sized leaves: the arithmetic is the plain formulas',
+        # operation for operation, written into a buffer instead of a new
+        # array
+        take = _make_buffers
+        n = len(y)
+        y, y_in = take("make.y", n, np.float64), y
+        np.copyto(y, y_in, casting="unsafe")
+        p, p_in = take("make.p", n, np.float64), p
+        np.copyto(p, p_in, casting="unsafe")
+        np.clip(p, 1e-15, 1 - 1e-15, out=p)
         with tracing.span("metrics.order", kind="fit") as sp:
-            ordering = order_scores(y, p)
+            ordering = order_scores(y, p, take)
             sp.annotate(path=ordering.path)
         with tracing.span("metrics.auc", kind="fit"):
-            auc = auc_exact(y, p, ordering=ordering)
-        logloss = float(-np.mean(y * np.log(p) + (1 - y) * np.log(1 - p)))
-        mse = float(np.mean((p - y) ** 2))
+            auc = auc_exact(y, p, ordering=ordering, take=take)
+        # -mean(y log p + (1 - y) log(1 - p)), then mean((p - y)^2)
+        a = take("logloss.a", n, np.float64)
+        b = take("logloss.b", n, np.float64)
+        c = take("logloss.c", n, np.float64)
+        np.log(p, out=a)
+        a *= y
+        np.subtract(1, p, out=b)
+        np.log(b, out=b)
+        np.subtract(1, y, out=c)
+        b *= c
+        a += b
+        logloss = float(-np.mean(a)) if n else float("nan")
+        np.subtract(p, y, out=a)
+        np.multiply(a, a, out=a)
+        mse = float(np.mean(a)) if n else float("nan")
         # max-F1 threshold via the AUC2-style binned sweep
         with tracing.span("metrics.roc", kind="fit"):
             qs, tpr, fpr, tp, fp, P, Ntot = roc_curve_binned(
-                y, p, ordering=ordering)
+                y, p, ordering=ordering, take=take)
         fn = P - tp
         prec = tp / np.maximum(tp + fp, 1e-12)
         rec = tp / max(P, 1e-12)
         f1s = 2 * prec * rec / np.maximum(prec + rec, 1e-12)
         bi = int(np.argmax(f1s))
         thr = float(qs[min(bi, len(qs) - 1)]) if len(qs) else 0.5
-        yhat = (p >= thr).astype(np.float64)
-        tp_, fp_ = float(((yhat == 1) & (y == 1)).sum()), float(((yhat == 1) & (y == 0)).sum())
-        tn_, fn_ = float(((yhat == 0) & (y == 0)).sum()), float(((yhat == 0) & (y == 1)).sum())
+        # the confusion matrix at that threshold: four counts of rows
+        hat, pos, neg, both = take("confusion", 4 * n, np.bool_).reshape(4, n)
+        np.greater_equal(p, thr, out=hat)
+        np.equal(y, 1, out=pos)
+        np.equal(y, 0, out=neg)
+        tp_ = float(np.count_nonzero(np.logical_and(hat, pos, out=both)))
+        fp_ = float(np.count_nonzero(np.logical_and(hat, neg, out=both)))
+        tn_ = float(np.count_nonzero(neg)) - fp_
+        fn_ = float(np.count_nonzero(pos)) - tp_
         cm = np.asarray([[tn_, fp_], [fn_, tp_]])
         err0 = fp_ / max(tn_ + fp_, 1e-12)
         err1 = fn_ / max(tp_ + fn_, 1e-12)
@@ -274,10 +347,11 @@ class ModelMetricsBinomial(ModelMetricsBase):
         with tracing.span("metrics.gains", kind="fit"):
             gains = gains_lift_table(y, p, ordering=ordering)
         return ModelMetricsBinomial(
-            mse=mse, rmse=float(np.sqrt(mse)), nobs=len(y),
+            mse=mse, rmse=float(np.sqrt(mse)), nobs=n,
             auc=auc, pr_auc=pr_auc, logloss=logloss, gini=2 * auc - 1,
             mean_per_class_error=(err0 + err1) / 2, f1=float(f1s[bi]),
-            accuracy=float((yhat == y).mean()), confusion_matrix=cm, threshold=thr,
+            accuracy=(tp_ + tn_) / n if n else float("nan"),
+            confusion_matrix=cm, threshold=thr,
             gains_lift_table=gains,
             _roc=(fpr, tpr),
         )

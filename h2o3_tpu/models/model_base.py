@@ -198,6 +198,64 @@ class ScoreKeeper:
         return recent >= best_prior - margin
 
 
+def _column_moments(v: "Vec", fit: bool):
+    """`(c, isna, has_nan, n_ok, mean, std)` of a numeric column: the values
+    as float64, where they are NaN, and for a fit the count, `np.nanmean` and
+    `np.nanstd` of the rest (0.0 where there is none or the result is not
+    finite). The arithmetic is numpy's own, operation for operation (the
+    zero-filled sum over the count; the zero-filled squared deviations'
+    sum over the count), in two of this thread's work buffers
+    (`runtime/workspace.py`) where those two calls make six row-sized
+    temporaries. `c` and `isna` are good until the next call."""
+    from ..runtime import workspace
+
+    src = v.numeric_np() if v.type == "enum" else np.asarray(v.data)
+    n = len(src)
+    c = workspace.take("design.column", n, np.float64)
+    np.copyto(c, src, casting="unsafe")
+    isna = workspace.take("design.isna", n, np.bool_)
+    np.isnan(c, out=isna)
+    n_ok = n - int(np.count_nonzero(isna))
+    has_nan = n_ok < n
+    if not fit or not n_ok:
+        return c, isna, has_nan, n_ok if fit else 0, 0.0, 0.0
+    dev = workspace.take("design.deviation", n, np.float64)
+    np.copyto(dev, c)
+    with np.errstate(all="ignore"):
+        if has_nan:
+            np.copyto(dev, 0, where=isna)
+        mean = np.add.reduce(dev) / n_ok
+        dev -= mean
+        if has_nan:
+            np.copyto(dev, 0, where=isna)
+        dev *= dev
+        std = np.sqrt(np.add.reduce(dev) / n_ok)
+    return (c, isna, has_nan, n_ok,
+            float(mean) if np.isfinite(mean) else 0.0,
+            float(std) if np.isfinite(std) else 0.0)
+
+
+def _filled(c: np.ndarray, isna: Optional[np.ndarray], value: float):
+    """`c` with `value` where it is NaN: written into `c` where `isna`
+    comes with it (`_column_moments`' work buffer), a new array where not."""
+    if isna is None:
+        return np.where(np.isnan(c), value, c)
+    np.copyto(c, value, where=isna)
+    return c
+
+
+def _level_counts(codes: np.ndarray, K: int) -> np.ndarray:
+    """Rows at each of the first `K` levels, NAs (negative codes) left out:
+    `np.bincount` a block at a time (it widens its whole input to int64)."""
+    from ..runtime import workspace
+
+    cnt = np.zeros(K, np.intp)
+    for b in workspace.blocks(len(codes)):
+        blk = codes[b]
+        cnt += np.bincount(blk[blk >= 0], minlength=K)[:K]
+    return cnt
+
+
 class DataInfo:
     """`hex.DataInfo` — Frame → dense numeric design matrix.
 
@@ -284,13 +342,22 @@ class DataInfo:
         `quota` rows per process) and expanded in place, so multi-device
         meshes get the same byte-compressed transfer as a single chip —
         no dense f32 upload and no unsharded intermediate on device 0.
-        Requires fitted stats (fit=False; call fit_transform first — its
-        global-moment collectives keep standardization identical to the
-        dense path on every cloud size)."""
-        import jax
+        In ONE process `fit=True` fits the stats from the compact columns
+        on every cloud size (the host holds all rows, so they are the
+        one-device lane's bit for bit). A multi-PROCESS cloud holds only
+        its ingest shard: there the stats come from `fit_transform`'s
+        global-moment collectives first, then fit=False."""
         import jax.numpy as jnp
 
-        from ..runtime import tracing
+        from ..parallel import distdata
+        from ..runtime import tracing, workspace
+
+        multiproc = cloud is not None and distdata.multiprocess()
+        if fit and multiproc:
+            raise ValueError(
+                "device_design(fit=True) on a multi-process cloud would fit "
+                "this process's shard alone: fit_transform first, then "
+                "fit=False")
 
         # children of the fit's `fit.design` span (also opened when a
         # frame is scored): column statistics and imputation from the
@@ -337,23 +404,18 @@ class DataInfo:
                 if kind == "num":
                     if name in _pre:
                         c, has_nan, pre_m, pre_s, n_ok = _pre[name]
+                        isna = None
                     else:
-                        c = v.numeric_np()
-                        has_nan = bool(np.isnan(c).any())
-                        n_ok = int((~np.isnan(c)).sum()) if fit else 0
-                        pre_m = pre_s = 0.0
-                        if fit:
-                            with np.errstate(all="ignore"):
-                                pre_m = (float(np.nanmean(c)) if n_ok else 0.0)
-                                pre_s = (float(np.nanstd(c)) if n_ok else 0.0)
-                            pre_m = pre_m if np.isfinite(pre_m) else 0.0
-                            pre_s = pre_s if np.isfinite(pre_s) else 0.0
+                        # float64 in this thread's work buffer, good until
+                        # the next column: `nums` keeps a float32 copy
+                        c, isna, has_nan, n_ok, pre_m, pre_s = \
+                            _column_moments(v, fit)
                     if self.impute_missing:
                         if fit:
                             self.col_means[name] = pre_m
                         if has_nan:
-                            c = np.where(np.isnan(c),
-                                         self.col_means.get(name, 0.0), c)
+                            c = _filled(c, isna,
+                                        self.col_means.get(name, 0.0))
                             # post-impute plain std: mean-filling leaves the
                             # mean unchanged and shrinks the variance by the
                             # valid-row fraction (exactly, analytically)
@@ -372,7 +434,7 @@ class DataInfo:
                             mm = (means[-1][0] if fit
                                   else float(self.means[pos])
                                   if self.means is not None else 0.0)
-                            c = np.where(np.isnan(c), mm, c)
+                            c = _filled(c, isna, mm)
                         else:
                             c = np.nan_to_num(c, nan=0.0)
                     nums.append(c.astype(np.float32))
@@ -384,10 +446,10 @@ class DataInfo:
                             [dom.index(d) if d in dom else -1 for d in v.domain],
                             np.int64)
                         codes = np.where(codes >= 0, remap[np.maximum(codes, 0)], -1)
-                    cats.append(codes.astype(np.int32))
+                    cats.append(codes.astype(np.int32, copy=False))
                     if fit and self.standardize:
                         K = len(dom)
-                        cnt = np.bincount(codes[codes >= 0], minlength=K)[:K]
+                        cnt = _level_counts(codes, K)
                         p_lvl = cnt / max(n, 1)
                         lv = p_lvl if self.use_all else p_lvl[1:]
                         means.append(lv.tolist())
@@ -401,8 +463,12 @@ class DataInfo:
                     [s for grp in stds for s in grp], np.float64)
 
         with tracing.span("design.codes", kind="fit"):
-            cats_a = (np.stack(cats, axis=1) if cats
-                      else np.zeros((n, 0), np.int32))
+            # np.stack's rows, written a block of rows at a time: a column
+            # at a time walks the whole (n, k) array once a column
+            cats_a = np.empty((n, len(cats)), np.int32)
+            for b in workspace.blocks(n, 1 << 15):
+                for j, c in enumerate(cats):
+                    cats_a[b, j] = c[b]
         with tracing.span("design.groups", kind="fit"):
             # per-column transfer dtype: integer-valued small-range columns
             # ship as 1–2 bytes/value (LOSSLESS — C1Chunk/C2Chunk parity);
@@ -417,11 +483,16 @@ class DataInfo:
                     return True
                 if not c.size:
                     return False
-                with np.errstate(invalid="ignore"):
-                    if not bool(np.all(np.mod(c, 1.0) == 0.0)):
-                        return False
                 lo, hi = (0.0, 255.0) if g == 0 else (-32768.0, 32767.0)
-                return bool(lo <= c.min() and c.max() <= hi)
+                if not (lo <= c.min() and c.max() <= hi):
+                    return False
+                # in range, so finite: whole-valued where truncation changes
+                # nothing. A block at a time: a fractional column is known
+                # by its first block and nothing row-sized is made (six
+                # passes of np.mod(c, 1.0) were 1.7 s of a 2.6 s design
+                # build at 7,250,000 rows; PERF.md section 5)
+                return all(np.array_equal(c[b], np.trunc(c[b]))
+                           for b in workspace.blocks(c.size, 1 << 16))
 
             def _local_groups():
                 out = []
@@ -430,9 +501,6 @@ class DataInfo:
                                else 1 if _fits_group(c, 1) else 2)
                 return out
 
-            from ..parallel import distdata
-
-            multiproc = cloud is not None and distdata.multiprocess()
             if fit:
                 num_group = _local_groups()
                 self._transfer_groups = list(num_group)
@@ -466,11 +534,11 @@ class DataInfo:
             for c, g in zip(nums, num_group):
                 groups[g].append(c)
             dts = (np.uint8, np.int16, np.float32)
-            packs = [
-                (np.stack(g, axis=1).astype(dt) if g
-                 else np.zeros((n, 0), dt))
-                for g, dt in zip(groups, dts)
-            ]
+            packs = [np.empty((n, len(g)), dt) for g, dt in zip(groups, dts)]
+            for b in workspace.blocks(n, 1 << 15):
+                for pk, g in zip(packs, groups):
+                    for j, c in enumerate(g):
+                        pk[b, j] = c[b]
             gi = iter(num_group)
             sig = (tuple((k, next(gi) if k == "num" else (len(d) if d else 0))
                          for k, _, d in self._spec),
@@ -517,9 +585,11 @@ class DataInfo:
                 gc = distdata.global_row_array(cats_a, quota, cloud)
                 return fn(gp[0], gp[1], gp[2], gc, m_r, s_r)
 
-            with tracing.span("design.upload", kind="fit"):
+            with tracing.span("design.upload", kind="fit",
+                              devices=cloud.size, bytes_h2d=nbytes):
                 return _phases.accounted_h2d(_sharded, nbytes)
-        with tracing.span("design.upload", kind="fit"):
+        with tracing.span("design.upload", kind="fit", devices=1,
+                          bytes_h2d=nbytes):
             return _phases.accounted_h2d(
                 lambda: fn(jnp.asarray(packs[0]), jnp.asarray(packs[1]),
                            jnp.asarray(packs[2]), jnp.asarray(cats_a),

@@ -177,9 +177,9 @@ def global_row_array(local: np.ndarray, quota: int, cloud, fill=0):
         fill_block = np.full((pad,) + local.shape[1:], fill, local.dtype)
         local = np.concatenate([local, fill_block])
     if not multiprocess():
-        import jax.numpy as jnp
-
-        return jax.device_put(jnp.asarray(local), cloud.row_sharding())
+        # straight from the host buffer: each device is sent its own rows
+        # (through jnp.asarray the whole array would land on device 0 first)
+        return jax.device_put(local, cloud.row_sharding())
     return jax.make_array_from_process_local_data(
         cloud.row_sharding(), local)
 
