@@ -468,6 +468,12 @@ class _StepCfg(NamedTuple):
     #                 since ISSUE 18's pod lane)
     shard_mode: str = "off"
     n_shards: int = 0                # canonical total block count (S)
+    # the form of the step programs' code argument (`_cfg_operand_form`):
+    #   "program" — the resident codes; the program widens what it reads
+    #   "fit"     — the pair (resident codes, the fit's Pallas code operand
+    #               of `ops.histogram.build_code_operand`): the kernel's
+    #               levels and the partition select read the operand
+    code_operand: str = "program"
 
 
 def _pack_hp(tp, lr, colp, mtries_rate=0.0) -> "jnp.ndarray":
@@ -516,6 +522,7 @@ def _concat_args(*xs):
 # sub-byte code packing lives in ops/packing.py since ISSUE 7 (the
 # histogram kernels and the partition step consume the packed words
 # directly); these aliases keep the driver's historical surface
+from ..ops import histogram as _hist
 from ..ops import packing as _packing
 from ..ops.histogram import record_fit_plan as _record_fit_plan
 from ..ops.histogram import resolve_method as _resolve_method
@@ -523,6 +530,23 @@ from ..ops.histogram import resolve_method as _resolve_method
 _pack_host = _packing.pack_host
 _unpack_device = _packing.unpack_device
 _pack_bits_for = _packing.pack_bits_for
+
+
+def _cfg_operand_form(cfg: "_StepCfg") -> dict:
+    """`ops.histogram.code_operand_form` of a step configuration's own
+    levels, kernel and shard lane: `_make_step_cfg` takes `code_operand`
+    from it, the fit and the warm-up thread the operand's `row_chunk`.
+    Lossguide growth has no level plan and takes full-width codes."""
+    levels = (treelib.histogram_level_plan(cfg.max_depth, cfg.compact_cap)
+              if cfg.grow_policy != "lossguide" else [])
+    return _hist.code_operand_form(levels, cfg.nbins, cfg.hist_method,
+                                   cfg.shard_mode)
+
+
+def _operand_shape(cfg: "_StepCfg") -> tuple:
+    """Shape of `ops.histogram.build_code_operand`'s array for `cfg`."""
+    return _hist.code_operand_shape(cfg.F, cfg.npad,
+                                    _cfg_operand_form(cfg)["row_chunk"])
 
 
 def _shard_plan(ndev: int, multiproc: bool, tp) -> tuple:
@@ -627,7 +651,10 @@ def _build_tree_step_fns(cfg: _StepCfg, cloud):
     All data (including the monotone-constraint vector) arrives as
     ARGUMENTS — a closure-captured device array would be embedded in the
     HLO as a literal, defeating the persistent compilation cache and
-    bloating programs."""
+    bloating programs. The code argument `codes_a` has the form
+    `cfg.code_operand` names: the resident codes, or the pair of them and
+    the fit's histogram-kernel operand, which the program then reads
+    instead of widening the codes itself."""
     npad, K, F = cfg.npad, cfg.K, cfg.F
 
     def _grads(margins, y_d, k):
@@ -642,6 +669,9 @@ def _build_tree_step_fns(cfg: _StepCfg, cloud):
         )
 
     def _build_one(codes, g, h, w, fm, edges, mono, hp, key):
+        operand = None
+        if cfg.code_operand == "fit":
+            codes, operand = codes
         if cfg.grow_policy == "lossguide":
             lg_kwargs = dict(max_depth=cfg.max_depth, nbins=cfg.nbins,
                              max_leaves=cfg.max_leaves,
@@ -739,7 +769,7 @@ def _build_tree_step_fns(cfg: _StepCfg, cloud):
         return treelib.build_tree(
             codes, g, h, w, fm, edges, key=key, max_abs_leaf=hp[7],
             min_rows=hp[0], min_split_improvement=hp[1],
-            reg_lambda=hp[2], reg_alpha=hp[3], **kwargs)
+            reg_lambda=hp[2], reg_alpha=hp[3], operand=operand, **kwargs)
 
     def _one_tree(margins, codes_a, y_a, w_a, rate_a, edges_a, mono, hp,
                   key, m, g_ext=None, h_ext=None):
@@ -1402,7 +1432,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
         what the estimator (or H2O3_HIST_METHOD) named, a structural cfg
         field → program-cache key, so an in-process flip retraces instead
         of being silently frozen into a cached program;
-        `ops.histogram.resolve_method` turns `auto` into a kernel."""
+        `ops.histogram.resolve_method` turns `auto` into a kernel.
+        `code_operand` follows from the rest (`_cfg_operand_form`)."""
         mtries = self._resolved_mtries(tp, F, problem)
         colp = tp["col_sample_rate"] * tp["col_sample_rate_per_tree"]
         hist_method = os.environ.get(
@@ -1410,7 +1441,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         # an unknown name is refused here, before the warm-up thread or
         # the fit traces anything
         _resolve_method(1, nbins, hist_method)
-        return _StepCfg(
+        cfg = _StepCfg(
             npad=npad, K=K, F=F, nbins=nbins, problem=problem, dist=dist,
             mode=self._mode, max_depth=tp["max_depth"],
             has_mtries=mtries > 0,
@@ -1441,6 +1472,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 and getattr(self, "_objective_fn", None) is None
                 else 0),
         )
+        return cfg._replace(code_operand=_cfg_operand_form(cfg)["form"])
 
     @staticmethod
     def _validate_tree_params(tp) -> None:
@@ -1933,9 +1965,10 @@ class H2OSharedTreeEstimator(H2OEstimator):
 
         # ---- resident sub-byte code packing (ISSUE 7 tentpole) -----------
         # The device-resident code matrix stays PACKED for the whole fit:
-        # the histogram kernels consume the packed words (widened once
-        # per program) and the partition step reads per-row codes from the
-        # same widened codes — so the matrix the dataset cache holds in HBM
+        # the histogram kernels and the partition step read its widened
+        # form (built once a fit where the plan runs the Pallas kernel —
+        # "the histogram kernel's code operand" below — else once per tree
+        # program) — so the matrix the dataset cache holds in HBM
         # (and ships through the host↔device link) shrinks 2-4×. Paths that
         # score `predict_codes` against the resident matrix (DART dropout,
         # checkpoint fast-forward) and the lossguide builder keep full width.
@@ -2028,11 +2061,16 @@ class H2OSharedTreeEstimator(H2OEstimator):
             def _warm():
                 try:
                     tj, _ = _tree_step_fns(cfg_early, cloud)
+                    codes_dummy = jnp.zeros(codes_shape, code_dt)
+                    if cfg_early.code_operand == "fit":
+                        # one-device fits only: no sharding below
+                        codes_dummy = (codes_dummy, jnp.zeros(
+                            _operand_shape(cfg_early), jnp.float32))
                     args = [
                         jnp.zeros((npad, K), jnp.float32),                # margins
                         jnp.zeros((npad, K) if drf else (1, K), jnp.float32),
                         jnp.zeros(npad if drf else 1, jnp.float32),
-                        jnp.zeros(codes_shape, code_dt),                  # codes
+                        codes_dummy,                                      # codes
                         jnp.zeros((npad, K), jnp.float32),                # y
                         jnp.zeros(npad, jnp.float32),                     # w
                         jnp.ones(npad, jnp.float32),                      # rate
@@ -2327,6 +2365,34 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     edges_d = jax.device_put(edges_d, cloud.replicated())
                     margins = jax.device_put(margins, cloud.row_sharding())
 
+        cfg = self._make_step_cfg(tp, npad, K, F, nbins, problem, dist,
+                                  pack_bits=resident_bits,
+                                  shard_mode=shard_mode, n_shards=n_shards)
+        if ooc_blocks and cfg.compact_cap:
+            # the streamed level loop is dense-only; deep streamed fits
+            # keep exactness by skipping active-node compaction (the
+            # in-core comparator must match — docs/perf.md)
+            cfg = cfg._replace(compact_cap=0)
+        # ---- the histogram kernel's code operand, once a fit ---------------
+        # The codes do not change during a fit, so where the plan runs the
+        # Pallas kernel on one device (`_cfg_operand_form`) ONE program
+        # widens them here into the kernel's feature-major float32 operand
+        # and every tree program takes that as an argument; a tree program
+        # that widened for itself did the same 1.3 GB of work every tree
+        # (225 of a 456 ms HIGGS tree). Dispatched, not waited for: the
+        # device builds it while the host goes on. It is this fit's alone —
+        # the resident, cached artefact stays the packed `codes_d` — and
+        # goes with the fit's other device state.
+        codes_arg = codes_d
+        operand_bytes = 0
+        if cfg.code_operand == "fit":
+            _ph.stage("design.operand")
+            operand_d = _hist.build_code_operand(
+                codes_d, cfg.pack_bits, _cfg_operand_form(cfg)["row_chunk"])
+            operand_bytes = int(operand_d.nbytes)
+            _ph.stage_span.annotate(bits=cfg.pack_bits, bytes=operand_bytes)
+            codes_arg = (codes_d, operand_d)
+
         # validation margins tracked incrementally per tree (the Score pass of
         # SharedTree.Driver on the validation frame) — early stopping uses the
         # validation metric when a validation_frame is given (ScoreKeeper).
@@ -2420,14 +2486,6 @@ class H2OSharedTreeEstimator(H2OEstimator):
         colp = tp["col_sample_rate"] * tp["col_sample_rate_per_tree"]
         custom_obj = getattr(self, "_objective_fn", None)
         mono_vec = getattr(self, "_monotone_vec", None)
-        cfg = self._make_step_cfg(tp, npad, K, F, nbins, problem, dist,
-                                  pack_bits=resident_bits,
-                                  shard_mode=shard_mode, n_shards=n_shards)
-        if ooc_blocks and cfg.compact_cap:
-            # the streamed level loop is dense-only; deep streamed fits
-            # keep exactness by skipping active-node compaction (the
-            # in-core comparator must match — docs/perf.md)
-            cfg = cfg._replace(compact_cap=0)
         # per-fit kernel plan (ISSUE 7 satellite): resolve + record which
         # histogram kernel each level will actually run (method, pallas
         # row_chunk, VMEM-pressure fallbacks — logged once per fit) into
@@ -2448,7 +2506,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
             pack_bits=cfg.pack_bits,
             n_shards=cfg.n_shards, n_devices=ndev_eff,
             partition_read=plan_read,
-            rank=getattr(custom_obj, "rank_plan", None))
+            rank=getattr(custom_obj, "rank_plan", None),
+            code_operand=cfg.code_operand, operand_bytes=operand_bytes)
         # per-lane collective skew of THIS fit (ISSUE 13): fences recorded
         # after this sequence point belong to this fit (training is
         # serialized on meshes via training_guard)
@@ -2507,7 +2566,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             packed_list, gains_list, ov_list = [], [], []
             for i in range(nsteps):
                 margins, oob_sum, oob_cnt, packed, gains, ov = tree_fn(
-                    margins, oob_sum, oob_cnt, codes_d, y_d, w_d, rate_d,
+                    margins, oob_sum, oob_cnt, codes_arg, y_d, w_d, rate_d,
                     edges_d, mono_d, hp_d, key, np.int32(m0 + i)
                 )
                 # CPU mesh: one collective executable in flight at a time
@@ -2904,7 +2963,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 else:
                     g_ext, h_ext = custom_obj(margins[:, 0], y_d[:, 0])
                 margins, packed, gains = _single_jit(
-                    margins, codes_d, y_d, w_d, rate_d, edges_d, mono_d,
+                    margins, codes_arg, y_d, w_d, rate_d, edges_d, mono_d,
                     hp_d, key, jnp.int32(m), g_ext, h_ext
                 )
                 cloudlib.collective_fence(margins)

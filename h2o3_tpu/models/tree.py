@@ -111,32 +111,44 @@ def partition_read(n_features: int) -> str:
     return "gather" if n_features > _ONEHOT_LOOKUP_MAX else "select"
 
 
-def _row_codes(codes: jax.Array, rf: jax.Array, pack_bits: int = 0) -> jax.Array:
+def _row_codes(codes: jax.Array, rf: jax.Array, pack_bits: int = 0,
+               operand: Optional[jax.Array] = None) -> jax.Array:
     """codes[i, rf[i]] as int32 — the code of the feature row i's node
     split on; the one place every partition step and training-time walk
     over packed codes asks for it.
 
-    Packed codes (`pack_bits` in {4, 5, 6}) are widened first. Narrow
-    frames then select densely over the feature axis of
-    `ops.histogram.feature_major`, the (F, N) float32 buffer the Pallas
-    histogram kernel takes: the program builds it once and every level's
-    select is one more streaming read of it. Nothing gathers into the code
-    matrix: on the TPU a per-row gather costs ~20 ns a row (228.6 ms at
-    11.5M rows) where the compare-select-sum is bound by bytes.
+    Narrow frames select densely over the feature axis of the (F, N)
+    float32 feature-major codes the Pallas histogram kernel takes: every
+    level's select is one more streaming read of that buffer. Nothing
+    gathers into the code matrix: on the TPU a per-row gather costs ~20 ns
+    a row (228.6 ms at 11.5M rows) where the compare-select-sum is bound by
+    bytes. Given `operand` — the fit's `ops.histogram.build_code_operand`
+    array, padded past F features and N rows with -1 — the select reads
+    its first F × N and nothing is widened here; without it, packed codes
+    (`pack_bits` in {4, 5, 6}) are widened and laid feature-major in this
+    program (`ops.histogram.feature_major`).
 
     Frames wider than `_ONEHOT_LOOKUP_MAX` keep the gather, from the
-    widened codes (`partition_read`)."""
+    row-major codes (widened here when packed; `partition_read`)."""
     if pack_bits:
-        codes = packing.unpack_device(codes, pack_bits)
-    F = codes.shape[1]
+        F = codes.shape[1]
+        N = packing.packed_nrows(codes.shape[0], pack_bits)
+    else:
+        N, F = codes.shape
     read = partition_read(F)
     record_partition_read(read)
-    if read == "gather":
-        return jnp.take_along_axis(
-            codes, rf[:, None].astype(jnp.int32), axis=1)[:, 0].astype(jnp.int32)
+    if read == "select" and operand is not None:
+        codes_fm = operand[:F, :N]
+    else:
+        if pack_bits:
+            codes = packing.unpack_device(codes, pack_bits)
+        if read == "gather":
+            return jnp.take_along_axis(
+                codes, rf[:, None].astype(jnp.int32),
+                axis=1)[:, 0].astype(jnp.int32)
+        codes_fm = feature_major(codes)
     feat_oh = rf[None, :] == jnp.arange(F, dtype=jnp.int32)[:, None]
-    return jnp.where(feat_oh, feature_major(codes), 0.0).sum(
-        axis=0).astype(jnp.int32)
+    return jnp.where(feat_oh, codes_fm, 0.0).sum(axis=0).astype(jnp.int32)
 
 
 @jax.named_scope("tree.leaf")
@@ -355,6 +367,7 @@ def build_tree(
     compact_cap: int = 0,
     pack_bits: int = 0,
     n_shard_blocks: int = 0,
+    operand: Optional[jax.Array] = None,  # the fit's Pallas code operand
 ):
     """Build one tree; returns (Tree, final_leaf_heap_idx (N,),
     gain_per_feature (F,), cover (T,) — Σ training row weights per heap node,
@@ -382,10 +395,21 @@ def build_tree(
     monotone=None.
 
     pack_bits in {4, 5, 6} means `codes` is the `ops.packing` packed word
-    matrix: histogram kernels consume it (widened once per program) and the
-    partition step reads each row's selected-feature code from the same
-    widened codes by a dense select over the feature axis (`_row_codes`;
-    no gather into the code matrix at F <= `_ONEHOT_LOOKUP_MAX`).
+    matrix: without `operand` the histogram kernels widen it once per
+    program, and the partition step reads each row's selected-feature code
+    from the same widened codes by a dense select over the feature axis
+    (`_row_codes`; no gather into the code matrix at
+    F <= `_ONEHOT_LOOKUP_MAX`).
+
+    `operand` is the fit-lifetime form of the same codes: the Pallas
+    histogram kernel's feature-major float32 operand, built once a fit by
+    `ops.histogram.build_code_operand` (`code_operand_form` says for which
+    fits). Given it, the kernel's levels and the select read it and this
+    program widens nothing for them; the gather read at
+    F > `_ONEHOT_LOOKUP_MAX` and a level that fell back to ``segment``
+    still read `codes`. The values are the same either way, so the tree is
+    bit for bit the same. One device only: the blocked reduction
+    (`n_shard_blocks`) refuses it.
 
     n_shard_blocks > 0 (ISSUE 12) makes every row reduction (histograms
     and final leaf totals) use the shard-invariant blocked fold of
@@ -435,7 +459,7 @@ def build_tree(
             hist = build_histograms(
                 codes, idx, g, h, w, L, nbins, method=hist_method,
                 axis_name=axis_name, pack_bits=pack_bits,
-                n_shard_blocks=n_shard_blocks,
+                n_shard_blocks=n_shard_blocks, operand=operand,
             )  # (L, F, B, 3)
         else:
             # sibling subtraction (the gpu_hist/LightGBM trick): build only
@@ -446,6 +470,7 @@ def build_tree(
                 codes, idx // 2, g, h, w * is_left.astype(w.dtype),
                 L // 2, nbins, method=hist_method, axis_name=axis_name,
                 pack_bits=pack_bits, n_shard_blocks=n_shard_blocks,
+                operand=operand,
             )  # (L/2, F, B, 3) indexed by parent
             hist_right = hist_prev - hist_left
             hist = jnp.stack([hist_left, hist_right], axis=1).reshape(
@@ -505,7 +530,7 @@ def build_tree(
             rf = _lookup_int(bf, idx, L)
             rb = _lookup_int(bb, idx, L)
             rs = _lookup_bool(do_split, idx, L)
-            rcode = _row_codes(codes, rf, pack_bits)
+            rcode = _row_codes(codes, rf, pack_bits, operand)
             go_right = (rcode > rb) & rs
             idx = 2 * idx + go_right.astype(jnp.int32)
         if row_leaf is not None:
@@ -577,7 +602,7 @@ def build_tree(
     slot_hist = build_histograms(
         codes, row_slot, g, h, w * (row_slot < CAP).astype(w.dtype),
         CAP + 1, nbins, method=hist_method, axis_name=axis_name,
-        pack_bits=pack_bits, n_shard_blocks=n_shard_blocks)
+        pack_bits=pack_bits, n_shard_blocks=n_shard_blocks, operand=operand)
 
     pad_edges_c = jnp.concatenate(
         [edges.astype(jnp.float32), jnp.full((F, 1), jnp.inf, jnp.float32)],
@@ -627,7 +652,7 @@ def build_tree(
             rs_do = do[row_slot]
             bf_r = bf[row_slot]
             bb_r = bb[row_slot]
-            rcode = _row_codes(codes, bf_r, pack_bits)
+            rcode = _row_codes(codes, bf_r, pack_bits, operand)
             go_right = (rcode > bb_r) & rs_do
         child_local = 2 * slot_node[row_slot] + go_right.astype(jnp.int32)
         row_leaf = jnp.where(rs_do, (2 ** (d + 1) - 1) + child_local,
@@ -657,7 +682,7 @@ def build_tree(
         hl = build_histograms(codes, row_slot, g, h, wl, CAP + 1, nbins,
                               method=hist_method, axis_name=axis_name,
                               pack_bits=pack_bits,
-                              n_shard_blocks=n_shard_blocks)
+                              n_shard_blocks=n_shard_blocks, operand=operand)
         prc = jnp.minimum(pr, CAP)
         hl_p = hl[prc]
         hp_p = slot_hist[prc]

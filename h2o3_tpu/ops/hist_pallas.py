@@ -18,15 +18,16 @@ the (3L, R) node-weighted values are built once in scratch, and each step
 computes hist[fb, 3L, 8·B] += weighted(3L,R) @ bin_onehot(R, 8·B).
 
 Packed-code input (ISSUE 7): the device-RESIDENT matrix is the 4/5/6-bit
-`ops.packing` word matrix; `build_histograms` widens it IN-GRAPH before
-the kernel, once per compiled tree program (XLA CSEs the widen across
-every level's pass — only a program-lifetime transient is full-width, the
-resident/cached/uploaded artifact stays packed). The factored kernel's
-operand (`ops.histogram.feature_major`) has a second reader in the
-same program: the partition step's dense select of each row's
-split-feature code (`models/tree._row_codes`). In-KERNEL sub-byte
-decode was evaluated and deferred: the factored kernel reads codes as
-8-sublane f32 feature blocks, while Mosaic's int8 minimum tile is
+`ops.packing` word matrix; the kernel's operand is its feature-major
+float32 widening (`ops.histogram.feature_major`). A one-device fit builds
+it ONCE, in a program of its own, already padded to this kernel's blocks
+(`ops.histogram.build_code_operand`), and hands it to every tree program;
+the blocked and mesh lanes still widen in-graph, once per tree program.
+The resident/cached/uploaded artifact stays packed either way. The operand
+has a second reader in the tree program: the partition step's dense select
+of each row's split-feature code (`models/tree._row_codes`). In-KERNEL
+sub-byte decode was evaluated and deferred: the factored kernel reads
+codes as 8-sublane f32 feature blocks, while Mosaic's int8 minimum tile is
 (32, 128) — a u8 packed operand would force a 32-feature block
 restructure (4× one-hot VMEM per step) or lane-strided unpacking of the
 interleaved row groups, neither validatable without a chip in the loop.
@@ -102,7 +103,8 @@ def _hist_kernel_factored(codes_ref, node_ref, vals_ref, out_ref, w_ref,
     out_ref[0] += h
 
 
-@functools.partial(jax.jit, static_argnames=("n_nodes", "nbins", "row_chunk"))
+@functools.partial(jax.jit, static_argnames=("n_nodes", "nbins", "row_chunk",
+                                             "n_features"))
 def build_histograms_pallas_factored(
     codes_t_bf: jax.Array,   # (F, N) float32 — PRE-TRANSPOSED feature-major
     node_id: jax.Array,      # (N,) int32
@@ -110,21 +112,28 @@ def build_histograms_pallas_factored(
     n_nodes: int,
     nbins: int,
     row_chunk: int = FACTORED_ROW_CHUNK,
+    n_features: int = 0,
 ) -> jax.Array:
     """(n_nodes, F, nbins, 3) histogram; the TPU fast path for L·R fitting
-    VMEM (the scratch is (3L, R) f32)."""
-    F, N = codes_t_bf.shape
+    VMEM (the scratch is (3L, R) f32). `codes_t_bf` may arrive already
+    padded with -1 (`ops.histogram.build_code_operand`: features to the
+    8-feature block, rows to a multiple of `row_chunk`); `n_features` then
+    says how many of its rows are features, and nothing of it is padded
+    here."""
+    Fin, Nin = codes_t_bf.shape
+    F = n_features or Fin
+    N = node_id.shape[0]
     L, B = n_nodes, nbins
     R = row_chunk
-    npad = ((N + R - 1) // R) * R
-    pad = npad - N
+    npad = ((max(N, Nin) + R - 1) // R) * R
     Fpad = ((F + _FB - 1) // _FB) * _FB
-    if pad or Fpad != F:
+    if (Fin, Nin) != (Fpad, npad):
         # pad codes with an out-of-range bin so padded rows match no bin
-        codes_t_bf = jnp.pad(codes_t_bf, ((0, Fpad - F), (0, pad)),
+        codes_t_bf = jnp.pad(codes_t_bf, ((0, Fpad - Fin), (0, npad - Nin)),
                              constant_values=-1.0)
-        node_id = jnp.pad(node_id.astype(jnp.int32), (0, pad))
-        vals = jnp.pad(vals, ((0, 0), (0, pad)))
+    if npad != N:
+        node_id = jnp.pad(node_id.astype(jnp.int32), (0, npad - N))
+        vals = jnp.pad(vals, ((0, 0), (0, npad - N)))
     node2 = node_id.astype(jnp.int32)[None, :]
     grid = (npad // R, Fpad // _FB)
     out = pl.pallas_call(

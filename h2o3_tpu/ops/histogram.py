@@ -21,13 +21,18 @@ VMEM fallback, ``segment`` on a CPU, ``onehot`` on other accelerators):
   scatter). The kernel of very large L·B (the VMEM fallback) and of
   CPU fits.
 * ``pallas_factored``: the fused VMEM kernel in
-  `hist_pallas.py`. With packed input it widens IN-GRAPH once per jitted
-  tree program (XLA CSEs the widen across every level's histogram pass of
-  the program), so the RESIDENT matrix — what the dataset cache holds
-  across fits and what the H2D upload moves — stays packed; only a
-  program-lifetime transient is full-width. True in-kernel sub-byte decode
-  is blocked by Mosaic's (32, 128) int8 tile granularity at the kernel's
-  8-feature block shape (see docs/perf.md).
+  `hist_pallas.py`. Its code operand is the feature-major float32 (F, N)
+  array `feature_major` names. Where a fit's plan runs this kernel on one
+  device, ONE program a fit (`build_code_operand`) widens the resident
+  codes into it, already padded to the kernel's blocks, and every tree
+  program takes it as an argument (`code_operand_form` is the rule, the
+  fit plan's `code_operand` says which form a fit ran). Elsewhere (the
+  blocked and mesh lanes, `run_block_kernel`) the program widens packed
+  input in-graph, once per program execution. Either way the RESIDENT
+  matrix — what the dataset cache holds across fits and what the H2D
+  upload moves — stays packed. True in-kernel sub-byte
+  decode is blocked by Mosaic's (32, 128) int8 tile granularity at the
+  kernel's 8-feature block shape (see docs/perf.md).
 
 The cross-host combine (ScoreBuildHistogram2.reduce / Rabit allreduce) is a
 single `lax.psum` over the ``hosts`` mesh axis, applied by the caller inside
@@ -54,6 +59,7 @@ metrics registry, and the tree driver records a per-fit level plan via
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from collections import deque
@@ -122,6 +128,12 @@ def _sel_registry() -> dict:
             "(trace-time): select = dense one-hot over the feature axis, "
             "gather = per-row gather (frames wider than the one-hot limit)",
             labelnames=("read",))
+        _SEL_REG["code_operand"] = _reg.counter(
+            "h2o3_tree_code_operand",
+            "tree fits by where the histogram kernel's code operand is "
+            "built: fit = one program a fit, handed to the tree programs; "
+            "program = widened inside every tree program",
+            labelnames=("built",))
     return _SEL_REG
 
 
@@ -196,11 +208,36 @@ def record_partition_read(read: str) -> None:
         pass
 
 
+def code_operand_form(levels, nbins: int, hist_method: str,
+                      shard_mode: str = "off",
+                      platform: Optional[str] = None) -> dict:
+    """The ONE rule for where the Pallas kernel's code operand is built:
+    ``{"form", "row_chunk"}``. ``"fit"``: one program a fit builds it
+    (`build_code_operand`) and the tree programs take it as an argument —
+    a one-device fit (`shard_mode` "off") any of whose `levels` (the
+    `(label, n_nodes)` of `tree.histogram_level_plan`) `resolve_method`
+    gives to ``pallas_factored``, packed and full-width codes alike;
+    `row_chunk` is then the largest row chunk of those levels, which every
+    other level's chunk divides (powers of two). ``"program"``: the tree
+    program widens what it needs itself — CPU fits (``segment``), the
+    blocked and mesh lanes, the streamed blocks, lossguide growth (which
+    passes no levels). Reads what the code can observe, no switch."""
+    sels = [resolve_method(n_nodes, nbins, hist_method, platform=platform)
+            for _, n_nodes in levels]
+    chunks = [sel["row_chunk"] for sel in sels
+              if sel["method"] == "pallas_factored"]
+    if shard_mode != "off" or not chunks:
+        return {"form": "program", "row_chunk": 0}
+    return {"form": "fit", "row_chunk": max(chunks)}
+
+
 def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
                     pack_bits: int = 0, platform: Optional[str] = None,
                     n_shards: int = 0, n_devices: int = 1,
                     partition_read: Optional[str] = None,
-                    rank: Optional[dict] = None) -> dict:
+                    rank: Optional[dict] = None,
+                    code_operand: str = "program",
+                    operand_bytes: int = 0) -> dict:
     """Resolve + record the per-level kernel plan of one tree fit.
 
     `levels` is a sequence of (label, n_nodes) histogram passes the fit
@@ -210,6 +247,10 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     plan in a bounded ring surfaced at /3/Profiler. `partition_read` is
     how the fit's levels read a row's split-feature code
     (`tree.partition_read`; None for a fit without a level partition).
+    `code_operand` is where the histogram kernel's code operand is built
+    (`code_operand_form`: ``"fit"`` once a fit and handed to the tree
+    programs, `operand_bytes` of it held for the fit; ``"program"`` inside
+    every tree program, 0 bytes held).
     `rank` is a pairwise ranking objective's own plan
     (`models.xgboost._make_lambdarank`), kept under the key `rank`:
     `queries`, `group_max`, `group_mean`, `pairs` (the real ordered pairs),
@@ -229,9 +270,14 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     plan = dict(tag=tag, ts=_time.time(), nbins=int(nbins),
                 hist_method=hist_method, pack_bits=int(pack_bits),
                 n_shards=int(n_shards), n_devices=int(n_devices),
-                partition_read=partition_read, levels=plan_levels)
+                partition_read=partition_read, code_operand=code_operand,
+                operand_bytes=int(operand_bytes), levels=plan_levels)
     if rank is not None:
         plan["rank"] = dict(rank)
+    try:
+        _sel_registry()["code_operand"].inc(1.0, code_operand)
+    except Exception:
+        pass
     if fellback:
         from ..runtime.log import Log
 
@@ -275,10 +321,11 @@ def kernel_stats() -> dict:
     `tree` fold). Pure counter read."""
     with _SEL_LOCK:
         plans = list(_FIT_PLANS)
-    out = dict(plans=plans, dispatch={}, partition_read={}, vmem_fallbacks=0)
+    out = dict(plans=plans, dispatch={}, partition_read={}, code_operand={},
+               vmem_fallbacks=0)
     try:
         reg = _sel_registry()
-        for fam in ("dispatch", "partition_read"):
+        for fam in ("dispatch", "partition_read", "code_operand"):
             out[fam] = {lv[0]: c.value()
                         for lv, c in reg[fam].children().items()}
         out["vmem_fallbacks"] = reg["vmem_fallbacks"].value()
@@ -394,18 +441,83 @@ def feature_major(codes: jax.Array) -> jax.Array:
     """Full-width (N, F) codes as the feature-major float32 (F, N) array,
     rows on the lanes (bin codes are exact in float32). The factored
     Pallas kernel's operand and the partition step's select
-    (`models/tree._row_codes`) both take it from HERE: one expression of
-    the program's loop-invariant codes, so XLA builds the buffer once per
-    program and every reader streams the same one."""
+    (`models/tree._row_codes`) both take it from HERE. Where a fit has a
+    fit-lifetime operand (`build_code_operand`) neither calls it; elsewhere
+    it is one expression of the program's loop-invariant codes, so XLA
+    builds the buffer once per program and every reader streams the same
+    one."""
     return codes.T.astype(jnp.float32)
 
 
+def code_operand_shape(n_features: int, n_rows: int, row_chunk: int) -> tuple:
+    """Shape of `build_code_operand`'s array: the features up to the Pallas
+    kernel's 8-feature block, the rows up to a multiple of `row_chunk`."""
+    from .hist_pallas import _FB
+
+    return (-(-n_features // _FB) * _FB, -(-n_rows // row_chunk) * row_chunk)
+
+
+# rows a step of `build_code_operand` widens: a multiple of every pack
+# group, and small enough that the step's temporaries (a row-major float32
+# block, 128 lanes a row whatever F is) stay ~0.13 GB
+_OPERAND_BLOCK_ROWS = 1 << 18
+
+
+@functools.partial(jax.jit, static_argnames=("pack_bits", "row_chunk"))
+def build_code_operand(codes: jax.Array, pack_bits: int,
+                       row_chunk: int) -> jax.Array:
+    """The Pallas histogram kernel's code operand for a whole fit, from the
+    resident codes (`ops.packing` words when `pack_bits`, else full width):
+    `feature_major` of the widened codes, in the kernel's final form —
+    features padded to its 8-feature block and rows to `row_chunk`
+    (`code_operand_form`) with -1, which matches no bin — so that
+    `hist_pallas.build_histograms_pallas_factored` has nothing left to pad
+    a level. ONE program a fit (`shared_tree._fit_phases`, span
+    `design.operand`); the tree programs take the result as an argument.
+    The values are the ones the in-program widen produces: the same
+    `unpack_device` and `feature_major`, over `_OPERAND_BLOCK_ROWS` rows
+    at a time written into the output in place, so the program's
+    temporaries are a block's and not the matrix's (at 11.5M x 28 the whole
+    matrix at once reserves 7.4 GB beside its 1.5 GB result)."""
+    F = codes.shape[1]
+    N = (packing.packed_nrows(codes.shape[0], pack_bits) if pack_bits
+         else codes.shape[0])
+    block = min(_OPERAND_BLOCK_ROWS, N)
+    stored = block * codes.shape[0] // N   # resident rows a block is stored in
+
+    def widen(rows):
+        if pack_bits:
+            rows = packing.unpack_device(rows, pack_bits)
+        return feature_major(rows)
+
+    def step(i, out):
+        rows = jax.lax.dynamic_slice_in_dim(codes, i * stored, stored)
+        return jax.lax.dynamic_update_slice(out, widen(rows), (0, i * block))
+
+    out = jnp.full(code_operand_shape(F, N, row_chunk), -1.0, jnp.float32)
+    whole = N // block
+    out = jax.lax.fori_loop(0, whole, step, out)
+    if N > whole * block:
+        out = jax.lax.dynamic_update_slice(
+            out, widen(codes[whole * stored:]), (0, whole * block))
+    return out
+
+
 def _run_kernel(sel: dict, codes, node_id, vals, n_nodes: int, nbins: int,
-                pack_bits: int):
-    """One resolved kernel invocation over one contiguous row range."""
+                pack_bits: int, operand=None):
+    """One resolved kernel invocation over one contiguous row range.
+    `operand` is the fit's `build_code_operand` array where the fit has
+    one: the Pallas kernel reads it and nothing is widened here."""
     method = sel["method"]
+    if method == "pallas_factored" and operand is not None:
+        from . import hist_pallas
+
+        return hist_pallas.build_histograms_pallas_factored(
+            operand, node_id, vals, n_nodes, nbins,
+            row_chunk=sel["row_chunk"],
+            n_features=codes.shape[1])
     if pack_bits:
-        # the kernels take dense codes: widen in-graph. The widen is
+        # these kernels take dense codes: widen in-graph. The widen is
         # a pure function of the loop-invariant packed input, so XLA
         # computes it once per program execution and shares the buffer
         # across every level's histogram pass; the RESIDENT matrix stays
@@ -447,6 +559,7 @@ def build_histograms(
     axis_name: Optional[str] = None,
     pack_bits: int = 0,
     n_shard_blocks: int = 0,
+    operand: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Histogram of {Σw, Σg, Σh} per (tree-node, feature, bin).
 
@@ -455,7 +568,10 @@ def build_histograms(
     cross-host merge (the MRTask.reduce step) when called under shard_map.
 
     With ``pack_bits`` in {4, 5, 6}, `codes` is the `ops.packing` packed
-    matrix, widened in-graph before accumulating.
+    matrix, widened in-graph before accumulating — unless `operand`, the
+    fit's `build_code_operand` array, is given: the Pallas kernel then
+    reads that and `codes` only where the level fell back to ``segment``.
+    One row range only: the blocked reduction takes no operand.
 
     ``n_shard_blocks`` > 0 switches to the shard-invariant blocked
     reduction (see module docstring): this call's rows are split into that
@@ -468,6 +584,8 @@ def build_histograms(
     sel = resolve_method(n_nodes, nbins, method)
     _record_selection(sel)
     if n_shard_blocks > 0:
+        if operand is not None:
+            raise ValueError("the blocked reduction takes no code operand")
         n = node_id.shape[0]
         if n % n_shard_blocks:
             raise ValueError(
@@ -482,7 +600,8 @@ def build_histograms(
                 vals[:, b * rows:(b + 1) * rows],
                 n_nodes, nbins, pack_bits))
         return ordered_axis_fold(jnp.stack(parts), axis_name)
-    hist = _run_kernel(sel, codes, node_id, vals, n_nodes, nbins, pack_bits)
+    hist = _run_kernel(sel, codes, node_id, vals, n_nodes, nbins, pack_bits,
+                       operand)
     if axis_name is not None:
         hist = jax.lax.psum(hist, axis_name)
     return hist  # (n_nodes, F, nbins, 3) — [..., 0]=Σw [..., 1]=Σg [..., 2]=Σh

@@ -21,16 +21,24 @@ bits   rows/group  bytes/group  bitstream
 
 Consumers:
 
-* ``unpack_device`` — whole-matrix widening on device: the in-graph
-  histogram kernels and the partition step (below) evaluate it inside the
-  tree program; a full-width resident fit ships packed and materializes
-  full width once.
-* ``ops/histogram.py`` — the kernels widen once per jitted tree program
-  (a program-lifetime transient — the resident matrix stays packed).
+* ``unpack_device`` — whole-matrix widening on device. Its row groups are
+  read through a reshape, ``packed.reshape(k, bytes, F)[:, i]``: the strided
+  slice ``packed[i::bytes]`` it used to take lowers to a gather on the
+  installed jax (38 ms a slice at 1.4M packed rows on a v5e). A full-width
+  resident fit ships packed and materializes full width once.
+* ``ops/histogram.build_code_operand`` — ONE program a fit widens the
+  resident words into the Pallas histogram kernel's operand (feature-major
+  float32, padded to the kernel's blocks) where the fit's plan runs that
+  kernel on one device; the tree programs take the operand as an argument
+  and widen nothing. The resident, cached, uploaded matrix stays packed.
+* Every other tree program (CPU fits on the ``segment`` kernel, the
+  blocked and mesh lanes, the streamed blocks, a level that fell back to
+  ``segment``) widens in-graph, once per program (XLA shares the widened
+  codes across the program's levels).
 * ``models/tree._row_codes`` — the partition step's per-row
   selected-feature code: a dense select over the feature axis of the SAME
-  widened codes (`unpack_device`, shared with the histogram kernels of the
-  program). Nothing gathers into the packed words: on the TPU a per-row
+  feature-major codes the kernel takes (the fit's operand where there is
+  one). Nothing gathers into the packed words: on the TPU a per-row
   gather costs ~20 ns a row, the select is a streaming read.
 """
 
@@ -135,16 +143,22 @@ def unpack_host(packed: np.ndarray, bits: int) -> np.ndarray:
     return out
 
 
+def _group_bytes(packed, bits: int):
+    """The packed words as one (k, F) uint16 array per byte of a pack group.
+    A reshape and a middle-axis index, not `packed[i::nbytes]`: the strided
+    slice lowers to `stablehlo.gather` on the installed jax."""
+    nbytes = GROUP_BYTES[bits]
+    grouped = packed.reshape((-1, nbytes) + packed.shape[1:])
+    return [grouped[:, i].astype(jnp.uint16) for i in range(nbytes)]
+
+
 @functools.partial(jax.jit, static_argnames=("bits",))
 def unpack_device(packed, bits: int):
     """Inverse of pack_host, on device: one widening program."""
     if bits == 4:
-        k = packed.shape[0]
-        out = jnp.stack([packed >> 4, packed & 0xF], axis=1)
-        return out.reshape((2 * k,) + packed.shape[1:]).astype(jnp.uint8)
-    if bits == 5:
-        b = [packed[i::5].astype(jnp.uint16) for i in range(5)]
-        k = packed.shape[0] // 5
+        vals = [packed >> 4, packed & 0xF]
+    elif bits == 5:
+        b = _group_bytes(packed, 5)
         vals = [
             b[0] >> 3,
             ((b[0] & 0x7) << 2) | (b[1] >> 6),
@@ -155,15 +169,11 @@ def unpack_device(packed, bits: int):
             ((b[3] & 0x3) << 3) | (b[4] >> 5),
             b[4] & 0x1F,
         ]
-        out = jnp.stack(vals, axis=1).reshape((8 * k,) + packed.shape[1:])
-        return out.astype(jnp.uint8)
-    b0 = packed[0::3].astype(jnp.uint16)
-    b1 = packed[1::3].astype(jnp.uint16)
-    b2 = packed[2::3].astype(jnp.uint16)
-    a = b0 >> 2
-    b = ((b0 & 0x3) << 4) | (b1 >> 4)
-    c = ((b1 & 0xF) << 2) | (b2 >> 6)
-    d = b2 & 0x3F
-    k = packed.shape[0] // 3
-    out = jnp.stack([a, b, c, d], axis=1).reshape((4 * k,) + packed.shape[1:])
+    else:
+        b0, b1, b2 = _group_bytes(packed, 6)
+        vals = [b0 >> 2, ((b0 & 0x3) << 4) | (b1 >> 4),
+                ((b1 & 0xF) << 2) | (b2 >> 6), b2 & 0x3F]
+    k = packed.shape[0] // GROUP_BYTES[bits]
+    out = jnp.stack(vals, axis=1).reshape(
+        (GROUP_ROWS[bits] * k,) + packed.shape[1:])
     return out.astype(jnp.uint8)

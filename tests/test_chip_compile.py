@@ -101,39 +101,89 @@ def test_fused_scorer_page_compiles_for_v5e(one_chip):
             max_depth=depth).compile()
 
 
-def test_tree_step_compiles_for_v5e(one_chip):
-    """The per-tree program the estimator's driver dispatches (gradients →
-    6 histogram levels → split search → partition → margin update) at
-    1M×28, depth 6, as `_make_step_cfg` resolves it for the flagship."""
+def _flagship_step_cfg(npad):
     from h2o3_tpu.models import shared_tree
     from h2o3_tpu.ops import packing
-    from h2o3_tpu.parallel import mesh as cloudlib
 
-    npad = 1 << 20
-    bits = packing.pack_bits_for(NBINS, npad)
     cfg = shared_tree._StepCfg(
         npad=npad, K=1, F=F, nbins=NBINS, problem="binomial",
         dist="bernoulli", mode="gbm", max_depth=6, has_mtries=False,
         no_row_sampling=True, has_col_sampling=False, has_monotone=False,
         tweedie_power=1.5, quantile_alpha=0.5, hist_method="pallas_factored",
-        pack_bits=bits)
+        pack_bits=packing.pack_bits_for(NBINS, npad))
+    # as `_make_step_cfg` resolves it: one device, the kernel's levels
+    return cfg._replace(
+        code_operand=shared_tree._cfg_operand_form(cfg)["form"])
+
+
+def _packed_rows(npad, bits):
+    from h2o3_tpu.ops import packing
+
+    return npad // packing.GROUP_ROWS[bits] * packing.GROUP_BYTES[bits]
+
+
+def test_tree_step_compiles_for_v5e(one_chip):
+    """The per-tree program the estimator's driver dispatches (gradients →
+    6 histogram levels → split search → partition → margin update) at
+    1M×28, depth 6, as `_make_step_cfg` resolves it for the flagship: its
+    code argument is the pair of the packed codes and the fit's operand,
+    and it widens nothing. Temporaries: 11,808,256 bytes, where the program
+    that widened for itself (PR 33, and still `code_operand="program"`)
+    holds 674,733,568 at this size, 512 MB of it the row-major float32
+    (N, 28→128) intermediate."""
+    import re
+
+    from h2o3_tpu.models import shared_tree
+    from h2o3_tpu.parallel import mesh as cloudlib
+
+    npad = 1 << 20
+    cfg = _flagship_step_cfg(npad)
+    assert cfg.code_operand == "fit"
     tree_jit, _ = shared_tree._build_tree_step_fns(cfg, cloudlib.cloud())
-    packed_rows = (npad // packing.GROUP_ROWS[bits]
-                   * packing.GROUP_BYTES[bits])
     f32, s = jnp.float32, one_chip
+    codes = (_sds((_packed_rows(npad, cfg.pack_bits), F), jnp.uint8, s),
+             _sds(shared_tree._operand_shape(cfg), f32, s))
+    assert codes[1].shape == (32, npad)
     compiled = tree_jit.lower(
         _sds((npad, 1), f32, s), _sds((1, 1), f32, s), _sds((1,), f32, s),
-        _sds((packed_rows, F), jnp.uint8, s), _sds((npad, 1), f32, s),
+        codes, _sds((npad, 1), f32, s),
         _sds((npad,), f32, s), _sds((npad,), f32, s),
         _sds((F, NBINS - 2), f32, s), _sds((F,), f32, s), _sds((9,), f32, s),
         _sds((2,), jnp.uint32, s), _sds((), jnp.int32, s)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 6
-    # the partition selects over the widened codes the kernel takes: in
-    # the chip's program no gather of the partition step survives, nor
-    # the flat int32 copy of the packed words it used to gather from
+    # nothing of the widen is left in the tree program: no packed word (the
+    # unused codes of the pair are not even a parameter of the executable),
+    # and no gather but the split thresholds' (one edge a node, at most 32)
+    assert "u8[" not in text
+    gathered = [int(n) for n in re.findall(r"= \w+\[(\d+)\][^=]* gather\(",
+                                           text)]
+    assert gathered and max(gathered) <= 32, gathered
     assert "tree.partition/gather" not in text
-    assert f"s32[{packed_rows * F}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
+@pytest.mark.parametrize("bits,nbins", [(4, 16), (5, NBINS), (6, 33)])
+def test_code_operand_program_compiles_for_v5e(one_chip, bits, nbins):
+    """The once-a-fit program that widens the resident codes into the
+    histogram kernel's operand (`build_code_operand`) at 1M×28: the row
+    groups are read through a reshape, so no gather is left of the strided
+    slices; the output is the kernel's padded (32, N) float32; and its
+    temporaries are one 262,144-row block's (134 MB at 5 bits; widening
+    the whole matrix in one go reserves 671 MB here, 7.4 GB at 11.5M
+    rows)."""
+    from h2o3_tpu.ops import packing
+
+    npad = 1 << 20
+    assert packing.pack_bits_for(nbins, npad) == bits
+    compiled = histogram.build_code_operand.lower(
+        _sds((_packed_rows(npad, bits), F), jnp.uint8, one_chip),
+        pack_bits=bits, row_chunk=8192).compile()
+    text = compiled.as_text()
+    assert "gather" not in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 32 * npad * 4
+    assert mem.temp_size_in_bytes < (192 << 20)
 
 
 # -- interpret-mode numerics on the CPU ---------------------------------------

@@ -72,6 +72,23 @@ def test_row_codes_exact(bits, B, F):
     assert np.array_equal(np.asarray(got), codes[np.arange(N), rf])
 
 
+@pytest.mark.parametrize("N", [8192, 8200, 24])
+@pytest.mark.parametrize("bits,B", [(4, 16), (5, 21), (6, 33)])
+def test_unpack_device_equals_unpack_host(bits, B, N):
+    """The device widen (row groups read through a reshape, no strided
+    slice) against the host's, at row counts that are and are not multiples
+    of the histogram kernel's row chunk; and the widen lowers to no gather."""
+    rng = np.random.default_rng(bits * 100 + N)
+    codes = rng.integers(0, B, (N, 5)).astype(np.uint8)
+    pk = packing.pack_host(codes, bits)
+    got = np.asarray(packing.unpack_device(jnp.asarray(pk), bits))
+    assert got.dtype == np.uint8
+    assert np.array_equal(got, packing.unpack_host(pk, bits))
+    assert np.array_equal(got, codes)
+    text = packing.unpack_device.lower(jnp.asarray(pk), bits=bits).as_text()
+    assert "gather" not in text
+
+
 @pytest.mark.parametrize("F,read", [(1, "select"), (28, "select"),
                                     (128, "select"), (129, "gather")])
 def test_partition_read_rule(F, read):
@@ -118,6 +135,85 @@ def test_build_tree_fused_packed_parity(variant):
     got = treelib.build_tree(jnp.asarray(pk), g, h, w, fm, edges,
                              pack_bits=bits, **kw)
     assert _leaves_equal(base, got)
+
+
+@pytest.mark.parametrize("F,N", [(28, 1024), (136, 520)])
+@pytest.mark.parametrize("bits,B", [(0, 21), (4, 16), (5, 21), (6, 33)])
+def test_build_tree_code_operand_parity(bits, B, F, N):
+    """`build_tree` handed the fit-lifetime operand against `build_tree`
+    handed the resident codes alone, the Pallas kernel in interpret mode:
+    the tree, the leaf indices, the gains and the covers are bit for bit
+    the same — through the dense select (F = 28) and the gather read
+    (F = 136), packed at every width and full width, at a row count the
+    operand pads (520 is no multiple of 8 x 128)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    codes, g, h, w, fm, edges, _ = _tree_data(N=N, F=F, B=B)
+    resident = jnp.asarray(packing.pack_host(codes, bits) if bits else codes)
+    depth = 3
+    form = histogram.code_operand_form(
+        treelib.histogram_level_plan(depth), B, "pallas_factored")
+    assert form["form"] == "fit" and form["row_chunk"] >= 512
+    kw = dict(max_depth=depth, nbins=B, min_rows=5.0, pack_bits=bits,
+              key=jax.random.PRNGKey(3), hist_method="pallas_factored")
+    with pltpu.force_tpu_interpret_mode():
+        operand = histogram.build_code_operand(resident, bits,
+                                               form["row_chunk"])
+        base = treelib.build_tree(resident, g, h, w, fm, edges, **kw)
+        got = treelib.build_tree(resident, g, h, w, fm, edges,
+                                 operand=operand, **kw)
+    assert operand.dtype == jnp.float32
+    assert operand.shape == (-(-F // 8) * 8,
+                             -(-N // form["row_chunk"]) * form["row_chunk"])
+    assert np.array_equal(np.asarray(operand[:F, :N]), codes.T)
+    assert np.all(np.asarray(operand[F:]) == -1)
+    assert np.all(np.asarray(operand[:, N:]) == -1)
+    assert _leaves_equal(base, got)
+
+
+@pytest.mark.parametrize("bits,B", [(0, 21), (4, 16), (5, 21), (6, 33),
+                                    (0, 300)])
+def test_code_operand_is_built_block_by_block(bits, B, monkeypatch):
+    """`build_code_operand` widens `_OPERAND_BLOCK_ROWS` rows a step and
+    a shorter last block: whole blocks, a tail and a matrix under one
+    block all give the widened codes feature-major, -1 beyond them."""
+    monkeypatch.setattr(histogram, "_OPERAND_BLOCK_ROWS", 64)
+    rng = np.random.default_rng(bits + B)
+    for N in (40, 192, 1000):      # shapes no other test traces
+        codes = rng.integers(0, B, (N, 7)).astype(
+            np.uint16 if B > 256 else np.uint8)
+        resident = jnp.asarray(packing.pack_host(codes, bits) if bits
+                               else codes)
+        got = np.asarray(histogram.build_code_operand(resident, bits, 128))
+        assert got.shape == (8, -(-N // 128) * 128)
+        assert np.array_equal(got[:7, :N], codes.T)
+        assert np.all(got[7:] == -1) and np.all(got[:, N:] == -1)
+
+
+def test_code_operand_rule():
+    """`code_operand_form`, the one rule: a one-device fit any of whose
+    levels the Pallas kernel runs gets the operand (at the largest of
+    those levels' row chunks); a CPU fit, a blocked or mesh lane and a
+    plan without levels widen inside the program."""
+    levels = treelib.histogram_level_plan(6)
+    fit = histogram.code_operand_form(levels, 21, "auto", platform="tpu")
+    assert fit == {"form": "fit", "row_chunk": 8192}
+    wide = histogram.code_operand_form(levels, 256, "auto", platform="tpu")
+    assert wide == {"form": "fit", "row_chunk": 2048}
+    program = {"form": "program", "row_chunk": 0}
+    assert histogram.code_operand_form(levels, 21, "auto",
+                                       platform="cpu") == program
+    for lane in ("blocks", "mesh", "mesh_psum"):
+        assert histogram.code_operand_form(
+            levels, 21, "pallas_factored", shard_mode=lane) == program
+    assert histogram.code_operand_form([], 21, "pallas_factored") == program
+    # a deep level that falls back to `segment` does not take the operand
+    # from the levels that run the kernel
+    deep = [("d0", 1), ("d16", 1 << 16)]
+    assert histogram.code_operand_form(
+        deep, 64, "pallas_factored")["form"] == "fit"
+    assert histogram.code_operand_form(
+        deep[1:], 64, "pallas_factored") == program
 
 
 def _row_gathers_over(text, numels):
@@ -322,6 +418,87 @@ def test_cv_fold_reuse_parity_packed_vs_full_width(cloud1, _tree_env):
     assert ma is not None and mb is not None
     np.testing.assert_array_equal(ma.logloss(), mb.logloss())
     np.testing.assert_array_equal(ma.auc(), mb.auc())
+
+
+# -- whole-fit parity: the code operand built once a fit · widened a tree ----
+
+def _fit_both_operand_forms(make_est):
+    """Train `make_est()` twice with the Pallas kernel (H2O3_HIST_METHOD,
+    which every tree estimator reads) in interpret mode:
+    as the rule says (one device, the kernel's levels: the operand is built
+    once a fit) and with the rule made to answer "program" (every tree
+    program widens the codes itself). Returns both with their fit plans."""
+    from jax.experimental.pallas import tpu as pltpu
+    from h2o3_tpu.models import shared_tree
+
+    out = []
+    with pltpu.force_tpu_interpret_mode():
+        for forced in (None, {"form": "program", "row_chunk": 0}):
+            with pytest.MonkeyPatch.context() as mp:
+                # the fit's programs are traced in this thread, where
+                # interpret mode is on
+                mp.setenv("H2O3_WARM_THREAD", "0")
+                mp.setenv("H2O3_HIST_METHOD", "pallas_factored")
+                if forced is not None:
+                    mp.setattr(shared_tree, "_cfg_operand_form",
+                               lambda cfg, _f=forced: dict(_f))
+                est = _train(make_est(), _FIT_X, _FIT_Y, _FIT_NAMES)
+            out.append((est, histogram.kernel_stats()["plans"][-1]))
+    return out
+
+
+def _gbm_kernel():
+    from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+
+    return H2OGradientBoostingEstimator(seed=42, ntrees=3, max_depth=3)
+
+
+def _drf_kernel():
+    from h2o3_tpu.models.drf import H2ORandomForestEstimator
+
+    return H2ORandomForestEstimator(seed=42, ntrees=3, max_depth=3)
+
+
+def _xgb_custom_kernel():
+    from h2o3_tpu.models.xgboost import H2OXGBoostEstimator
+
+    est = H2OXGBoostEstimator(seed=42, ntrees=3, max_depth=3)
+    # a custom objective's round: `single_tree_jit` from the caller's (g, h)
+    est._objective_fn = lambda m, y: (jax.nn.sigmoid(m) - y,
+                                      jnp.full_like(m, 0.25))
+    return est
+
+
+@pytest.mark.parametrize("make_est", [_gbm_kernel, _drf_kernel,
+                                      _xgb_custom_kernel])
+def test_fit_parity_code_operand_fit_vs_program(cloud1, _tree_env, make_est):
+    """Whole fits through `tree_jit` (GBM, DRF) and `single_tree_jit` (a
+    custom-objective XGBoost round): the fit plan says where the kernel's
+    operand was built, and the forest is the same either way."""
+    (a, plan_a), (b, plan_b) = _fit_both_operand_forms(make_est)
+    assert plan_a["code_operand"] == "fit"
+    # 6 features padded to the kernel's 8, the rows to the largest row
+    # chunk of the plan's levels (8,192 at 21 bins, 2,048 at 256)
+    chunk = max(lv["row_chunk"] for lv in plan_a["levels"])
+    assert plan_a["operand_bytes"] == 8 * (-(-_FIT_N // chunk) * chunk) * 4
+    assert plan_b["code_operand"] == "program"
+    assert plan_b["operand_bytes"] == 0
+    assert all(lv["method"] == "pallas_factored"
+               for p in (plan_a, plan_b) for lv in p["levels"])
+    _assert_models_bitexact(a, b)
+    counts = histogram.kernel_stats()["code_operand"]
+    assert counts.get("fit", 0) > 0 and counts.get("program", 0) > 0
+
+
+def test_cpu_fit_builds_no_code_operand(cloud1, _tree_env):
+    """A CPU fit runs the `segment` kernel: its plan says the programs
+    widen for themselves, and no operand is held."""
+    _fit_gbm(_FIT_X, _FIT_Y, _FIT_NAMES, ntrees=2, max_depth=_FIT_DEPTH)
+    plan = histogram.kernel_stats()["plans"][-1]
+    assert plan["code_operand"] == "program" and plan["operand_bytes"] == 0
+    from h2o3_tpu.runtime import metrics_registry
+
+    assert "h2o3_tree_code_operand_total" in metrics_registry.prometheus_text()
 
 
 # -- the warm-fit zero-retrace pin (ROADMAP item 2) -------------------------
