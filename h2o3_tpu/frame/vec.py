@@ -176,8 +176,8 @@ def _scan(v: "Vec") -> Rollup:
 
 def _count_rollup(result: str) -> None:
     """Count one `Vec.rollup()` request, "computed" or "reused": in the
-    registry, and on the open span if it keeps such a tally (`train.resolve`
-    opens with `rollups_computed` and `rollups_reused` at 0)."""
+    registry, and as a tally on the open span (`train.resolve` opens with
+    `rollups_computed` and `rollups_reused` at 0, so it shows both)."""
     global _ROLLUP_COUNTER
     from ..runtime import tracing
 
@@ -191,10 +191,7 @@ def _count_rollup(result: str) -> None:
             "the column, reused = read what the Vec remembered",
             labelnames=("result",))
     c.inc(1.0, result)
-    sp = tracing.current()
-    key = "rollups_" + result
-    if sp is not None and key in sp.attrs:
-        sp.attrs[key] += 1
+    tracing.tally("rollups_" + result)
 
 
 class Vec:

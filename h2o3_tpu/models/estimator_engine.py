@@ -537,7 +537,6 @@ def iter_phase():
     from ..runtime import phases as _phases
     from ..runtime import tracing as _tracing
 
-    _phases.install_listener()
     comp0 = _phases.totals(_phases.COMPILE_KEYS)
     with _tracing.span("fit.iterate", kind="fit") as sp:
         try:

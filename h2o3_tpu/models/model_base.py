@@ -956,8 +956,12 @@ class H2OEstimator:
             from ..client import remote_train
 
             return remote_train(self, x, y, training_frame, validation_frame)
-        from ..runtime import tracing
+        from ..runtime import phases, tracing
 
+        # every fit begins here, so this is where the compile pipeline's
+        # listener is installed (idempotent): whatever a fit traces,
+        # compiles or loads is counted, and lands on its span tree
+        phases.install_listener()
         # the fit's span tree (docs/observability.md): the same names for
         # every estimator, under the trace id of the REST request / job /
         # candidate that called, or one minted here

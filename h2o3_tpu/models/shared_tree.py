@@ -2057,8 +2057,16 @@ class H2OSharedTreeEstimator(H2OEstimator):
             codes_shape = ((npad * resident_bits // 8, F) if resident_bits
                            else (npad, F))
             drf = self._mode == "drf"
+            # the thread continues the fit's trace under ``fit.design``, so
+            # what it compiles or loads lands on the fit's span tree
+            design = _ph.fit_span
 
             def _warm():
+                with _tracing.attach(design.trace_id, design.span_id,
+                                     name="design.warm", kind="fit"):
+                    _warm_programs()
+
+            def _warm_programs():
                 try:
                     tj, _ = _tree_step_fns(cfg_early, cloud)
                     codes_dummy = jnp.zeros(codes_shape, code_dt)
@@ -3262,13 +3270,16 @@ class H2OSharedTreeEstimator(H2OEstimator):
                       and cfg.shard_mode not in ("mesh", "blocks"))
         if device_auc:
             # binomial GBM/XGB: the whole training-metric reduction runs on
-            # device (AUC2 binned design) — no margin D2H, no host rank sort
+            # device (AUC2 binned design) — no margin D2H, no host rank sort.
+            # The stage holds the dispatch and the reads that wait for it
+            _ph.stage("metrics.binned")
+            _ph.stage_span.annotate(device=True)
             qs_b, npos_b, nneg_b, nll_b, sq_b = _binom_binned_stats(
                 margins, y_d, jnp.int32(n))
-            model.training_metrics = ModelMetricsBinomial.from_binned(
-                np.asarray(qs_b), np.asarray(npos_b), np.asarray(nneg_b),
-                float(nll_b), float(sq_b))
+            binned = (np.asarray(qs_b), np.asarray(npos_b),
+                      np.asarray(nneg_b), float(nll_b), float(sq_b))
             _ph.mark("training_metrics")
+        _ph.stage("metrics.margins")
         if multiproc:
             # this process's real rows in INGEST order (training metrics
             # are local-shard on a multi-host cloud; the forest itself is
@@ -3284,6 +3295,9 @@ class H2OSharedTreeEstimator(H2OEstimator):
             # training metrics below do, with no second matrix and no
             # re-predict; the caller takes the attribute and clears it
             self._final_margins = margins_np
+        _ph.stage("metrics.make")
+        if device_auc:
+            model.training_metrics = ModelMetricsBinomial.from_binned(*binned)
         if self._mode == "drf" and row_sampled and n_prior > 0:
             # checkpoint continuation: the prior forest's per-tree sample
             # masks are gone, so OOB accounting cannot be reconstructed —
@@ -3321,6 +3335,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                                                   probs_tr)
         _ph.mark("training_metrics")
         if valid is not None:
+            _ph.stage("metrics.valid")
             if valid_state is not None and self._mode != "drf":
                 # multiproc: local-shard validation metrics, matching the
                 # local-shard training metrics above (forest is identical

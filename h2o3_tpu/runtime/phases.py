@@ -10,7 +10,11 @@ feeds bench.py's JSON).
 Two sources:
 - jax monitoring events (always cheap): `backend_compile_duration` →
   compile, `jaxpr_trace/`mlir_module` → trace, persistent-cache
-  retrievals → deserialize.
+  retrievals → deserialize. The same listener puts them on the span tree
+  (runtime/tracing.py): a compile request that ended is one span,
+  ``xla.compile`` or ``xla.cache_load``, under whatever span the requesting
+  thread holds open; trace and lowering seconds tally on that span as the
+  attr ``xla_trace_s``.
 - explicit instrumentation at the few fat host→device transfer points
   (`accounted_h2d`). device_put is async, so
   measuring real transfer time needs a one-element D2H barrier after the
@@ -26,8 +30,10 @@ import logging
 import os
 import threading
 import time
-from collections import OrderedDict, defaultdict
+from collections import OrderedDict, defaultdict, deque
 from contextlib import contextmanager
+
+from . import tracing
 
 _LOCK = threading.Lock()
 _SECS: dict = defaultdict(float)
@@ -47,7 +53,8 @@ _XLA_PER_SIG: "OrderedDict[str, dict]" = OrderedDict()
 _XLA_SIG_CAP = 512
 # the program whose compile request the current thread made last (see
 # _CompileTap): the compile / cache-retrieval duration events that follow
-# on the same thread belong to it
+# on the same thread belong to it. `loaded` is set between a request's
+# cache-retrieval event and its closing backend_compile event
 _PROG_TLS = threading.local()
 _COMPILER_LOGGER = "jax._src.compiler"
 _CACHE_MSGS = ("Persistent compilation cache hit for",
@@ -156,19 +163,56 @@ def _xla_count(kind: str, sig: "str | None", total: bool = True) -> None:
         reg[kind].inc()
     if retraced:
         reg["retraces"].inc()
-    # candidate/batch/request correlation lives on the span as an event
-    # annotation, NOT in the signature — span names must not leak into
-    # program identity
-    if sig is None:
-        return
-    try:
-        from . import tracing
+        # candidate/batch/request correlation lives on the span as an event
+        # annotation, NOT in the signature — span names must not leak into
+        # program identity
+        tracing.event("xla_retrace", sig=sig)
 
-        tracing.event(f"xla_{kind}", sig=sig)
-        if retraced:
-            tracing.event("xla_retrace", sig=sig)
-    except Exception:
-        pass
+
+def _tally_trace(duration: float) -> None:
+    """A trace or lowering event just ended on this thread: tally on the
+    open span, as ``xla_trace_s``, the seconds of it that no earlier event
+    has already reported. jax times a jit's trace with the traces of the
+    jits inside it, each of which ended, and reported itself, first; so the
+    events since this one began are its inner ones, and what is left of it
+    after theirs is its own. Summed, the tallies never exceed the thread's
+    wall-clock (the `trace` bucket, which adds whole durations, does)."""
+    seen = getattr(_PROG_TLS, "traces", None)
+    if seen is None:
+        # (start, duration) of recent events; bounded: an entry matters
+        # only until the trace that encloses it ends
+        seen = _PROG_TLS.traces = deque(maxlen=256)
+    start = time.time() - duration      # jax times them on this clock
+    inner = 0.0
+    while seen and seen[-1][0] >= start:
+        inner += seen.pop()[1]
+    seen.append((start, duration))
+    tracing.tally("xla_trace_s", max(duration - inner, 0.0))
+
+
+def _request_span(duration: float, fun_name) -> None:
+    """One compile request of this thread has ended: record it as a span
+    under the span the thread holds open (the first call of a program:
+    ``iterate.dispatch``, ``design.upload``, ``fit.metrics``, ...).
+
+    jax times the WHOLE request as `backend_compile_duration`, whether the
+    persistent cache answered it or the compiler did; a cache hit announces
+    itself just before, with a retrieval event on the same thread. So a
+    request is ``xla.cache_load`` (key, read, deserialize) when one came,
+    ``xla.compile`` (key, miss, compile, cache write) when none did — one
+    span a request, never both. `program` is the module's name and `sig`
+    its cache key as the `_CompileTap` read them for this request; with the
+    compilation cache off jax logs neither, and `program` is what the event
+    itself carries."""
+    name = getattr(_PROG_TLS, "name", None)
+    sig = getattr(_PROG_TLS, "sig", None)
+    loaded = getattr(_PROG_TLS, "loaded", False)
+    _PROG_TLS.name = _PROG_TLS.sig = None
+    _PROG_TLS.loaded = False
+    tracing.record_span("xla.cache_load" if loaded else "xla.compile",
+                        duration, kind="xla",
+                        program=name if name is not None else str(fun_name),
+                        sig=sig)
 
 
 def xla_counts() -> dict:
@@ -325,17 +369,23 @@ def install_listener() -> None:
         if "backend_compile" in event:
             add("compile", duration)
             _xla_count("compiles", _event_signature(fun_name))
+            _request_span(duration, fun_name)
         elif "jaxpr_trace" in event:
             # the total counts every trace jax ran; the per-program count
             # (and the retrace pin) is taken at the compile request, where
-            # the program's identity is known — see _CompileTap
+            # the program's identity is known — see _CompileTap. Every
+            # inner jit of a first fit traces: too many to be spans, so
+            # their seconds tally on the open span
             add("trace", duration)
             _xla_count("traces", None)
+            _tally_trace(duration)
         elif "mlir_module" in event:
             add("trace", duration)
+            _tally_trace(duration)
         elif "cache_retrieval" in event or "deserialize" in event:
             add("deserialize", duration)
             _xla_count("cache_retrievals", _event_signature(fun_name))
+            _PROG_TLS.loaded = True
 
     def _on_event(event: str, **kw) -> None:
         # persistent compilation-cache hit/miss counts (no duration)

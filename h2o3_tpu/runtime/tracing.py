@@ -22,8 +22,15 @@ Design:
   ring). An UNSAMPLED fraction is not implemented: span volume here is
   per-request/per-op, not per-row.
 - ops whose instrumentation already measures wall-clock (ingest/munge
-  stats modules) register retroactively via ``record_span`` instead of
-  wrapping their hot paths twice.
+  stats modules, the compile pipeline's listener in `runtime/phases.py`:
+  ``xla.compile`` / ``xla.cache_load``) register retroactively via
+  ``record_span`` instead of wrapping their hot paths twice; what is too
+  frequent to be a span each (Vec rollups, jax's trace and lowering events)
+  adds itself to an attr of the innermost open span with ``tally``.
+- the ring evicts its oldest span when full, and counts it: ``dropped()``
+  and ``h2o3_trace_spans_dropped_total``. A reader of the ring that finds
+  0 knows that no span of the tree it reads was evicted; an operator who
+  finds it rising knows ``H2O3_TRACE_SPANS`` is too small for the traffic.
 - ONE primitive, two sinks: `span()` also holds a
   ``jax.profiler.TraceAnnotation(name)`` open for its lifetime. With no
   profiler session that is a flag test; under one (`profiler.trace()`, the
@@ -35,7 +42,8 @@ Design:
   `fit.design`, `fit.iterate`, ...) is listed in docs/observability.md.
 
 Metric fold: ``h2o3_trace_spans_total{kind}`` counts completed spans per
-kind in the central registry, so span volume itself is scrapable.
+kind in the central registry, so span volume itself is scrapable;
+``h2o3_trace_spans_dropped_total`` counts the spans the ring evicted.
 """
 
 from __future__ import annotations
@@ -53,14 +61,15 @@ from jax.profiler import TraceAnnotation
 from . import env_int
 
 __all__ = ["Span", "span", "attach", "current", "current_trace_id",
-           "new_trace_id", "event", "record_span", "export_chrome",
-           "summaries", "clear", "span_count"]
+           "new_trace_id", "event", "tally", "record_span", "export_chrome",
+           "summaries", "clear", "span_count", "dropped"]
 
 _MAX_SPANS = env_int("H2O3_TRACE_SPANS", 4096)
 _MAX_EVENTS_PER_SPAN = 64
 
 _LOCK = threading.Lock()
 _SPANS: deque = deque(maxlen=_MAX_SPANS)
+_DROPPED = 0          # spans the full ring evicted since the last clear()
 _TLS = threading.local()
 
 
@@ -133,24 +142,31 @@ def current_trace_id() -> Optional[str]:
     return sp.trace_id if sp is not None else None
 
 
-_SPAN_COUNTER = None
+_COUNTERS = None
 
 
 def _record(sp: Span) -> None:
-    global _SPAN_COUNTER
+    global _COUNTERS, _DROPPED
     with _LOCK:
+        evicts = len(_SPANS) == _SPANS.maxlen
+        if evicts:
+            _DROPPED += 1
         _SPANS.append(sp)
-    # registry fold; the family is memoized so ending a span never takes
+    # registry fold; the families are memoized so ending a span never takes
     # the registry's registration lock (deferred first resolve: tracing
     # must stay importable before metrics_registry)
-    c = _SPAN_COUNTER
+    c = _COUNTERS
     if c is None:
         from . import metrics_registry as _reg
 
-        c = _SPAN_COUNTER = _reg.counter(
-            "h2o3_trace_spans", "completed trace spans",
-            labelnames=("kind",))
-    c.inc(1, sp.kind)
+        c = _COUNTERS = (
+            _reg.counter("h2o3_trace_spans", "completed trace spans",
+                         labelnames=("kind",)),
+            _reg.counter("h2o3_trace_spans_dropped",
+                         "spans the full ring (H2O3_TRACE_SPANS) evicted"))
+    c[0].inc(1, sp.kind)
+    if evicts:
+        c[1].inc()
 
 
 @contextmanager
@@ -205,6 +221,16 @@ def event(name: str, **attrs) -> None:
         sp.add_event(name, **attrs)
 
 
+def tally(name: str, value=1) -> None:
+    """Add `value` to attr `name` of the current span (created at 0): for
+    what happens too often inside a span to be a span or an event each —
+    a Vec's rollup reads, the seconds jax spends tracing and lowering.
+    Does nothing when no span is open."""
+    sp = current()
+    if sp is not None:
+        sp.attrs[name] = sp.attrs.get(name, 0) + value
+
+
 def record_span(name: str, duration_s: float, kind: str = "span",
                 trace_id: Optional[str] = None,
                 parent_id: Optional[str] = None,
@@ -238,6 +264,13 @@ def _snapshot_spans() -> List[Span]:
 def span_count() -> int:
     with _LOCK:
         return len(_SPANS)
+
+
+def dropped() -> int:
+    """Spans the full ring has evicted since the last `clear()`: 0 says
+    every span recorded since then is still in `spans()`."""
+    with _LOCK:
+        return _DROPPED
 
 
 def spans(trace_id: Optional[str] = None, n: Optional[int] = None
@@ -298,5 +331,7 @@ def export_chrome(trace_id: Optional[str] = None) -> Dict:
 def clear() -> None:
     """Drop recorded spans (tests). Open spans on live threads are
     unaffected — they record on exit as usual."""
+    global _DROPPED
     with _LOCK:
         _SPANS.clear()
+        _DROPPED = 0

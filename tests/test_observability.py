@@ -388,7 +388,13 @@ def _fit_estimator(algo):
 
 
 def _children(spans, parent):
-    return sorted((s for s in spans if s["parent_id"] == parent["span_id"]),
+    """The fit's own spans under `parent`, on its thread: a first call's
+    `xla.compile` / `xla.cache_load` (kind "xla") hang under whichever of
+    them was open, and the tree fit's warm-up thread (`design.warm`) runs
+    beside the stages, not among them."""
+    return sorted((s for s in spans if s["parent_id"] == parent["span_id"]
+                   and s["kind"] == parent["kind"]
+                   and s.get("thread") == parent.get("thread")),
                   key=lambda s: s["ts"])
 
 
@@ -444,7 +450,9 @@ def test_fit_leaves_one_span_tree(cloud1, algo):
                                  "design.vectors", "design.codes",
                                  "design.state"],
                   "fit.iterate": ["iterate.setup", "iterate.dispatch",
-                                  "iterate.forest", "iterate.model"]}
+                                  "iterate.forest", "iterate.model"],
+                  "fit.metrics": ["metrics.binned", "metrics.margins",
+                                  "metrics.make"]}
         root = by_name["train"]
         for parent, names in stages.items():
             kids = _children(spans, by_name[parent])
@@ -461,7 +469,7 @@ def test_fit_leaves_one_span_tree(cloud1, algo):
             assert codes[1]["attrs"]["bytes_h2d"] > 0
         assert by_name["fit.iterate"]["attrs"]["n_devices"] == 1
     # no span per iteration, level or tree: a fit is a handful of spans
-    assert len([s for s in spans if s["kind"] == "fit"]) <= 20
+    assert len([s for s in spans if s["kind"] == "fit"]) <= 24
 
 
 def test_fit_spans_land_on_the_profilers_host_plane(cloud1, tmp_path):
