@@ -128,7 +128,9 @@ def phase_fit(n_rows: int, ntrees: int, max_depth: int, expect_method: str,
         auc=round(auc, 5), train_s=round(fit_s, 1),
         kernel=expect_method, pack_bits=plan["pack_bits"],
         row_chunks=sorted({lv["row_chunk"] for lv in plan["levels"]},
-                          key=str))
+                          key=str),
+        bins_padded=sorted({lv["bins_padded"] for lv in plan["levels"]},
+                           key=str))
 
     # the kernel against the exact f32 scatter: the kernel's bf16 one-hot
     # weights are the only licensed difference

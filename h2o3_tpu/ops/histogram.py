@@ -21,12 +21,17 @@ VMEM fallback, ``segment`` on a CPU, ``onehot`` on other accelerators):
   scatter). The kernel of very large L·B (the VMEM fallback) and of
   CPU fits.
 * ``pallas_factored``: the fused VMEM kernel in
-  `hist_pallas.py`. Its code operand is the feature-major float32 (F, N)
-  array `feature_major` names. Where a fit's plan runs this kernel on one
-  device, ONE program a fit (`build_code_operand`) widens the resident
-  codes into it, already padded to the kernel's blocks, and every tree
-  program takes it as an argument (`code_operand_form` is the rule, the
-  fit plan's `code_operand` says which form a fit ran). Elsewhere (the
+  `hist_pallas.py`. It builds its bin one-hot a feature at a time on a bin
+  axis padded to the sublane tile (`hist_pallas.bins_padded`: 21 → 24, so
+  no tile of the one-hot straddles two features; the padded bins are exact
+  zeros and are sliced off before the result leaves the op — the fit plan's
+  `bins_padded` says which width ran). Its code operand is the
+  feature-major float32 (F, N) array `feature_major` names. Where a fit's
+  plan runs this kernel on one device, ONE program a fit
+  (`build_code_operand`) widens the resident codes into it, already padded
+  to the kernel's blocks, and every tree program takes it as an argument
+  (`code_operand_form` is the rule, the fit plan's `code_operand` says
+  which form a fit ran). Elsewhere (the
   blocked and mesh lanes, `run_block_kernel`) the program widens packed
   input in-graph, once per program execution. Either way the RESIDENT
   matrix — what the dataset cache holds across fits and what the H2D
@@ -54,7 +59,8 @@ Kernel-selection observability (ISSUE 7): every dispatch records the chosen
 method (and the VMEM-pressure pallas→segment fallbacks) into the central
 metrics registry, and the tree driver records a per-fit level plan via
 ``record_fit_plan`` — surfaced at ``GET /3/Profiler`` under ``tree`` so
-"which kernel actually ran, at which row_chunk" is never guesswork.
+"which kernel actually ran, at which row_chunk and padded bin width" is
+never guesswork.
 """
 
 from __future__ import annotations
@@ -77,22 +83,25 @@ METHODS = ("auto", "onehot", "segment", "pallas_factored")
 def _factored_row_chunk(n_nodes: int, nbins: int) -> int:
     """Largest row chunk whose co-resident VMEM buffers fit the kernel's
     stated `hist_pallas.VMEM_LIMIT_BYTES` (16 MiB): the (3L,R) f32 scratch
-    and (8B,R) bf16 bin one-hot each ≤ half of it AND scratch + one-hot +
-    the revisited (3L,8B) f32 output block within it together. Held to the
-    v5e compiler ahead of time (1M×28, B∈{21,64,256,1024}, every L up to
-    the fallback): each chunk this picks compiles under the stated limit,
-    and where the scratch term binds (L ≥ 128 at B ≤ 64) the next chunk up
-    is refused ("ran out of memory in memory space vmem") — the scratch
-    bound is the chip's; the one-hot bound is conservative (the next chunk
-    up still compiles at B ≥ 256). Returns <512 when no chunk fits (caller
-    falls back to the XLA segment path — recorded, see `resolve_method`)."""
-    from .hist_pallas import VMEM_LIMIT_BYTES as limit
+    and (8·Bp,R) bf16 bin one-hot each ≤ half of it AND scratch + one-hot +
+    the revisited (3L,8·Bp) f32 output block within it together, `Bp` the
+    bin axis as the kernel pads it (`hist_pallas.bins_padded`). Held to the
+    v5e compiler ahead of time (1M×28, B∈{16,21,33,64,256,1024}, every L
+    up to the fallback): each chunk this picks compiles under the stated
+    limit, and where the scratch term binds (L ≥ 128 at B ≤ 64) the next
+    chunk up is refused ("ran out of memory in memory space vmem") — the
+    scratch bound is the chip's; the one-hot bound is conservative (the next
+    chunk up still compiles at B ≥ 256). Returns <512 when no chunk fits
+    (caller falls back to the XLA segment path — recorded, see
+    `resolve_method`)."""
+    from .hist_pallas import VMEM_LIMIT_BYTES as limit, bins_padded
 
-    out_bytes = 3 * n_nodes * 8 * nbins * 4
+    bp = bins_padded(nbins)
+    out_bytes = 3 * n_nodes * 8 * bp * 4
     rc = 8192
     while rc >= 512:
         scratch = 3 * n_nodes * rc * 4
-        onehot = 8 * nbins * rc * 2
+        onehot = 8 * bp * rc * 2
         if scratch <= limit // 2 and onehot <= limit // 2 \
                 and scratch + onehot + out_bytes <= limit:
             break
@@ -244,7 +253,10 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     will run. Logs ONE warning per fit when any level hits the VMEM
     pressure fallback (the previously-silent `_factored_row_chunk` < 512
     path), counts every level's selection in the registry, and keeps the
-    plan in a bounded ring surfaced at /3/Profiler. `partition_read` is
+    plan in a bounded ring surfaced at /3/Profiler. Beside a level's
+    `row_chunk` stands `bins_padded`, the bin axis the Pallas kernel ran it
+    on (`hist_pallas.bins_padded(nbins)`; None where another kernel ran the
+    level). `partition_read` is
     how the fit's levels read a row's split-feature code
     (`tree.partition_read`; None for a fit without a level partition).
     `code_operand` is where the histogram kernel's code operand is built
@@ -264,7 +276,13 @@ def record_fit_plan(tag: str, levels, nbins: int, hist_method: str,
     for label, n_nodes in levels:
         sel = resolve_method(n_nodes, nbins, hist_method, platform=platform)
         _record_selection(sel, vmem=True)
-        plan_levels.append(dict(level=label, n_nodes=int(n_nodes), **sel))
+        bp = None
+        if sel["method"] == "pallas_factored":
+            from .hist_pallas import bins_padded
+
+            bp = bins_padded(nbins)
+        plan_levels.append(dict(level=label, n_nodes=int(n_nodes), **sel,
+                                bins_padded=bp))
         if sel["fallback"] == "vmem":
             fellback.append((label, int(n_nodes)))
     plan = dict(tag=tag, ts=_time.time(), nbins=int(nbins),

@@ -107,8 +107,9 @@ def fault_stats() -> Dict:
 def tree_stats() -> Dict:
     """Tree-kernel observability folded into the profiler surface
     (ISSUE 7 satellite): the per-fit histogram kernel plans recorded by
-    `ops.histogram.record_fit_plan` (method, pallas row_chunk, pack bits,
-    VMEM-pressure fallbacks per level) plus the cumulative dispatch
+    `ops.histogram.record_fit_plan` (method, pallas row_chunk and padded
+    bin width, pack bits, VMEM-pressure fallbacks per level) plus the
+    cumulative dispatch
     counters — `build_histograms`' auto-dispatch made visible. Pure
     counter read — never builds a histogram."""
     from ..ops import histogram

@@ -6,7 +6,17 @@ guards is what interpret mode cannot see (tiling, VMEM, HBM).
 
 The topology is described inside a module-scoped fixture (only one process
 may hold the TPU library; never at import, in a skipif or in parametrize),
-and all of these live in ONE file so one worker owns them."""
+and all of these live in ONE file so one worker owns them. The same fixture
+asks the library, before it loads, to dump what Mosaic makes of a kernel
+(`--xla_mosaic_dump_to`): `test_onehot_step_has_no_strided_store` counts the
+instructions of one grid step in it."""
+
+import collections
+import functools
+import glob
+import os
+import re
+import shutil
 
 import numpy as np
 import pytest
@@ -22,14 +32,33 @@ NBINS = 21          # the flagship's resolved nbins (GBM nbins=20, +1 NA bin)
 
 
 @pytest.fixture(scope="module")
-def topo():
+def mosaic_dump(tmp_path_factory):
+    """The directory the TPU library writes every Mosaic kernel's passes to
+    (15 files, ~10 MB a compile), gone with the module."""
+    path = tmp_path_factory.mktemp("mosaic")
+    yield str(path)
+    shutil.rmtree(path, ignore_errors=True)
+
+
+@pytest.fixture(scope="module")
+def topo(mosaic_dump):
     from jax.experimental import topologies
 
+    # read once, when the library loads: nothing of this file has loaded it
+    # before this call, and no other file does
+    was = os.environ.get("LIBTPU_INIT_ARGS")
+    os.environ["LIBTPU_INIT_ARGS"] = (
+        f"{was or ''} --xla_mosaic_dump_to={mosaic_dump}").strip()
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        if was is None:
+            del os.environ["LIBTPU_INIT_ARGS"]
+        else:
+            os.environ["LIBTPU_INIT_ARGS"] = was
 
 
 @pytest.fixture(scope="module")
@@ -51,15 +80,73 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.fixture(autouse=True)
+def _drop_dumps(request):
+    """Keep the dump directory to one test's compiles (the tree program's
+    are ~100 MB). Touches no topology: a test that describes none has no
+    directory either."""
+    yield
+    if "mosaic_dump" in request.fixturenames:
+        for path in glob.glob(request.getfixturevalue("mosaic_dump") + "/*"):
+            os.remove(path)
+
+
+def _kernel_args(sharding):
+    return (_sds((F, N), jnp.float32, sharding),
+            _sds((N,), jnp.int32, sharding),
+            _sds((3, N), jnp.float32, sharding))
+
+
+# 4- and 6-bit codes (16, 33), the flagship (21), XGBoost's 64 and 256, and
+# `nbins_cats`' 1,024: the bin axis as the kernel pads it is 16, 24, 40, 64,
+# 256, 1,024
+@pytest.mark.parametrize("nbins", [16, NBINS, 33, 64, 256, 1024])
 @pytest.mark.parametrize("n_nodes", [1, 32, 64])
-def test_factored_kernel_compiles_for_v5e(one_chip, n_nodes):
-    rc = histogram.resolve_method(n_nodes, NBINS, "pallas_factored")
+def test_factored_kernel_compiles_for_v5e(one_chip, n_nodes, nbins):
+    rc = histogram.resolve_method(n_nodes, nbins, "pallas_factored")
     assert rc["fallback"] is None and rc["row_chunk"] >= 512
     compiled = hist_pallas.build_histograms_pallas_factored.lower(
-        _sds((F, N), jnp.float32, one_chip), _sds((N,), jnp.int32, one_chip),
-        _sds((3, N), jnp.float32, one_chip),
-        n_nodes=n_nodes, nbins=NBINS, row_chunk=rc["row_chunk"]).compile()
+        *_kernel_args(one_chip),
+        n_nodes=n_nodes, nbins=nbins, row_chunk=rc["row_chunk"]).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert hist_pallas.bins_padded(nbins) % 8 == 0
+    assert 0 <= hist_pallas.bins_padded(nbins) - nbins < 8
+
+
+def test_onehot_step_has_no_strided_store(one_chip, mosaic_dump):
+    """What Mosaic makes of ONE grid step at the flagship's shape (B 21,
+    L 16, R 8,192), read from its final LLO: the (8·Bp, R) one-hot costs one
+    compare and one select a vreg and half a pack, and nothing else. With
+    the bin axis unpadded (`repeat(codes, 21) == iota % 21`, through PR 35)
+    the same step held 1,344 sublane-strided stores, 1,008 sublane rotates,
+    1,844 loads, 5,787 selects and 27 remainders; an `astype` of the compare
+    instead of the `where` costs a convert a vreg more."""
+    L, R = 16, 8192
+    Bp = hist_pallas.bins_padded(NBINS)
+    assert Bp == 24
+    hist_pallas.build_histograms_pallas_factored.lower(
+        *_kernel_args(one_chip), n_nodes=L, nbins=NBINS,
+        row_chunk=R).compile()
+    dumps = glob.glob(f"{mosaic_dump}/*tree_hist_factored*post-finalize-llo*")
+    if not dumps:
+        pytest.skip("this TPU library wrote no Mosaic dump")
+    assert len(dumps) == 1
+    with open(dumps[0]) as fh:
+        ops = collections.Counter(re.findall(r"\bllo\.([\w.]+)", fh.read()))
+    vregs = 8 * Bp * R // 1024                    # float32 one-hot vregs
+    assert ops["vcmp.eq.f32"] == vregs == 1536
+    assert ops["vector_store_slane_stride"] == 0
+    assert ops["vrot.slane"] == 0
+    # the only remainder left is the weighted scratch's `% L`, a sublane
+    # tile of its (3L, 1) column each, under `fb == 0`
+    assert ops["vrem.s32"] <= -(-3 * L // 8)
+    # one select a one-hot vreg, and the scratch's node mask (3L·R/1,024)
+    assert ops["vselect"] <= vregs + 3 * L * R // 1024 + 16
+    assert ops["vcvt.s32.f32"] < vregs // 2
+    assert ops["vunpack"] == 0
+    # the cast packs whole tiles: two float32 vregs into one bfloat16
+    assert ops["vpack"] == (vregs + 3 * L * R // 1024) // 2
+    assert ops["vector_load"] < 600
 
 
 def test_factored_row_chunk_is_this_chips_limit(one_chip):
@@ -69,9 +156,7 @@ def test_factored_row_chunk_is_this_chips_limit(one_chip):
     L = 128
     rc = histogram._factored_row_chunk(L, NBINS)
     assert 512 <= rc < 8192
-    args = (_sds((F, N), jnp.float32, one_chip),
-            _sds((N,), jnp.int32, one_chip),
-            _sds((3, N), jnp.float32, one_chip))
+    args = _kernel_args(one_chip)
     hist_pallas.build_histograms_pallas_factored.lower(
         *args, n_nodes=L, nbins=NBINS, row_chunk=rc).compile()
     with pytest.raises(Exception, match="(?i)vmem"):
@@ -131,8 +216,6 @@ def test_tree_step_compiles_for_v5e(one_chip):
     that widened for itself (PR 33, and still `code_operand="program"`)
     holds 674,733,568 at this size, 512 MB of it the row-major float32
     (N, 28→128) intermediate."""
-    import re
-
     from h2o3_tpu.models import shared_tree
     from h2o3_tpu.parallel import mesh as cloudlib
 
@@ -218,3 +301,97 @@ def test_pallas_kernels_match_onehot_in_interpret_mode(method):
     cnt = np.zeros((n_nodes, NBINS))
     np.add.at(cnt, (node, codes[:, 0]), w)
     np.testing.assert_allclose(np.asarray(got)[:, 0, :, 0], cnt, atol=1e-4)
+
+
+def _emulated(codes, node, vals, n_nodes, nbins, row_chunk):
+    """The kernel's arithmetic in plain jnp: the node-weighted values cast
+    to bfloat16, an exact 0/1 bin one-hot, one float32-accumulating matmul a
+    feature block and a row chunk, the chunks added in row order. At the
+    kernel's own matmul shapes (rows padded to the chunk, features to eight,
+    bins to `bins_padded`), because the CPU's dot sums in an order that
+    depends on them (one ulp in one of 528 sums otherwise)."""
+    n, f = codes.shape
+    bp = hist_pallas.bins_padded(nbins)
+    fpad, npad = -(-f // 8) * 8, -(-n // row_chunk) * row_chunk
+    codes_t = jnp.pad(codes.T.astype(jnp.float32),
+                      ((0, fpad - f), (0, npad - n)), constant_values=-1.0)
+    node = jnp.pad(node, (0, npad - n))
+    vals = jnp.pad(vals, ((0, 0), (0, npad - n)))
+    l_of = np.arange(3 * n_nodes) % n_nodes
+    c_of = np.arange(3 * n_nodes) // n_nodes
+    w = (vals[c_of] * (node[None, :] == l_of[:, None])).astype(jnp.bfloat16)
+    bins = jnp.arange(bp, dtype=jnp.float32)
+    oh = (codes_t[:, None, :] == bins[None, :, None]).astype(
+        jnp.bfloat16).reshape(fpad // 8, 8 * bp, npad)
+    acc = jnp.zeros((fpad // 8, 3 * n_nodes, 8 * bp), jnp.float32)
+    for r0 in range(0, npad, row_chunk):
+        rows = slice(r0, r0 + row_chunk)
+        acc = acc + jnp.stack([
+            jax.lax.dot_general(w[:, rows], blk[:, rows],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for blk in oh])
+    acc = acc.reshape(fpad // 8, 3 * n_nodes, 8, bp)[..., :nbins]
+    acc = acc.transpose(0, 2, 1, 3).reshape(fpad, 3 * n_nodes, nbins)[:f]
+    return acc.reshape(f, 3, n_nodes, nbins).transpose(2, 0, 3, 1)
+
+
+# "features": two feature blocks, the second with five pad features, ONE row
+# chunk most of which is pad rows, through `build_histograms` at the chunk
+# `resolve_method` picks. "chunks": three row chunks, the last part pad
+# rows, of one feature block with three pad features, the kernel called at a
+# small chunk. (Both at once is the chip's: the interpreter refuses an
+# output block that is revisited after another was written.)
+@pytest.mark.parametrize("layout,n,f", [("features", 1000, 11),
+                                        ("chunks", 1300, 5)])
+@pytest.mark.parametrize("n_nodes", [1, 4])
+@pytest.mark.parametrize("nbins", [16, NBINS, 33, 256])
+def test_factored_kernel_numerics_in_interpret_mode(nbins, n_nodes, layout,
+                                                    n, f):
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(codes, node, g, h, w, nb=nbins):
+        if layout == "features":
+            return histogram.build_histograms(
+                codes, node, g, h, w, n_nodes, nb, method="pallas_factored")
+        return hist_pallas.build_histograms_pallas_factored(
+            histogram.feature_major(codes), node, jnp.stack([w, g * w, h * w]),
+            n_nodes, nb, row_chunk=512)
+
+    row_chunk = 512 if layout == "chunks" else histogram.resolve_method(
+        n_nodes, nbins, "pallas_factored")["row_chunk"]
+    assert (n > row_chunk) == (layout == "chunks") and n % row_chunk
+    codes, node, g, h, w = (jnp.asarray(a) for a in _hist_inputs(
+        n=n, f=f, nbins=nbins, n_nodes=n_nodes, seed=nbins + n_nodes))
+    ref = histogram.build_histograms(codes, node, g, h, w, n_nodes, nbins,
+                                     method="onehot")
+    # values that bfloat16 rounds: the kernel's own order of sums shows
+    rng = np.random.default_rng(nbins * 7 + n_nodes)
+    g2 = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    h2 = jnp.asarray(rng.random(n).astype(np.float32))
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.jit(kernel)(codes, node, g, h, w)
+        got2 = jax.jit(kernel)(codes, node, g2, h2, w)
+        # the same codes on a wider bin axis: bins no code reaches
+        wide = jax.jit(functools.partial(kernel, nb=nbins + 3))(
+            codes, node, g2, h2, w)
+    # no padded bin, feature or row comes back, and none has added
+    # anything: these values' sums are exact in float32
+    assert got.shape == ref.shape == (n_nodes, f, nbins, 3)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    cnt = np.zeros((n_nodes, nbins))
+    np.add.at(cnt, (np.asarray(node), np.asarray(codes)[:, 0]), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got)[:, 0, :, 0], cnt)
+    # bit for bit the stated arithmetic, not only close to it
+    emu = _emulated(codes, node, jnp.stack([w, g2 * w, h2 * w]), n_nodes,
+                    nbins, row_chunk)
+    np.testing.assert_array_equal(np.asarray(got2), np.asarray(emu))
+    assert not np.array_equal(
+        np.asarray(got2),
+        np.asarray(histogram.build_histograms(codes, node, g2, h2, w, n_nodes,
+                                              nbins, method="segment")))
+    # a bin that matches no code is an exact zero column and moves no other
+    assert wide.shape == (n_nodes, f, nbins + 3, 3)
+    assert not np.asarray(wide)[:, :, nbins:].any()
+    np.testing.assert_array_equal(np.asarray(wide)[:, :, :nbins],
+                                  np.asarray(got2))

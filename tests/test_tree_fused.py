@@ -485,6 +485,11 @@ def test_fit_parity_code_operand_fit_vs_program(cloud1, _tree_env, make_est):
     assert plan_b["operand_bytes"] == 0
     assert all(lv["method"] == "pallas_factored"
                for p in (plan_a, plan_b) for lv in p["levels"])
+    # and on which bin axis the kernel ran them: GBM's and DRF's 20 bins
+    # and the NA bin on 24, XGBoost's 256 as they are
+    padded = {21: 24, 256: 256}[plan_a["nbins"]]
+    assert all(lv["bins_padded"] == padded
+               for p in (plan_a, plan_b) for lv in p["levels"])
     _assert_models_bitexact(a, b)
     counts = histogram.kernel_stats()["code_operand"]
     assert counts.get("fit", 0) > 0 and counts.get("program", 0) > 0
@@ -595,6 +600,36 @@ def test_vmem_fallback_counted_and_logged(_tree_env):
     after = metrics_registry.get("h2o3_tree_hist_vmem_fallbacks").total()
     assert after == before + 1
     assert [lv["fallback"] for lv in plan["levels"]] == [None, "vmem"]
+
+
+@pytest.mark.parametrize("nbins,padded,chunk", [
+    (21, 24, 8192),        # the HIGGS cell: nbins 20 and the NA bin
+    (256, 256, 2048),      # the MSLR cell: nothing to pad
+    (16, 16, 8192), (33, 40, 8192), (64, 64, 8192), (1024, 1024, 512)])
+def test_fit_plan_records_bins_padded(_tree_env, nbins, padded, chunk):
+    """The plan of a depth-6 fit as a TPU resolves it says, beside each
+    level's `row_chunk`, the bin axis the Pallas kernel pads to
+    (`hist_pallas.bins_padded`, a function of `nbins` alone); a level
+    another kernel runs says None. It is what /3/Profiler's `tree` serves."""
+    from h2o3_tpu.ops import hist_pallas
+
+    assert hist_pallas.bins_padded(nbins) == padded
+    levels = treelib.histogram_level_plan(6)
+    plan = histogram.record_fit_plan(f"test:bins{nbins}", levels, nbins,
+                                     "auto", platform="tpu")
+    assert len(plan["levels"]) == len(levels) > 0
+    for lv in plan["levels"]:
+        assert lv["method"] == "pallas_factored"
+        assert lv["bins_padded"] == padded
+    assert max(lv["row_chunk"] for lv in plan["levels"]) == chunk
+    assert histogram.kernel_stats()["plans"][-1] is plan
+    deep = histogram.record_fit_plan(
+        f"test:bins{nbins}:deep", [("d0", 1), ("d16", 1 << 16)], nbins,
+        "pallas_factored", platform="tpu")
+    assert [lv["bins_padded"] for lv in deep["levels"]] == [padded, None]
+    cpu = histogram.record_fit_plan(f"test:bins{nbins}:cpu", levels, nbins,
+                                    "auto", platform="cpu")
+    assert {lv["bins_padded"] for lv in cpu["levels"]} == {None}
 
 
 def test_dataset_cache_keys_pack_mode(cloud1, _tree_env):
