@@ -144,6 +144,47 @@ from .model_base import (SCORE_ROW_BUCKET, DataInfo, H2OEstimator,
 
 _predict_codes_jit = jax.jit(treelib.predict_codes, static_argnames=("max_depth",))
 
+# rows a step of `_rows_above_edges`: one step is an (edges, 8,192) compare
+# reduced along the rows, which XLA keeps in registers; under 2^16, which the
+# packed counts need
+_EDGE_COUNT_ROWS = 8192
+
+
+def _edge_count_block(rows: int) -> int:
+    """The row block of `_rows_above_edges` over `rows` padded rows: a
+    tiny fit runs one block."""
+    return min(_EDGE_COUNT_ROWS, rows)
+
+
+def _rows_above_edges(qs, p, valid, pos):
+    """For every edge `qs[j]`, the valid rows and the positive rows whose
+    score `p` lies above it, in `searchsorted`'s order (a NaN score lies
+    above every edge, nothing lies above a NaN edge), as two int32 arrays.
+    A scan over row blocks with the edge axis major: no per-row bin index,
+    no gather over the rows, no scatter, and no `(edges, rows)` array in
+    memory. Both counts ride in one int32 a row, valid in the low 16 bits
+    and positive in the high, so a block is one compare-and-sum; a block's
+    sums stay under 2^16 in each half."""
+    n = p.shape[0]
+    blk = _edge_count_block(n)
+    nblk = -(-n // blk)
+    w = valid.astype(jnp.int32) | (pos.astype(jnp.int32) << 16)
+
+    def blocks(a):
+        return jnp.pad(a, (0, nblk * blk - n)).reshape(nblk, blk)
+
+    def step(acc, xs):
+        pb, wb = xs
+        s = jnp.sum(jnp.where(~(pb[None, :] <= qs[:, None]), wb[None, :], 0),
+                    axis=1)
+        return (acc[0] + (s & 0xFFFF), acc[1] + (s >> 16)), None
+
+    zero = jnp.zeros(qs.shape, jnp.int32)
+    (n_all, n_pos), _ = jax.lax.scan(step, (zero, zero),
+                                     (blocks(p), blocks(w)))
+    nan_edge = jnp.isnan(qs)
+    return jnp.where(nan_edge, 0, n_all), jnp.where(nan_edge, 0, n_pos)
+
 
 @functools.partial(jax.jit, static_argnames=("nbins",))
 def _binom_binned_stats(margins, y_d, n, nbins: int = 400):
@@ -151,6 +192,10 @@ def _binom_binned_stats(margins, y_d, n, nbins: int = 400):
     quantile edges, per-bin (pos, neg) counts and the logloss/mse sums are
     the only things that cross the wire (~KBs instead of the 4·n-byte
     margin pull + a host rank sort).
+
+    Bin `b` holds the rows with `qs[b-1] < p <= qs[b]` (`searchsorted`,
+    side left): the rows above edge `b-1` less those above edge `b`, the
+    valid total above no edge and nothing above edge `nbins`.
 
     `n` is TRACED (pad rows masked out), so CV folds padded to the parent
     frame's row shape reuse ONE compiled program instead of recompiling
@@ -160,11 +205,18 @@ def _binom_binned_stats(margins, y_d, n, nbins: int = 400):
     y = y_d[:, 0]
     qs = jnp.nanquantile(jnp.where(valid, p, jnp.nan),
                          jnp.linspace(0.0, 1.0, nbins))
-    bins = jnp.searchsorted(qs, p, side="left")
-    vf = valid.astype(jnp.float32)
-    npos = jax.ops.segment_sum(y * vf, bins, num_segments=nbins + 1)
-    nneg = jax.ops.segment_sum((1.0 - y) * vf, bins,
-                               num_segments=nbins + 1)
+    pos = valid & (y > 0.5)
+    zero = jnp.zeros((1,), jnp.int32)
+
+    def per_bin(total, above):
+        return (jnp.concatenate([total[None], above])
+                - jnp.concatenate([above, zero]))
+
+    a_all, a_pos = _rows_above_edges(qs, p, valid, pos)
+    c_all = per_bin(jnp.sum(valid, dtype=jnp.int32), a_all)
+    c_pos = per_bin(jnp.sum(pos, dtype=jnp.int32), a_pos)
+    npos = c_pos.astype(jnp.float32)
+    nneg = (c_all - c_pos).astype(jnp.float32)
     pc = jnp.clip(p, 1e-15, 1 - 1e-15)
     nll = -jnp.sum(jnp.where(valid & (y > 0.5), jnp.log(pc), 0.0)
                    + jnp.where(valid & (y <= 0.5), jnp.log(1.0 - pc), 0.0))
@@ -3262,18 +3314,21 @@ class H2OSharedTreeEstimator(H2OEstimator):
         # training metrics straight from the final margins (already on device)
         # instead of a fresh forest re-predict — saves transfers + a compile
         _ph.mark("forest_unpack", then="fit.metrics")
-        # sharded fits take the host metrics path: the binned-AUC reduction
-        # is a whole-array scatter whose sharded lowering is not bit-stable
-        # across device counts, and the margins D2H is local on a CPU mesh
+        # sharded fits take the host metrics path: the binned reduction's
+        # quantile sort and float32 sums are not bit-stable across device
+        # counts, and the margins D2H is local on a CPU mesh
         device_auc = (not multiproc and problem == "binomial"
                       and dist == "bernoulli" and self._mode == "gbm"
                       and cfg.shard_mode not in ("mesh", "blocks"))
         if device_auc:
             # binomial GBM/XGB: the whole training-metric reduction runs on
             # device (AUC2 binned design) — no margin D2H, no host rank sort.
-            # The stage holds the dispatch and the reads that wait for it
+            # The stage holds the dispatch and the reads that wait for it;
+            # its attrs say which form of the reduction ran
             _ph.stage("metrics.binned")
-            _ph.stage_span.annotate(device=True)
+            _ph.stage_span.annotate(
+                device=True, counts="edges",
+                block_rows=_edge_count_block(margins.shape[0]))
             qs_b, npos_b, nneg_b, nll_b, sq_b = _binom_binned_stats(
                 margins, y_d, jnp.int32(n))
             binned = (np.asarray(qs_b), np.asarray(npos_b),

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from h2o3_tpu.frame.frame import Frame
+from h2o3_tpu.models import shared_tree
 from h2o3_tpu.runtime import metrics_registry as registry
 from h2o3_tpu.runtime import phases, tracing
 
@@ -154,7 +155,10 @@ def test_binomial_tree_fit_metrics_is_tiled_by_stages(fresh, fit):
                    and s["kind"] == "fit"), key=lambda s: s["ts"])
     assert [s["name"] for s in kids] == ["metrics.binned", "metrics.margins",
                                          "metrics.make"]
-    assert kids[0]["attrs"] == {"device": True}
+    attrs = kids[0]["attrs"]
+    assert attrs == {"device": True, "counts": "edges",
+                     "block_rows": attrs["block_rows"]}
+    assert 0 < attrs["block_rows"] <= shared_tree._EDGE_COUNT_ROWS
     bare = metrics["duration_s"] - sum(s["duration_s"] for s in kids)
     assert 0 <= bare < max(1e-3, 0.01 * metrics["duration_s"])
 
