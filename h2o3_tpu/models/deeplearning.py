@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,8 @@ import numpy as np
 from ..frame.frame import Frame
 from ..parallel import distdata
 from ..parallel import mesh as cloudlib
+from ..runtime import tracing
+from . import estimator_engine as _est
 from .metrics import (
     ModelMetricsBinomial,
     ModelMetricsMultinomial,
@@ -47,7 +49,15 @@ ACTIVATIONS = (
 )
 
 
-def _act(name: str, x, k2=None, dropout=0.0):
+# The step's and the scorer's matmuls: float32 at HIGHEST. On a TPU the
+# DEFAULT precision is one bfloat16 pass, which a 56,641-step epoch does not
+# forgive; the matmuls are a small part of a step's time (PERF.md).
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _act(name: str, x, key=None, dropout=None):
+    """Activation, then inverted dropout at ratio `dropout` when a key is
+    given (a traced ratio of 0 keeps every unit, exactly)."""
     base = name.replace("WithDropout", "")
     if base == "Rectifier":
         h = jax.nn.relu(x)
@@ -58,8 +68,8 @@ def _act(name: str, x, k2=None, dropout=0.0):
         h = jnp.max(x.reshape(x.shape[0], -1, 2), axis=2)
     else:
         raise ValueError(f"unknown activation {name}")
-    if dropout > 0.0 and k2 is not None:
-        keep = jax.random.bernoulli(k2, 1 - dropout, h.shape)
+    if dropout is not None and key is not None:
+        keep = jax.random.bernoulli(key, 1 - dropout, h.shape)
         h = jnp.where(keep, h / (1 - dropout), 0.0)
     return h
 
@@ -80,25 +90,208 @@ def _init_params(key, sizes: List[int], activation: str, seed_dist="UniformAdapt
     return params
 
 
-def _forward(params, X, activation, hidden_dropout, input_dropout, key, train: bool):
+def _forward(params, X, activation, key=None, input_dropout=None,
+             hidden_dropout=None, precision=None):
+    """The network's output layer (linear; the link is the loss's or the
+    scorer's). Dropout only in training, where a key is given: at ratio
+    `input_dropout` on the inputs and `hidden_dropout[i]` after hidden
+    layer i, each None where the network has none. The matmuls run at
+    `precision`, `_PRECISION` where none is given."""
+    precision = _PRECISION if precision is None else precision
     h = X
-    if train and input_dropout > 0:
+    if key is not None and input_dropout is not None:
         key, sub = jax.random.split(key)
         keep = jax.random.bernoulli(sub, 1 - input_dropout, h.shape)
         h = jnp.where(keep, h / (1 - input_dropout), 0.0)
     L = len(params)
     for i, (W, b) in enumerate(params):
-        z = h @ W + b
-        if i < L - 1:
-            dr = hidden_dropout[i] if train and hidden_dropout else 0.0
-            if train and dr > 0:
-                key, sub = jax.random.split(key)
-            else:
-                sub = None
-            h = _act(activation, z, sub, dr if train else 0.0)
-        else:
-            h = z  # output layer linear; link applied in the loss/score
+        z = jnp.dot(h, W, precision=precision) + b
+        if i == L - 1:
+            return z
+        sub = dr = None
+        if key is not None and hidden_dropout is not None:
+            key, sub = jax.random.split(key)
+            dr = hidden_dropout[i]
+        h = _act(activation, z, sub, dr)
     return h
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "precision"))
+def _scorer(params, X, activation, precision):
+    """The scorer's forward pass as ONE program: each bias and activation
+    fuses into its matmul, so a scoring event holds the output beside the
+    design and not every layer's activations of every row."""
+    return _forward(params, X, activation, precision=precision)
+
+
+# -- the compiled training step ------------------------------------------------
+# ONE program a structure (`_Net`, plus the steps a chunk), kept by
+# `estimator_engine.cached_program`: every hyperparameter that changes no
+# shape is an operand (`_hyper`), so the candidates of a grid search, or
+# AutoML's DeepLearning steps, on one frame trace and compile the step once.
+# Parameters and optimizer state ride the scan as the per-layer tensors
+# themselves, not as one flat vector sliced into layers every step: to cut
+# a (200, 2) output layer from such a vector a TPU lays the whole vector out
+# as a (P/2, 2) array of 128-lane tiles, 64 times its bytes (72 of a 104 us
+# step on a v5e at 803-200-200-2, where the step is 22 us without it).
+
+class _Net(NamedTuple):
+    """What the compiled step is specialised on: its shapes and structure."""
+    shapes: Tuple[Tuple[int, ...], ...]   # W, b of every layer, flat order
+    activation: str
+    loss: str              # "autoencoder" | "softmax" | "poisson" | "gaussian"
+    batch: int
+    adaptive: bool         # ADADELTA, else annealed-momentum SGD
+    input_dropout: bool
+    hidden_dropout: bool
+    clamp: bool            # ADADELTA with a finite max_w2
+
+
+def _hyper(p: dict, n_hidden: int) -> dict:
+    """The fit's hyperparameters as float32 operands of the step."""
+    hd = p.get("hidden_dropout_ratios")
+    if hd is None and p.get("activation", "Rectifier").endswith("WithDropout"):
+        hd = [0.5] * n_hidden
+    f32 = lambda v: np.float32(v)
+    return dict(
+        rho=f32(p.get("rho", 0.99)), epsilon=f32(p.get("epsilon", 1e-8)),
+        rate=f32(p.get("rate", 0.005)),
+        rate_annealing=f32(p.get("rate_annealing", 1e-6)),
+        momentum_start=f32(p.get("momentum_start", 0.0)),
+        momentum_ramp=f32(max(float(p.get("momentum_ramp", 1e6)), 1.0)),
+        momentum_stable=f32(p.get("momentum_stable", 0.0)),
+        l1=f32(p.get("l1", 0.0)), l2=f32(p.get("l2", 0.0)),
+        max_w2=f32(p.get("max_w2", float("inf"))),
+        input_dropout=f32(p.get("input_dropout_ratio", 0.0)),
+        hidden_dropout=np.asarray(list(hd) if hd else [], np.float32))
+
+
+def _clamp_w2(W, max_w2):
+    """max_w2: each unit's incoming squared weights summed at most max_w2."""
+    norms = jnp.sum(W * W, axis=0, keepdims=True)
+    return W * jnp.sqrt(jnp.minimum(max_w2 / jnp.maximum(norms, 1e-12), 1.0))
+
+
+def _loss(net: _Net, params, xb, yb, wb, key, hp):
+    out = _forward(params, xb, net.activation, key,
+                   hp["input_dropout"] if net.input_dropout else None,
+                   hp["hidden_dropout"] if net.hidden_dropout else None)
+    if net.loss == "autoencoder":
+        nll = jnp.mean((out - xb) ** 2, axis=1)
+    elif net.loss == "softmax":
+        logp = jax.nn.log_softmax(out, axis=1)
+        nll = -jnp.take_along_axis(logp, yb[:, None].astype(jnp.int32),
+                                   axis=1)[:, 0]
+    elif net.loss == "poisson":
+        nll = jnp.exp(out[:, 0]) - yb * out[:, 0]
+    else:
+        nll = 0.5 * (out[:, 0] - yb) ** 2
+    loss = jnp.sum(nll * wb) / jnp.maximum(jnp.sum(wb), 1e-12)
+    # both penalties are always in the program: a coefficient of 0 adds
+    # exactly 0 to the loss and to every gradient
+    Ws = [W for W, _ in params]
+    return (loss + hp["l2"] * sum(jnp.sum(W * W) for W in Ws)
+            + hp["l1"] * sum(jnp.sum(jnp.abs(W)) for W in Ws))
+
+
+def _step(net: _Net, carry, xb, yb, wb, key, hp):
+    """One minibatch update (ADADELTA per Neurons.java, or annealed-rate
+    momentum SGD) of the per-layer parameters and optimizer state."""
+    params, state, it = carry
+    g = jax.grad(lambda ps: _loss(net, ps, xb, yb, wb, key, hp))(params)
+    tmap = jax.tree.map
+    if net.adaptive:
+        rho, eps = hp["rho"], hp["epsilon"]
+        eg2, ed2 = state
+        eg2 = tmap(lambda e, gg: rho * e + (1 - rho) * gg * gg, eg2, g)
+        d = tmap(lambda e, s, gg: -jnp.sqrt(s + eps) / jnp.sqrt(e + eps) * gg,
+                 eg2, ed2, g)
+        ed2 = tmap(lambda s, dd: rho * s + (1 - rho) * dd * dd, ed2, d)
+        params = tmap(jnp.add, params, d)
+        if net.clamp:
+            params = [(_clamp_w2(W, hp["max_w2"]), b) for W, b in params]
+        return params, (eg2, ed2), it + 1.0
+    rate = hp["rate"] / (1.0 + hp["rate_annealing"] * it)
+    ms, mst = hp["momentum_start"], hp["momentum_stable"]
+    mom = jnp.minimum(ms + (mst - ms) * it / hp["momentum_ramp"],
+                      jnp.maximum(mst, ms))
+    (vel,) = state
+    vel = tmap(lambda v, gg: mom * v - rate * gg, vel, g)
+    return tmap(jnp.add, params, vel), (vel,), it + 1.0
+
+
+def _chunk_rows(key, nrows: int, nsteps: int, batch: int):
+    """A chunk's rows, (nsteps, batch), and a dropout key a step: ONE
+    permutation of all rows in order, repeated from its start where the
+    chunk asks for more rows than there are (the last step of an epoch
+    whose rows are not a multiple of the batch, a short frame)."""
+    kperm, kdrop = jax.random.split(key)
+    perm = jax.random.permutation(kperm, nrows)
+    need = nsteps * batch
+    rows = jnp.tile(perm, -(-need // nrows))[:need].reshape(nsteps, batch)
+    return rows, jax.random.split(kdrop, nsteps)
+
+
+def _train_chunk_fn(cloud, net: _Net, nsteps: int):
+    """`nsteps` minibatch updates as ONE device program (lax.scan) over the
+    design resident in HBM. Each step gathers its own batch's rows from
+    the design: no per-batch host→device upload, and no permuted copy of
+    the whole design beside it. Replaces the reference's per-row Hogwild
+    loop (DeepLearningTask.map) with compiled minibatch SGD; the per-chunk
+    reshuffle matches `shuffle_training_data` semantics. On a mesh each
+    batch is row-sharded and XLA inserts the gradient psum (the Hogwild
+    replacement)."""
+    rows_sharding = cloud.row_sharding() if cloud.size > 1 else None
+
+    def build():
+        def dl_train_chunk(params, state, X_d, y_d, w_d, key, it0, hp):
+            rows, keys = _chunk_rows(key, X_d.shape[0], nsteps, net.batch)
+
+            def body(carry, step):
+                idx, k = step
+                xb, yb, wb = X_d[idx], y_d[idx], w_d[idx]
+                if rows_sharding is not None:
+                    xb, yb, wb = (jax.lax.with_sharding_constraint(
+                        a, rows_sharding) for a in (xb, yb, wb))
+                return _step(net, carry, xb, yb, wb, k, hp), None
+
+            (params, state, _), _ = jax.lax.scan(
+                body, (params, state, it0), (rows, keys))
+            return params, state
+
+        return jax.jit(dl_train_chunk, donate_argnums=(0, 1))
+
+    return _est.cached_program(cloud, ("dl_train_chunk", net, int(nsteps)),
+                               build)
+
+
+def _train_batch_fn(cloud, net: _Net):
+    """One update on a batch the host drew (the `max_runtime_secs` lane)."""
+    def build():
+        def dl_train_batch(params, state, xb, yb, wb, key, it, hp):
+            params, state, _ = _step(net, (params, state, it), xb, yb, wb,
+                                     key, hp)
+            return params, state
+
+        return jax.jit(dl_train_batch, donate_argnums=(0, 1))
+
+    return _est.cached_program(cloud, ("dl_train_batch", net), build)
+
+
+_STEPS: list = []
+
+
+def _steps_counter():
+    """`h2o3_dl_steps_total`: minibatch updates run by the step programs
+    (memoized: counting must not take the registry's registration lock)."""
+    if not _STEPS:
+        from ..runtime import metrics_registry as _reg
+
+        _STEPS.append(_reg.counter(
+            "h2o3_dl_steps",
+            "DeepLearning minibatch updates run by the compiled step "
+            "programs"))
+    return _STEPS[0]
 
 
 class DeepLearningModel(H2OModel):
@@ -122,7 +315,7 @@ class DeepLearningModel(H2OModel):
         design matrix — the training loop passes its HBM copy so scoring
         events skip the host re-expansion and the big re-upload."""
         X = X_pre if X_pre is not None else jnp.asarray(self.dinfo.transform(frame))
-        out = _forward(self.net_params, X, self.activation, None, 0.0, None, False)
+        out = _scorer(self.net_params, X, self.activation, _PRECISION)
         if self.problem == "autoencoder":
             return np.asarray(out, np.float64)  # reconstruction
         if self.problem in ("binomial", "multinomial"):
@@ -136,9 +329,8 @@ class DeepLearningModel(H2OModel):
         if self.problem != "autoencoder":
             raise ValueError("anomaly() requires autoencoder=True")
         X = self.dinfo.transform(frame)  # one expansion, reused for both
-        rec = np.asarray(_forward(self.net_params, jnp.asarray(X),
-                                  self.activation, None, 0.0, None, False),
-                         np.float64)
+        rec = np.asarray(_scorer(self.net_params, jnp.asarray(X),
+                                 self.activation, _PRECISION), np.float64)
         return Frame.from_dict(
             {"Reconstruction.MSE": np.mean((rec - X) ** 2, axis=1)})
 
@@ -182,8 +374,10 @@ class H2ODeepLearningEstimator(H2OEstimator):
         hidden=[200, 200],
         epochs=10.0,
         train_samples_per_iteration=-2,
-        mini_batch_size=32,           # reference default is 1 (per-row Hogwild);
-                                      # sync-DP wants real batches — documented delta
+        # H2O-3's default is 1, one row a Hogwild update on a CPU core; a
+        # compiled synchronous step of one row would be its latency alone,
+        # so a step here is 32 rows gathered from the resident design
+        mini_batch_size=32,
         adaptive_rate=True,
         rho=0.99,
         epsilon=1e-8,
@@ -248,6 +442,7 @@ class H2ODeepLearningEstimator(H2OEstimator):
         # training loop below (a second copy of this predicate diverging
         # would read X_dev_pre=None inside the loop)
         use_scan = not (_max_runtime > 0) or multiproc
+        matrix_cache = "off"
         if use_scan and not multiproc and cloud.size == 1:
             # device-resident training path: build the design matrix ON
             # device from compact columns (small-range integer features
@@ -259,13 +454,15 @@ class H2ODeepLearningEstimator(H2OEstimator):
             # std layer (ISSUE 15): a sweep's DL candidates (and AutoML's
             # three DeepLearning steps) expand + upload ONCE per frame.
             X = None
-            from . import estimator_engine as _est
-
             if _est.cache_enabled():
+                from . import dataset_cache
+
+                cache0 = dataset_cache.snapshot()
                 dinfo, X_dev_pre = _est.design_matrix(
                     train, x,
                     standardize=bool(p.get("standardize", True)),
                     use_all=bool(p.get("use_all_factor_levels", True)))
+                matrix_cache = _est.matrix_cache_state(cache0)
             else:
                 X_dev_pre = dinfo.device_design(train, fit=True)
             n, nfeat = train.nrow, int(X_dev_pre.shape[1])
@@ -332,198 +529,24 @@ class H2ODeepLearningEstimator(H2OEstimator):
         key, kinit = jax.random.split(key)
         params = _init_params(kinit, sizes, activation)
 
-        hidden_dropout = p.get("hidden_dropout_ratios")
-        if hidden_dropout is None and activation.endswith("WithDropout"):
-            hidden_dropout = [0.5] * len(hidden)
-        hidden_dropout = tuple(hidden_dropout) if hidden_dropout else None
-        input_dropout = float(p.get("input_dropout_ratio", 0.0))
-        l1 = float(p.get("l1", 0.0))
-        l2 = float(p.get("l2", 0.0))
-        max_w2 = float(p.get("max_w2", float("inf")))
+        hp = _hyper(p, len(hidden))
         adaptive = bool(p.get("adaptive_rate", True))
-        rho = float(p.get("rho", 0.99))
-        eps = float(p.get("epsilon", 1e-8))
-        rate0 = float(p.get("rate", 0.005))
-        rate_annealing = float(p.get("rate_annealing", 1e-6))
-        mom_start = float(p.get("momentum_start", 0.0))
-        mom_ramp = max(float(p.get("momentum_ramp", 1e6)), 1.0)
-        mom_stable = float(p.get("momentum_stable", 0.0))
+        net = _Net(
+            shapes=tuple(tuple(t.shape) for W, b in params for t in (W, b)),
+            activation=activation,
+            loss=("autoencoder" if autoenc
+                  else "softmax" if problem in ("binomial", "multinomial")
+                  else "poisson" if dist == "poisson" else "gaussian"),
+            batch=batch, adaptive=adaptive,
+            input_dropout=float(hp["input_dropout"]) > 0,
+            hidden_dropout=hp["hidden_dropout"].size > 0,
+            clamp=adaptive and bool(np.isfinite(hp["max_w2"])))
+        n_params = sum(int(np.prod(s)) for s in net.shapes)
+        # the keys the fit drew: its initial weights', and each chunk's (the
+        # chunk's row order and dropout masks, `_chunk_rows`), with the
+        # chunk's first iteration and steps: what replays the fit
+        rng_record = {"init_key": kinit, "chunks": []}
 
-        def loss_fn(params, xb, yb, wb, key):
-            out = _forward(params, xb, activation, hidden_dropout, input_dropout, key, True)
-            if autoenc:
-                nll = jnp.mean((out - xb) ** 2, axis=1)
-            elif problem in ("binomial", "multinomial"):
-                logp = jax.nn.log_softmax(out, axis=1)
-                nll = -jnp.take_along_axis(logp, yb[:, None].astype(jnp.int32), axis=1)[:, 0]
-            elif dist == "poisson":
-                nll = jnp.exp(out[:, 0]) - yb * out[:, 0]
-            else:
-                nll = 0.5 * (out[:, 0] - yb) ** 2
-            loss = jnp.sum(nll * wb) / jnp.maximum(jnp.sum(wb), 1e-12)
-            if l2 > 0:
-                loss = loss + l2 * sum(jnp.sum(W * W) for W, _ in params)
-            if l1 > 0:
-                loss = loss + l1 * sum(jnp.sum(jnp.abs(W)) for W, _ in params)
-            return loss
-
-        # ADADELTA state: (E[g²], E[Δ²]) per tensor (Neurons ADADELTA impl).
-        # Only the per-batch (max_runtime) path uses the structured layout;
-        # the scan path carries the fused flat state (oflat below).
-        if use_scan:
-            opt_state = None
-        elif adaptive:
-            opt_state = [
-                (jnp.zeros_like(W), jnp.zeros_like(W), jnp.zeros_like(b), jnp.zeros_like(b))
-                for W, b in params
-            ]
-        else:
-            opt_state = [(jnp.zeros_like(W), jnp.zeros_like(b)) for W, b in params]
-
-        def _update(params, opt_state, grads, it):
-            """One optimizer update (ADADELTA per Neurons.java, or
-            momentum/annealed-rate SGD) — shared by the per-batch step and
-            the device-resident scan."""
-            new_params, new_state = [], []
-            if adaptive:
-                for (W, b), (Eg2W, Ed2W, Eg2b, Ed2b), (gW, gb) in zip(params, opt_state, grads):
-                    Eg2W = rho * Eg2W + (1 - rho) * gW * gW
-                    dW = -jnp.sqrt(Ed2W + eps) / jnp.sqrt(Eg2W + eps) * gW
-                    Ed2W = rho * Ed2W + (1 - rho) * dW * dW
-                    Eg2b = rho * Eg2b + (1 - rho) * gb * gb
-                    db = -jnp.sqrt(Ed2b + eps) / jnp.sqrt(Eg2b + eps) * gb
-                    Ed2b = rho * Ed2b + (1 - rho) * db * db
-                    W2, b2 = W + dW, b + db
-                    if np.isfinite(max_w2):
-                        norms = jnp.sum(W2 * W2, axis=0, keepdims=True)
-                        scale = jnp.sqrt(jnp.minimum(max_w2 / jnp.maximum(norms, 1e-12), 1.0))
-                        W2 = W2 * scale
-                    new_params.append((W2, b2))
-                    new_state.append((Eg2W, Ed2W, Eg2b, Ed2b))
-            else:
-                rate = rate0 / (1.0 + rate_annealing * it)
-                mom = jnp.minimum(
-                    mom_start + (mom_stable - mom_start) * it / mom_ramp,
-                    jnp.maximum(mom_stable, mom_start),
-                ) if mom_ramp > 0 else mom_stable
-                for (W, b), (vW, vb), (gW, gb) in zip(params, opt_state, grads):
-                    vW2 = mom * vW - rate * gW
-                    vb2 = mom * vb - rate * gb
-                    new_params.append((W + vW2, b + vb2))
-                    new_state.append((vW2, vb2))
-            return new_params, new_state
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def train_step(params, opt_state, xb, yb, wb, key, it):
-            grads = jax.grad(loss_fn)(params, xb, yb, wb, key)
-            return _update(params, opt_state, grads, it)
-
-        # ---- flat-parameter scan path ----------------------------------
-        # The per-tensor optimizer updates are ~200 tiny elementwise ops
-        # per step; inside lax.scan that op overhead dominates a small
-        # MLP's step time (~430 µs/step measured). Flattening params and
-        # optimizer state into single vectors fuses ADADELTA into a
-        # handful of full-vector ops — identical math, elementwise either
-        # way. The flat layout exists only inside the scan; boundaries
-        # (scoring, model export) see the per-layer (W, b) list.
-        _seg_shapes = []
-        _seg_offs = []
-        _off = 0
-        for W0, b0 in params:                 # actual shapes (maxout widens)
-            for t in (W0, b0):
-                _seg_shapes.append(tuple(t.shape))
-                _seg_offs.append(_off)
-                _off += int(np.prod(t.shape))
-        _flat_n = _off
-
-        def _flatten(ps):
-            return jnp.concatenate([jnp.ravel(t) for W, b in ps
-                                    for t in (W, b)])
-
-        def _unflatten(v):
-            out = []
-            for i in range(0, len(_seg_shapes), 2):
-                W = jax.lax.dynamic_slice(
-                    v, (_seg_offs[i],),
-                    (int(np.prod(_seg_shapes[i])),)).reshape(_seg_shapes[i])
-                b = jax.lax.dynamic_slice(
-                    v, (_seg_offs[i + 1],),
-                    (int(np.prod(_seg_shapes[i + 1])),)
-                ).reshape(_seg_shapes[i + 1])
-                out.append((W, b))
-            return out
-
-        def _clamp_w2(v):
-            """Per-layer max_w2 column-norm clamp on the flat vector
-            (only traced when the non-default max_w2 is set)."""
-            for i in range(0, len(_seg_shapes), 2):
-                shp = _seg_shapes[i]
-                W = jax.lax.dynamic_slice(
-                    v, (_seg_offs[i],), (int(np.prod(shp)),)).reshape(shp)
-                norms = jnp.sum(W * W, axis=0, keepdims=True)
-                scale = jnp.sqrt(jnp.minimum(
-                    max_w2 / jnp.maximum(norms, 1e-12), 1.0))
-                v = jax.lax.dynamic_update_slice(
-                    v, (W * scale).ravel(), (_seg_offs[i],))
-            return v
-
-        def _flat_update(pv, ov, gv, it):
-            if adaptive:
-                eg2, ed2 = ov
-                eg2 = rho * eg2 + (1 - rho) * gv * gv
-                d = -jnp.sqrt(ed2 + eps) / jnp.sqrt(eg2 + eps) * gv
-                ed2 = rho * ed2 + (1 - rho) * d * d
-                pv = pv + d
-                if np.isfinite(max_w2):
-                    pv = _clamp_w2(pv)
-                return pv, (eg2, ed2)
-            rate = rate0 / (1.0 + rate_annealing * it)
-            mom = jnp.minimum(
-                mom_start + (mom_stable - mom_start) * it / mom_ramp,
-                jnp.maximum(mom_stable, mom_start),
-            ) if mom_ramp > 0 else mom_stable
-            (vel,) = ov
-            vel = mom * vel - rate * gv
-            return pv + vel, (vel,)
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1),
-                           static_argnames=("nsteps",))
-        def train_chunk(pflat, oflat, X_d, y_d, w_d, key, it0, nsteps):
-            """nsteps minibatch updates as ONE device program (lax.scan):
-            the training set lives in HBM; one random permutation per chunk
-            re-batches it into (nsteps, batch, ·) slices that scan consumes
-            directly — no per-step gathers, no per-batch host→device uploads
-            (either would dominate the step time over the host↔device
-            link). Replaces the reference's per-row Hogwild loop
-            (DeepLearningTask.map) with compiled minibatch SGD; the
-            per-chunk reshuffle matches `shuffle_training_data` semantics."""
-            kperm, kdrop = jax.random.split(key)
-            need = nsteps * batch
-            nrows = X_d.shape[0]            # global padded rows on a mesh
-            perm = jax.random.permutation(kperm, nrows)
-            reps = -(-need // nrows)                   # ceil: allow short n
-            sel = jnp.tile(perm, reps)[:need]
-            xs = (X_d[sel].reshape(nsteps, batch, -1),
-                  y_d[sel].reshape((nsteps, batch) + y_d.shape[1:]),
-                  w_d[sel].reshape(nsteps, batch),
-                  jax.random.split(kdrop, nsteps))
-
-            def flat_loss(pv, xb, yb, wb, k):
-                return loss_fn(_unflatten(pv), xb, yb, wb, k)
-
-            def body(carry, xb_yb_wb_k):
-                pv, ov, it = carry
-                xb, yb, wb, k = xb_yb_wb_k
-                gv = jax.grad(flat_loss)(pv, xb, yb, wb, k)
-                pv, ov = _flat_update(pv, ov, gv, it)
-                return (pv, ov, it + 1.0), None
-
-            (pflat, oflat, _), _ = jax.lax.scan(
-                body, (pflat, oflat, jnp.float32(it0)), xs)
-            return pflat, oflat
-
-        # sync-DP: batches row-sharded over the mesh; params replicated —
-        # XLA inserts the gradient psum (the Hogwild replacement)
         rs = cloud.row_sharding() if cloud.size > 1 else None
         epochs = float(p.get("epochs", 10.0))
         tspi = int(p.get("train_samples_per_iteration", -2))
@@ -546,8 +569,8 @@ class H2ODeepLearningEstimator(H2OEstimator):
         model = DeepLearningModel(self, x, y, dinfo, problem, nclass, domain,
                                   params, activation, dist)
         # device-resident fast path: data in HBM (row-sharded on a mesh),
-        # scan over steps; GSPMD turns the per-chunk permutation gather into
-        # collectives and psums the sharded-batch gradients automatically.
+        # scan over steps; GSPMD turns each step's gather into collectives
+        # and psums the sharded-batch gradients automatically.
         # max_runtime keeps the per-batch path (its wall check needs host
         # control between steps) — EXCEPT on multi-process clouds, where the
         # per-batch path would draw rank-divergent local batches; there the
@@ -599,102 +622,130 @@ class H2ODeepLearningEstimator(H2OEstimator):
         # too — discount them so `epochs` counts REAL samples (1.0 when
         # unpadded)
         real_frac = (n_global / float(X_dev.shape[0]) if use_scan else 1.0)
-        if use_scan:
-            pflat = _flatten(params)
-            oflat = (tuple(jnp.zeros(_flat_n, jnp.float32)
-                           for _ in range(2)) if adaptive
-                     else (jnp.zeros(_flat_n, jnp.float32),))
+        state = tuple(jax.tree.map(jnp.zeros_like, params)
+                      for _ in range(2 if adaptive else 1))
+        steps_counter = _steps_counter()
+        sm, scored_at, chunk_steps = None, None, set()
         _score_time = 0.0
-        while seen < total:
-            # REST job cancellation (single-process: a per-rank host
-            # decision would diverge a multi-process cloud)
-            if self.job is not None and jax.process_count() == 1:
-                self.job.check_cancelled()
-            if use_scan:
-                upto = min(next_score, total)
-                eff_batch = max(batch * real_frac, 1e-9)
-                steps = max(1, -(-int(upto - seen) // int(max(eff_batch, 1))))
-                key, sub = jax.random.split(key)
-                pflat, oflat = train_chunk(
-                    pflat, oflat, X_dev, y_dev, w_dev, sub,
-                    float(it), int(steps))
-                # CPU mesh: serialize collective executables (see
-                # parallel.mesh.collective_fence)
-                cloudlib.collective_fence(pflat)
-                seen += max(int(steps * eff_batch), 1)
-                it += steps
-            else:
-                idx = rng.integers(0, n, batch)
-                xb = jnp.asarray(X[idx])
-                yb = jnp.asarray(yarr[idx])
-                wb = jnp.asarray(w[idx])
-                if rs is not None:
-                    xb, yb, wb = (jax.device_put(a, rs) for a in (xb, yb, wb))
-                key, sub = jax.random.split(key)
-                params, opt_state = train_step(params, opt_state, xb, yb, wb,
-                                               sub, jnp.float32(it))
-                cloudlib.collective_fence(params[0][0])
-                seen += batch
-                it += 1
-            if seen >= next_score or seen >= total:
-                next_score += score_every
-                # train_samples_per_iteration=-2 (auto-tune): cap the wall
-                # share spent scoring at score_duty_cycle, like the
-                # reference's computeSamplesPerIteration duty-cycle target.
-                # Early stopping keeps every event (scoring IS its signal),
-                # as does the final event and score_each_iteration.
-                if (seen < total and stopper is None and tspi == -2
-                        and not max_runtime
-                        and not p.get("score_each_iteration")):
-                    want_skip = _score_time > float(
-                        p.get("score_duty_cycle", 0.1) or 0.1) * max(
-                        time.time() - t0, 1e-9)
-                    # per-rank clocks diverge; one rank skipping while
-                    # another scores would desync the scoring path's
-                    # collectives — skip only on a UNANIMOUS vote
-                    want_skip = distdata.global_all(want_skip)
-                    if want_skip:
-                        if self.job:
-                            self.job.update(min(seen / total, 1.0))
-                        continue
-                _t_sc = time.time()
+        with _est.iter_phase() as it_span:
+            while seen < total:
+                # REST job cancellation (single-process: a per-rank host
+                # decision would diverge a multi-process cloud)
+                if self.job is not None and jax.process_count() == 1:
+                    self.job.check_cancelled()
                 if use_scan:
-                    params = _unflatten(pflat)
-                model.net_params = params
-                sm = model._make_metrics(train, X_pre=X_score)
-                ev = {
-                    "epochs": seen / n_global, "iterations": it,
-                    "samples": seen, "timestamp": time.time(),
-                }
-                if problem in ("regression", "autoencoder"):
-                    ev["deviance"] = sm.mse
-                    metric_val = sm.mse
+                    upto = min(next_score, total)
+                    eff_batch = max(batch * real_frac, 1e-9)
+                    steps = max(1, -(-int(upto - seen)
+                                     // int(max(eff_batch, 1))))
+                    key, sub = jax.random.split(key)
+                    rng_record["chunks"].append((sub, it, steps))
+                    with tracing.span("iterate.chunk", kind="fit",
+                                      steps=steps, batch=batch):
+                        params, state = _train_chunk_fn(cloud, net, steps)(
+                            params, state, X_dev, y_dev, w_dev, sub,
+                            np.float32(it), hp)
+                        # CPU mesh: serialize collective executables (see
+                        # parallel.mesh.collective_fence)
+                        cloudlib.collective_fence(params[0][0])
+                    chunk_steps.add(steps)
+                    seen += max(int(steps * eff_batch), 1)
                 else:
-                    ev["logloss"] = sm.logloss
-                    metric_val = sm.logloss
-                history.append(ev)
-                stop = stopper is not None and stopper.record(metric_val)
-                # metrics are local-shard here, so ranks may disagree — a
-                # global any-rank-stops vote keeps the remaining collective
-                # programs aligned across processes
-                stop = distdata.global_any(stop)
-                _score_time += time.time() - _t_sc
-                if stop:
-                    break
-            if max_runtime:
-                hit = distdata.global_any(time.time() - t0 > max_runtime)
-                if hit:
-                    break
-            if self.job:
-                self.job.update(min(seen / total, 1.0))
+                    steps = 1
+                    idx = rng.integers(0, n, batch)
+                    xb = jnp.asarray(X[idx])
+                    yb = jnp.asarray(yarr[idx])
+                    wb = jnp.asarray(w[idx])
+                    if rs is not None:
+                        xb, yb, wb = (jax.device_put(a, rs)
+                                      for a in (xb, yb, wb))
+                    key, sub = jax.random.split(key)
+                    params, state = _train_batch_fn(cloud, net)(
+                        params, state, xb, yb, wb, sub, np.float32(it), hp)
+                    cloudlib.collective_fence(params[0][0])
+                    seen += batch
+                it += steps
+                steps_counter.inc(steps)
+                if seen >= next_score or seen >= total:
+                    next_score += score_every
+                    # train_samples_per_iteration=-2 (auto-tune): cap the
+                    # wall share spent scoring at score_duty_cycle, like the
+                    # reference's computeSamplesPerIteration duty-cycle
+                    # target. Early stopping keeps every event (scoring IS
+                    # its signal), as does the final event and
+                    # score_each_iteration.
+                    if (seen < total and stopper is None and tspi == -2
+                            and not max_runtime
+                            and not p.get("score_each_iteration")):
+                        want_skip = _score_time > float(
+                            p.get("score_duty_cycle", 0.1) or 0.1) * max(
+                            time.time() - t0, 1e-9)
+                        # per-rank clocks diverge; one rank skipping while
+                        # another scores would desync the scoring path's
+                        # collectives — skip only on a UNANIMOUS vote
+                        want_skip = distdata.global_all(want_skip)
+                        if want_skip:
+                            if self.job:
+                                self.job.update(min(seen / total, 1.0))
+                            continue
+                    _t_sc = time.time()
+                    with tracing.span("iterate.score", kind="fit"):
+                        model.net_params = params
+                        sm = model._make_metrics(train, X_pre=X_score)
+                    scored_at = it
+                    ev = {
+                        "epochs": seen / n_global, "iterations": it,
+                        "samples": seen, "timestamp": time.time(),
+                    }
+                    if problem in ("regression", "autoencoder"):
+                        ev["deviance"] = sm.mse
+                        metric_val = sm.mse
+                    else:
+                        ev["logloss"] = sm.logloss
+                        metric_val = sm.logloss
+                    history.append(ev)
+                    stop = stopper is not None and stopper.record(metric_val)
+                    # metrics are local-shard here, so ranks may disagree —
+                    # a global any-rank-stops vote keeps the remaining
+                    # collective programs aligned across processes
+                    stop = distdata.global_any(stop)
+                    _score_time += time.time() - _t_sc
+                    if stop:
+                        break
+                if max_runtime:
+                    hit = distdata.global_any(time.time() - t0 > max_runtime)
+                    if hit:
+                        break
+                if self.job:
+                    self.job.update(min(seen / total, 1.0))
+            it_span.annotate(iterations=it)
 
-        if use_scan:
-            params = _unflatten(pflat)
-        model.net_params = params
-        model.scoring_history = ScoringHistory(history)
-        model.training_metrics = model._make_metrics(train, X_pre=X_score)
-        if valid is not None:
-            model.validation_metrics = model._make_metrics(valid)
+        with tracing.span("fit.metrics", kind="fit"):
+            model.net_params = params
+            model.scoring_history = ScoringHistory(history)
+            # the last scoring event scored these very weights: its
+            # metrics are the training metrics
+            model.training_metrics = (
+                sm if scored_at == it
+                else model._make_metrics(train, X_pre=X_score))
+            if valid is not None:
+                model.validation_metrics = model._make_metrics(valid)
+        model.rng_record = rng_record
+        # a step reads its batch's rows (features, response, weight); the
+        # parameters, the gradient and each optimizer state vector are read
+        # and written once
+        bytes_per_step = (4 * batch * (nfeat + 2)
+                          + 2 * 4 * n_params * (2 + len(state)))
+        _est.record_fit(
+            "deeplearning", "fused" if use_scan else "batch",
+            iterations=it, matrix_cache=matrix_cache, n_devices=cloud.size,
+            params=n_params,
+            batch=batch, steps_per_chunk=sorted(chunk_steps),
+            chunks=len(rng_record["chunks"]),
+            bytes_per_step=bytes_per_step,
+            flops_per_step=6 * batch * sum(
+                int(np.prod(s)) for s in net.shapes[0::2]),
+            gather="per_step" if use_scan else "host_batch")
         return model
 
     def _cv_predict(self, model: DeepLearningModel, frame: Frame) -> np.ndarray:
@@ -721,7 +772,7 @@ def _dryrun_dp_step(cloud, n_devices: int):
     @jax.jit
     def step(params, X, y):
         def loss(params):
-            out = _forward(params, X, "Rectifier", None, 0.0, None, False)
+            out = _forward(params, X, "Rectifier")
             logp = jax.nn.log_softmax(out, axis=1)
             return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
 
