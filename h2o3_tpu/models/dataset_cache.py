@@ -29,6 +29,15 @@ This module is the sweep-level analog: a fingerprinted multi-layer cache
   design array (ISSUE 15). GLM, K-Means, PCA, GLRM and DeepLearning —
   and every CV fold and sweep candidate sharing a frame — reuse ONE
   upload instead of re-extracting and re-uploading per fit.
+- **targets**: + the response, weights and offset columns (each by name
+  and Vec/buffer identity) and a caller-supplied key (problem, class
+  count, distribution, estimator mode, custom objective, class balancing,
+  padded rows, device layout, see `models/shared_tree.py`) → the tree
+  fit's padded device response and weights, the padded device offset, the
+  initial margin f0 and the class-balancing priors. A sweep's candidates
+  then skip the host build of those vectors and their upload. Only
+  single-process fits whose codes the **device** layer holds use it: the
+  multi-process builds run collectives, which no cache builder may.
 
 Fingerprint: frame identity (id + DKV key + a weakref guard), row count,
 the frame's in-place mutation counter (`Frame._touch` bumps it), the x
@@ -42,7 +51,8 @@ Eviction: LRU over entries with both an entry cap
 drop their entries via weakref callback. ``H2O3_DATASET_CACHE=0`` (or the
 bench comparator ``H2O3_TRAIN_LEGACY=1``) disables caching entirely.
 
-Stats (hits/misses/evictions per layer) feed ``GET /3/Training/metrics``.
+Stats (hits/misses per layer — matrix, bins, device, blocks, std, targets —
+and evictions) feed ``GET /3/Training/metrics``.
 """
 
 from __future__ import annotations
@@ -61,7 +71,8 @@ _LOCK = threading.RLock()
 _ENTRIES: "OrderedDict[tuple, _Entry]" = OrderedDict()
 _STATS = dict(matrix_hits=0, matrix_misses=0, bins_hits=0, bins_misses=0,
               device_hits=0, device_misses=0, blocks_hits=0,
-              blocks_misses=0, std_hits=0, std_misses=0, evictions=0)
+              blocks_misses=0, std_hits=0, std_misses=0, targets_hits=0,
+              targets_misses=0, evictions=0)
 
 
 def enabled() -> bool:
@@ -79,11 +90,12 @@ def _caps() -> Tuple[int, int]:
     return max(ents, 1), int(mb * 1e6)
 
 
-class _StdArtifact:
-    """One cached standardized-design artifact (ISSUE 15): the fitted
-    DataInfo-equivalent `aux` plus the matrix itself (host np.ndarray or a
-    device jax.Array — `space` says which side of the link the bytes live
-    on, for the ledger's host/device split)."""
+class _Artifact:
+    """One cached artifact whose size the cache cannot read off a single
+    array: a standardized design, the fitted DataInfo-equivalent
+    `aux` plus the matrix itself (host np.ndarray or a device jax.Array), or
+    a tree fit's targets. `space` says which side of the link most of the
+    bytes live on, for the ledger's host/device split."""
 
     __slots__ = ("value", "_nbytes", "space")
 
@@ -98,7 +110,7 @@ class _StdArtifact:
 
 class _Entry:
     __slots__ = ("frame_ref", "key", "matrix", "bins", "device", "blocks",
-                 "std", "lock", "owner_base", "__weakref__")
+                 "std", "targets", "lock", "owner_base", "__weakref__")
 
     def __init__(self, frame, key):
         self.frame_ref = weakref.ref(frame, lambda _: _drop(key))
@@ -107,7 +119,8 @@ class _Entry:
         self.bins: Dict[tuple, object] = {}     # bkey -> BinnedMatrix
         self.device: Dict[tuple, object] = {}   # (bkey, npad) -> jax array
         self.blocks: Dict[tuple, object] = {}   # (bkey, npad, ...) -> BlockStore
-        self.std: Dict[tuple, _StdArtifact] = {}  # skey -> _StdArtifact
+        self.std: Dict[tuple, _Artifact] = {}  # skey -> _Artifact
+        self.targets: Dict[tuple, _Artifact] = {}  # tkey -> _Artifact
         self.lock = threading.Lock()            # serializes builds per entry
         self.owner_base = ""                    # memory-ledger owner prefix
 
@@ -121,12 +134,12 @@ class _Entry:
             total += _arr_nbytes(arr)
         for st in self.blocks.values():
             total += int(st.nbytes_total())
-        for art in self.std.values():
+        for art in (*self.std.values(), *self.targets.values()):
             total += art.nbytes()
         return total
 
 
-_LAYERS = ("matrix", "bins", "device", "blocks", "std")
+_LAYERS = ("matrix", "bins", "device", "blocks", "std", "targets")
 
 
 def _arr_nbytes(arr) -> int:
@@ -205,10 +218,16 @@ def _drop(key) -> None:
             pass
 
 
+def _column_guard(frame, name: str) -> tuple:
+    """A column by name and Vec/buffer identity: replacing the column, or
+    its buffer, changes the guard."""
+    v = frame.vec(name)
+    return (name, id(v),
+            id(v.data) if getattr(v, "data", None) is not None else 0)
+
+
 def _frame_key(frame, x: Tuple[str, ...]) -> tuple:
-    cols = tuple(
-        (n, id(v), id(v.data) if getattr(v, "data", None) is not None else 0)
-        for n, v in ((n, frame.vec(n)) for n in x))
+    cols = tuple(_column_guard(frame, n) for n in x)
     return (id(frame), frame.key, int(frame.nrow),
             int(getattr(frame, "_version", 0)), x, cols)
 
@@ -400,35 +419,59 @@ def blocked_codes(frame, x, nbins: int, histogram_type: str, seed, npad: int,
     return st
 
 
+def _artifact(layer: str, frame, x, key: tuple,
+              builder: Callable[[], tuple]):
+    """Look-up of an `_Artifact` layer (`std`, `targets`) — cached.
+    `builder` returns ``(value, nbytes, space)`` on a miss."""
+    e = _entry_for(frame, tuple(x))
+    arts = getattr(e, layer)
+    with e.lock:
+        art = arts.get(key)
+        if art is not None:
+            with _LOCK:
+                _STATS[f"{layer}_hits"] += 1
+            return art.value
+        with _LOCK:
+            _STATS[f"{layer}_misses"] += 1
+        art = _Artifact(*builder())
+        with _LOCK:   # see matrix(): publish vs nbytes()/snapshot() races
+            arts[key] = art
+        _memory.record_event("alloc", f"{e.owner_base}:{layer}",
+                             art.nbytes(), trigger="miss",
+                             kind="dataset_cache", space=art.space)
+    with _LOCK:
+        _evict_locked(keep=e.key)
+    return art.value
+
+
 def std_artifact(frame, x, skey: tuple, builder: Callable[[], tuple]):
-    """Standardized-design artifact for (frame, x, skey) — cached (ISSUE
-    15). `skey` carries the standardization/impute/expansion parameters
+    """Standardized-design artifact for (frame, x, skey) — cached.
+    `skey` carries the standardization/impute/expansion parameters
     (composed by `models/estimator_engine.py` — the ONE place the key
     layout lives); `builder` returns ``(value, nbytes, space)`` on a miss,
     where `value` is whatever the engine wants back (typically a
     ``(DataInfo, matrix)`` pair) and `space` is ``"host"`` or ``"device"``
     for the ledger's split. Every estimator fit and CV fold sharing the
     (frame, x, params) triple then reuses one extraction + one upload."""
-    e = _entry_for(frame, tuple(x))
-    skey = tuple(skey)
-    with e.lock:
-        art = e.std.get(skey)
-        if art is not None:
-            with _LOCK:
-                _STATS["std_hits"] += 1
-            return art.value
-        with _LOCK:
-            _STATS["std_misses"] += 1
-        value, nbytes, space = builder()
-        art = _StdArtifact(value, nbytes, space)
-        with _LOCK:   # see matrix(): publish vs nbytes()/snapshot() races
-            e.std[skey] = art
-        _memory.record_event("alloc", f"{e.owner_base}:std", int(nbytes),
-                             trigger="miss", kind="dataset_cache",
-                             space=space)
-    with _LOCK:
-        _evict_locked(keep=e.key)
-    return art.value
+    return _artifact("std", frame, x, tuple(skey), builder)
+
+
+def targets(frame, x, columns, tkey: tuple, builder: Callable[[], object]):
+    """A tree fit's targets for (frame, x) — cached. `columns` names the
+    response, weights and offset columns (None where a fit has none); each
+    joins the key by name and Vec/buffer identity. `tkey` carries
+    everything else the build reads (composed by `models/shared_tree.py`).
+    `builder` returns the artifact on a miss: device arrays and small host
+    arrays, measured here once for the ledger."""
+    key = (tuple(None if c is None else _column_guard(frame, c)
+                 for c in columns), tuple(tkey))
+
+    def sized():
+        value = builder()
+        host, dev = _memory.measure(value)
+        return value, host + dev, "device" if dev >= host else "host"
+
+    return _artifact("targets", frame, x, key, sized)
 
 
 def snapshot() -> Dict:

@@ -477,6 +477,24 @@ def frame_to_matrix(frame: Frame, x: Sequence[str], expected_domains=None):
     return np.column_stack(cols), np.asarray(cats), doms
 
 
+class _Targets(NamedTuple):
+    """What a single-process tree fit derives from its response, weights
+    and offset columns: the padded device response (npad, K) and weights
+    (npad,), zero-weight tail included; the padded device offset or None;
+    the initial margin f0 and the balance_classes priors; and the host
+    response, kept only for DRF's out-of-bag scoring. None of it depends
+    on the learning rate, row limits or seed, so a sweep's candidates on
+    one frame share one (`dataset_cache.targets`). No tree program donates
+    these arrays."""
+
+    y_d: object
+    w_d: object
+    off_d: object
+    f0: np.ndarray
+    balance_dists: Optional[tuple]
+    yk: Optional[np.ndarray]
+
+
 class _StepCfg(NamedTuple):
     """STRUCTURAL configuration of the per-iteration tree-step program —
     only what changes the traced computation graph (shapes, depth, bins,
@@ -1833,11 +1851,6 @@ class H2OSharedTreeEstimator(H2OEstimator):
             )
 
         _ph.stage("design.vectors")
-        w = (
-            train.vec(self._parms["weights_column"]).numeric_np()
-            if self._parms.get("weights_column")
-            else np.ones(n)
-        ).astype(np.float32)
         mc = self._parms.get("monotone_constraints")
         if mc:
             # {col: ±1} → (F,) vector aligned with x (GBM monotone_constraints)
@@ -1854,91 +1867,108 @@ class H2OSharedTreeEstimator(H2OEstimator):
         else:
             self._monotone_vec = None
 
-        balance_dists = None  # (prior_dist, model_dist) for score correction
-        if (self._parms.get("balance_classes")
-                and problem in ("binomial", "multinomial")):
-            # class balancing as per-class row weights — expectation-equal to
-            # the reference's minority oversampling (ModelBuilder
-            # balance_classes / class_sampling_factors); scoring applies the
-            # priorClassDist/modelClassDist probability correction below
-            codes_y = np.asarray(yvec.data)
-            counts = np.bincount(codes_y, minlength=nclass).astype(np.float64)
-            n_bal = n
-            if multiproc:
-                # global class distribution (the MRTask class-count reduce)
-                counts = distdata.global_sum(counts)
-                n_bal = float(counts.sum())
-            csf = self._parms.get("class_sampling_factors")
-            if csf is not None:
-                factors = np.asarray(csf, np.float64)
-            else:
-                factors = n_bal / (len(counts) * np.maximum(counts, 1.0))
-            cap = float(self._parms.get("max_after_balance_size", 5.0))
-            factors = np.minimum(factors, cap * n_bal / np.maximum(counts, 1.0))
-            w = (w * factors[codes_y]).astype(np.float32)
-            prior_dist = counts / counts.sum()
-            model_w = counts * factors
-            balance_dists = (prior_dist, model_w / model_w.sum())
-
-        offset = (
-            train.vec(self._parms["offset_column"]).numeric_np().astype(np.float32)
-            if self._parms.get("offset_column")
-            else None
-        )
-        if offset is not None and self._mode == "drf":
+        if self._parms.get("offset_column") and self._mode == "drf":
             # reference parity: DRF.init rejects offsets ("Offsets are not yet
             # supported for DRF") — and scoring here never applies them
             raise ValueError("offset_column is not supported for DRF")
+        K = 1 if problem in ("regression", "binomial") else nclass
 
-        if problem == "regression":
-            yk = yvec.numeric_np().astype(np.float32)[:, None]
-            K = 1
-        elif problem == "binomial":
-            yk = np.asarray(yvec.data, np.float32)[:, None]
-            K = 1
-        else:
-            K = nclass
-            codes = np.asarray(yvec.data)
-            yk = np.zeros((n, K), np.float32)
-            yk[np.arange(n), codes] = 1.0
+        def _host_targets():
+            """The response side of the design on the host: float32 row
+            weights (balance_classes factors folded in), the offset, the
+            (n, K) float32 response and the initial margin f0 — global
+            moments on a multi-host cloud, whose collectives keep this out
+            of any dataset-cache builder there."""
+            w = (
+                train.vec(self._parms["weights_column"]).numeric_np()
+                if self._parms.get("weights_column")
+                else np.ones(n)
+            ).astype(np.float32)
+            # (prior_dist, model_dist) for score correction
+            balance_dists = None
+            if (self._parms.get("balance_classes")
+                    and problem in ("binomial", "multinomial")):
+                # class balancing as per-class row weights — expectation-
+                # equal to the reference's minority oversampling
+                # (ModelBuilder balance_classes / class_sampling_factors);
+                # scoring applies the priorClassDist/modelClassDist
+                # probability correction below
+                codes_y = np.asarray(yvec.data)
+                counts = np.bincount(
+                    codes_y, minlength=nclass).astype(np.float64)
+                n_bal = n
+                if multiproc:
+                    # global class distribution (the MRTask class-count reduce)
+                    counts = distdata.global_sum(counts)
+                    n_bal = float(counts.sum())
+                csf = self._parms.get("class_sampling_factors")
+                if csf is not None:
+                    factors = np.asarray(csf, np.float64)
+                else:
+                    factors = n_bal / (len(counts) * np.maximum(counts, 1.0))
+                cap = float(self._parms.get("max_after_balance_size", 5.0))
+                factors = np.minimum(
+                    factors, cap * n_bal / np.maximum(counts, 1.0))
+                w = (w * factors[codes_y]).astype(np.float32)
+                prior_dist = counts / counts.sum()
+                model_w = counts * factors
+                balance_dists = (prior_dist, model_w / model_w.sum())
 
-        # initial margins (global moments on a multi-host cloud)
-        if multiproc and not pod:
-            sw = float(distdata.global_sum(np.asarray([w.sum()]))[0])
-            swy = distdata.global_sum((yk * w[:, None]).sum(axis=0))
-        elif pod and self._mode != "drf" \
-                and getattr(self, "_objective_fn", None) is None:
-            # pod determinism: f0 must match the 1-device comparator's
-            # host computation BITWISE, and a sum of per-rank partials
-            # does not (numpy's pairwise reduction groups differently).
-            # The response/weight columns are small — gather them exactly
-            # (byte transport, rank order = global ingest order) and run
-            # the single-process formulas on the global vectors.
-            yk_g = distdata.allgather_rows(yk)
-            w_g = distdata.allgather_rows(w)
-        if self._mode == "drf":
-            f0 = np.zeros(K, np.float32)
-        elif problem == "multinomial":
-            pri = (np.average(yk_g, axis=0, weights=w_g) if pod
-                   else swy / max(sw, 1e-12) if multiproc
-                   else np.average(yk, axis=0, weights=w))
-            f0 = np.log(np.clip(pri, 1e-10, 1.0)).astype(np.float32)
-        elif getattr(self, "_objective_fn", None) is not None:
-            f0 = np.zeros(1, np.float32)  # custom objectives start at 0 margin
-        elif multiproc and not pod and dist in ("quantile", "laplace"):
-            # order-statistic inits need GLOBAL quantiles of the response
-            alpha = (float(self._parms.get("quantile_alpha", 0.5))
-                     if dist == "quantile" else 0.5)
-            f0 = np.asarray([np.float32(
-                distdata.global_quantiles(yk[:, 0], [alpha])[0])])
-        else:
-            f0 = np.float32(dist_mod.init_margin(
-                dist, yk_g[:, 0] if pod else yk[:, 0],
-                w_g if pod else w,
-                mu=(float(swy[0]) / max(sw, 1e-12))
-                if (multiproc and not pod) else None,
-                alpha=float(self._parms.get("quantile_alpha", 0.5))))
-            f0 = np.asarray([f0])
+            offset = (
+                train.vec(self._parms["offset_column"]).numeric_np()
+                .astype(np.float32)
+                if self._parms.get("offset_column")
+                else None
+            )
+
+            if problem == "regression":
+                yk = yvec.numeric_np().astype(np.float32)[:, None]
+            elif problem == "binomial":
+                yk = np.asarray(yvec.data, np.float32)[:, None]
+            else:
+                codes = np.asarray(yvec.data)
+                yk = np.zeros((n, K), np.float32)
+                yk[np.arange(n), codes] = 1.0
+
+            # initial margins (global moments on a multi-host cloud)
+            if multiproc and not pod:
+                sw = float(distdata.global_sum(np.asarray([w.sum()]))[0])
+                swy = distdata.global_sum((yk * w[:, None]).sum(axis=0))
+            elif pod and self._mode != "drf" \
+                    and getattr(self, "_objective_fn", None) is None:
+                # pod determinism: f0 must match the 1-device comparator's
+                # host computation BITWISE, and a sum of per-rank partials
+                # does not (numpy's pairwise reduction groups differently).
+                # The response/weight columns are small — gather them exactly
+                # (byte transport, rank order = global ingest order) and run
+                # the single-process formulas on the global vectors.
+                yk_g = distdata.allgather_rows(yk)
+                w_g = distdata.allgather_rows(w)
+            if self._mode == "drf":
+                f0 = np.zeros(K, np.float32)
+            elif problem == "multinomial":
+                pri = (np.average(yk_g, axis=0, weights=w_g) if pod
+                       else swy / max(sw, 1e-12) if multiproc
+                       else np.average(yk, axis=0, weights=w))
+                f0 = np.log(np.clip(pri, 1e-10, 1.0)).astype(np.float32)
+            elif getattr(self, "_objective_fn", None) is not None:
+                # custom objectives start at 0 margin
+                f0 = np.zeros(1, np.float32)
+            elif multiproc and not pod and dist in ("quantile", "laplace"):
+                # order-statistic inits need GLOBAL quantiles of the response
+                alpha = (float(self._parms.get("quantile_alpha", 0.5))
+                         if dist == "quantile" else 0.5)
+                f0 = np.asarray([np.float32(
+                    distdata.global_quantiles(yk[:, 0], [alpha])[0])])
+            else:
+                f0 = np.float32(dist_mod.init_margin(
+                    dist, yk_g[:, 0] if pod else yk[:, 0],
+                    w_g if pod else w,
+                    mu=(float(swy[0]) / max(sw, 1e-12))
+                    if (multiproc and not pod) else None,
+                    alpha=float(self._parms.get("quantile_alpha", 0.5))))
+                f0 = np.asarray([f0])
+            return w, offset, yk, f0, balance_dists
 
         # `ndev_eff` is the device count the data will actually span — 1
         # under the H2O3_TREE_SHARD=0 escape hatch even on a mesh
@@ -2213,6 +2243,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 codes_d = distdata.global_row_array(padr(bm.codes), quota,
                                                     cloud)
             _ph.stage("design.state")
+            w, offset, yk, f0, balance_dists = _host_targets()
             y_d = distdata.global_row_array(
                 padr(yk).astype(np.float32), quota, cloud)
             w_d = distdata.global_row_array(padr(w), quota, cloud)
@@ -2262,6 +2293,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     return _unpack_device(dev, widen) if widen else dev
 
             ooc_store = None
+            cache_codes = (use_cache and not ooc_blocks
+                           and (ndev_eff == 1 or shard_mode == "mesh"))
             if ooc_blocks:
                 # out-of-core: the matrix NEVER uploads whole. Packed
                 # blocks are built O(block) from the padded codes and live
@@ -2283,7 +2316,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 else:
                     ooc_store = _build_store()
                 codes_d = None
-            elif use_cache and (ndev_eff == 1 or shard_mode == "mesh"):
+            elif cache_codes:
                 # sweep-level reuse: every candidate sharing this
                 # (frame, x, nbins, histogram) trains off ONE device-resident
                 # code matrix — the pack + H2D upload happens once. The
@@ -2299,36 +2332,71 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 _ph.stage_span.annotate(cache="off")
                 codes_d = _build_codes_dev()
             _ph.stage("design.state")
-            if yk.size and bool(np.all((yk >= 0) & (yk <= 255)
-                                       & (yk == np.floor(yk)))):
-                # integer-ish response (class indicators, counts): ship uint8
-                # over the host↔device link (4× smaller) and widen on device
-                _phases_mod.add("h2d", 0.0, npad)
-                y_d = jnp.asarray(padr(yk.astype(np.uint8))).astype(jnp.float32)
+
+            def _build_targets():
+                w, offset, yk, f0, balance_dists = _host_targets()
+                if yk.size and bool(np.all((yk >= 0) & (yk <= 255)
+                                           & (yk == np.floor(yk)))):
+                    # integer-ish response (class indicators, counts): ship
+                    # uint8 over the host↔device link (4× smaller) and
+                    # widen on device
+                    _phases_mod.add("h2d", 0.0, npad)
+                    y_d = jnp.asarray(
+                        padr(yk.astype(np.uint8))).astype(jnp.float32)
+                else:
+                    _phases_mod.add("h2d", 0.0, 4 * npad)
+                    y_d = jnp.asarray(padr(yk))
+                if np.all(w == 1.0):
+                    # trivial weights: build on device (zero-weight padded
+                    # tail) instead of pushing 4·npad bytes of 1.0s over
+                    # the link
+                    w_d = (jnp.ones(npad, jnp.float32).at[n:].set(0.0)
+                           if pad else jnp.ones(npad, jnp.float32))
+                else:
+                    _phases_mod.add("h2d", 0.0, 4 * npad)
+                    w_d = jnp.asarray(padr(w))
+                off_d = (None if offset is None
+                         else jnp.asarray(padr(offset)))
+                if ndev_eff > 1:
+                    rs = cloud.row_sharding()
+                    y_d = jax.device_put(y_d, rs)
+                    w_d = jax.device_put(w_d, rs)
+                return _Targets(y_d, w_d, off_d, f0, balance_dists,
+                                yk if self._mode == "drf" else None)
+
+            if cache_codes:
+                # the response side is the same for every candidate on the
+                # frame: built and uploaded once, like the codes. The key
+                # is everything `_build_targets` reads beyond the columns
+                _ph.stage_span.annotate(cache="hit")
+                csf = self._parms.get("class_sampling_factors")
+                tkey = (
+                    problem, nclass, dist, self._mode,
+                    getattr(self, "_objective_fn", None) is not None,
+                    float(self._parms.get("quantile_alpha", 0.5))
+                    if dist == "quantile" else None,
+                    bool(self._parms.get("balance_classes")),
+                    None if csf is None else tuple(map(float, csf)),
+                    float(self._parms.get("max_after_balance_size", 5.0)),
+                    npad, ndev_eff)
+                tg = _dsc.targets(
+                    train, x, (y, self._parms.get("weights_column"),
+                               self._parms.get("offset_column")), tkey,
+                    builder=_noting_miss(_ph, _build_targets))
             else:
-                _phases_mod.add("h2d", 0.0, 4 * npad)
-                y_d = jnp.asarray(padr(yk))
-            if np.all(w == 1.0):
-                # trivial weights: build on device (zero-weight padded tail)
-                # instead of pushing 4·npad bytes of 1.0s over the link
-                w_d = jnp.ones(npad, jnp.float32).at[n:].set(0.0) if pad else (
-                    jnp.ones(npad, jnp.float32))
-            else:
-                _phases_mod.add("h2d", 0.0, 4 * npad)
-                w_d = jnp.asarray(padr(w))
+                _ph.stage_span.annotate(cache="off")
+                tg = _build_targets()
+            y_d, w_d, off_d, f0, balance_dists, yk = tg
             _phases_mod.add("h2d", 0.0, edges.nbytes)
             edges_d = jnp.asarray(edges)
 
             if ndev_eff > 1:
-                rs = cloud.row_sharding()
-                codes_d = jax.device_put(codes_d, rs)
-                y_d = jax.device_put(y_d, rs)
-                w_d = jax.device_put(w_d, rs)
+                codes_d = jax.device_put(codes_d, cloud.row_sharding())
                 edges_d = jax.device_put(edges_d, cloud.replicated())
 
             margins = jnp.broadcast_to(jnp.asarray(f0)[None, :], (npad, K)).astype(jnp.float32)
-            if offset is not None:
-                margins = margins + jnp.asarray(padr(offset))[:, None]
+            if off_d is not None:
+                margins = margins + off_d[:, None]
             if ndev_eff > 1:
                 margins = jax.device_put(margins, cloud.row_sharding())
 
@@ -2418,8 +2486,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     margins = _margin_ffwd_jit(
                         jax.tree.map(jnp.asarray, pm.forest[k]), codes_d,
                         margins, jnp.int32(k), tp["max_depth"])
-                if offset is not None:
-                    margins = margins + jnp.asarray(padr(offset))[:, None]
+                if off_d is not None:
+                    margins = margins + off_d[:, None]
                 if ndev_eff > 1:
                     codes_d = jax.device_put(codes_d, cloud.row_sharding())
                     edges_d = jax.device_put(edges_d, cloud.replicated())

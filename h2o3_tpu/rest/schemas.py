@@ -278,7 +278,8 @@ def training_metrics_schema() -> Dict:
          " busy/(wall×parallelism)"),
         ("cache", "DatasetCacheStats",
          "the dataset-artifact cache (models/dataset_cache.py): hits/"
-         "misses per layer (matrix/bins/device), evictions, live entries,"
+         "misses per layer (matrix/bins/device/blocks/std/targets),"
+         " evictions, live entries,"
          " resident bytes, enabled flag"),
         ("totals.retried", "int",
          "candidate build attempts re-run after a TRANSIENT failure"

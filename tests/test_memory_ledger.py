@@ -348,6 +348,33 @@ def test_dataset_cache_cap_eviction_emits_event(cloud1, monkeypatch):
     DKV.remove("ml_cap_2")
 
 
+def test_tree_targets_layer_registered_and_released(cloud1, monkeypatch):
+    """A tree fit's targets (padded device response and weights) are a
+    `dataset_cache:<fp>:targets` owner holding their device bytes; a cap
+    eviction and a clear each unregister it."""
+    from h2o3_tpu.models import dataset_cache
+
+    dataset_cache.clear()
+    fr1 = _cls_frame("ml_tgt_1", n=300, seed=22)
+    _gbm(fr1, ntrees=2, max_depth=2)
+    ml.refresh(force=True)
+    tgt = [o for o in ml.owners("dataset_cache:")
+           if o["owner"].endswith(":targets")]
+    assert len(tgt) == 1
+    assert tgt[0]["device_bytes"] >= 2 * 4 * 300   # y_d and w_d, padded
+    monkeypatch.setenv("H2O3_DATASET_CACHE_ENTRIES", "1")
+    fr2 = _cls_frame("ml_tgt_2", n=300, seed=23)
+    _gbm(fr2, ntrees=2, max_depth=2)
+    assert ml.owners(tgt[0]["owner"]) == []          # evicted with fr1's entry
+    assert [o for o in ml.owners("dataset_cache:")
+            if o["owner"].endswith(":targets")]
+    dataset_cache.clear()
+    assert not [o for o in ml.owners("dataset_cache:")
+                if o["owner"].endswith(":targets")]
+    DKV.remove("ml_tgt_1")
+    DKV.remove("ml_tgt_2")
+
+
 def test_ingest_buffer_accounted(cloud1):
     from h2o3_tpu.frame import chunked
 

@@ -81,6 +81,145 @@ def test_dataset_cache_eviction_and_disable(cloud1, monkeypatch):
     assert s["matrix_hits"] == s["matrix_misses"] == 0
 
 
+# -- the targets layer: response, weights and initial margin -----------------
+_X4 = ["x0", "x1", "x2", "x3"]
+
+
+def _targets_frame(n=600, seed=21):
+    """Four features and one column for each role the targets layer keys:
+    binary, three-class and real responses, two weight columns, an
+    offset."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, 4))
+    z = X[:, 0] + 0.5 * X[:, 1]
+    cols = [X, (z > 0.3).astype(float), np.digitize(z, [-0.8, 0.4]),
+            z + 0.1 * rng.normal(size=n), rng.uniform(0.5, 2.0, n),
+            rng.uniform(0.2, 3.0, n), 0.1 * rng.normal(size=n)]
+    return Frame.from_numpy(
+        np.column_stack(cols),
+        names=_X4 + ["yb", "ym", "yr", "w", "w2", "off"]) \
+        .asfactor("yb").asfactor("ym")
+
+
+def _tree_fit(fr, y, drf=False, npad_floor=None, **kw):
+    from h2o3_tpu.models.drf import H2ORandomForestEstimator
+
+    cls = H2ORandomForestEstimator if drf else H2OGradientBoostingEstimator
+    est = cls(ntrees=3, max_depth=3, seed=7, score_tree_interval=1, **kw)
+    if npad_floor is not None:
+        est._parms["_npad_floor"] = npad_floor
+    est.train(x=_X4, y=y, training_frame=fr)
+    return est.model
+
+
+def _assert_same_fit(a, b):
+    """Forest, f0, class-balancing priors, training metrics and every
+    scoring-history loss, bit for bit."""
+    from h2o3_tpu.models import tree as treelib
+    from h2o3_tpu.models.metrics import MetricValue
+
+    assert a.ntrees_built == b.ntrees_built
+    for ta, tb in zip(a.forest, b.forest):
+        for f in treelib.Tree._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(ta, f)),
+                                          np.asarray(getattr(tb, f)), f)
+    np.testing.assert_array_equal(np.asarray(a.f0), np.asarray(b.f0))
+    assert (a.balance_dists is None) == (b.balance_dists is None)
+    for pa, pb in zip(a.balance_dists or (), b.balance_dists or ()):
+        np.testing.assert_array_equal(pa, pb)
+    ma, mb = vars(a.training_metrics), vars(b.training_metrics)
+    named = [k for k, v in ma.items() if isinstance(v, MetricValue)]
+    assert named
+    for k in named:
+        np.testing.assert_array_equal(float(ma[k]), float(mb[k]), k)
+    assert len(a.scoring_history) == len(b.scoring_history) > 0
+    for ea, eb in zip(a.scoring_history, b.scoring_history):
+        np.testing.assert_equal(   # NaN equals NaN here, as it should
+            {k: v for k, v in ea.items() if k != "timestamp"},
+            {k: v for k, v in eb.items() if k != "timestamp"})
+
+
+def test_targets_layer_built_once_across_candidates(cloud1):
+    fr = _targets_frame(seed=31)
+    for lr, min_rows in ((0.1, 10), (0.2, 5), (0.3, 1)):
+        _tree_fit(fr, "yb", learn_rate=lr, min_rows=min_rows)
+    s = dataset_cache.snapshot()
+    assert s["targets_misses"] == 1 and s["targets_hits"] == 2
+    assert s["device_misses"] == 1 and s["device_hits"] == 2
+
+
+@pytest.mark.parametrize("y,kw", [
+    ("yb", {}),
+    ("yr", {"weights_column": "w"}),
+    ("ym", {}),
+    ("yb", {"drf": True}),
+    ("ym", {"balance_classes": True}),
+    ("yr", {"offset_column": "off"}),
+], ids=["gbm_bernoulli", "gbm_gaussian_weights", "gbm_multinomial", "drf",
+        "balance_classes", "offset"])
+def test_targets_hit_is_bit_identical_to_uncached(cloud1, monkeypatch, y,
+                                                  kw):
+    """A fit that takes its targets from the cache trains exactly what a
+    fit with the dataset cache off trains."""
+    fr = _targets_frame(seed=32)
+    _tree_fit(fr, y, **kw)          # builds the entry
+    hit = _tree_fit(fr, y, **kw)
+    s = dataset_cache.snapshot()
+    assert s["targets_misses"] == 1 and s["targets_hits"] == 1
+    monkeypatch.setenv("H2O3_DATASET_CACHE", "0")
+    _assert_same_fit(hit, _tree_fit(fr, y, **kw))
+    assert dataset_cache.snapshot()["targets_misses"] == 1
+
+
+def _swap_response(fr):
+    # a new Vec under the same name, the frame's version left alone: only
+    # the response column's identity can tell the fits apart
+    from h2o3_tpu.frame.vec import Vec
+
+    fr._vecs["yr"] = Vec.from_numpy(fr.vec("yr").numeric_np() * 3.0 + 1.0)
+
+
+@pytest.mark.parametrize("before,after,mutate", [
+    ({}, {}, _swap_response),
+    ({}, {}, Frame._touch),
+    ({"weights_column": "w"}, {"weights_column": "w2"}, None),
+    ({}, {"distribution": "laplace"}, None),
+    ({}, {"drf": True}, None),
+    ({}, {"npad_floor": 4096}, None),
+], ids=["response_swapped", "frame_touched", "weights_swapped",
+        "distribution", "drf_after_gbm", "npad_floor"])
+def test_targets_key_parts_invalidate(cloud1, monkeypatch, before, after,
+                                      mutate):
+    """Each part of the key on its own forces a fresh build, and the fit
+    after it trains what an uncached fit of the same change trains."""
+    fr = _targets_frame(seed=33)
+    _tree_fit(fr, "yr", **before)
+    if mutate is not None:
+        mutate(fr)
+    changed = _tree_fit(fr, "yr", **after)
+    s = dataset_cache.snapshot()
+    assert s["targets_misses"] == 2 and s["targets_hits"] == 0
+    monkeypatch.setenv("H2O3_DATASET_CACHE", "0")
+    _assert_same_fit(changed, _tree_fit(fr, "yr", **after))
+
+
+def test_targets_layer_skipped_by_cv_folds_and_ooc(cloud1, monkeypatch):
+    fr = _targets_frame(seed=34)
+    H2OGradientBoostingEstimator(ntrees=2, max_depth=3, seed=7, nfolds=3) \
+        .train(x=_X4, y="yb", training_frame=fr)
+    assert trainpool.snapshot()["cv"]["reuse_folds"] == 3
+    s = dataset_cache.snapshot()   # the main fit alone: fold fits skip it
+    assert s["targets_misses"] == 1 and s["targets_hits"] == 0
+    dataset_cache.clear()
+    dataset_cache.reset_stats()
+    monkeypatch.setenv("H2O3_TREE_OOC", "1")
+    for _ in range(2):
+        _tree_fit(fr, "yb")
+    s = dataset_cache.snapshot()
+    assert s["blocks_misses"] == 1 and s["blocks_hits"] == 1
+    assert s["targets_misses"] == 0 and s["targets_hits"] == 0
+
+
 # -- CV fold reuse -------------------------------------------------------------
 def test_cv_reuse_metric_parity_with_rebin(cloud1, monkeypatch):
     """Fold reuse slices the parent's binned codes (fold-local bin edges
