@@ -2623,7 +2623,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         if cfg.grow_policy != "lossguide":
             plan_levels = treelib.histogram_level_plan(cfg.max_depth,
                                                       cfg.compact_cap)
-            plan_read = treelib.partition_read(F)
+            plan_read = treelib.partition_read(F, cfg.code_operand)
         else:
             plan_levels = [("lossguide_node", 1)]
             plan_read = None

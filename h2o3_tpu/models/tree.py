@@ -81,7 +81,10 @@ def histogram_level_plan(max_depth: int, compact_cap: int = 0):
 
 # one-hot contraction beats a per-row dynamic gather on TPU by ~10× (the
 # VPU has no fast per-lane table lookup; XLA serializes row gathers), but
-# materializes an (N, L) operand — only worth it for small tables
+# materializes an (N, L) operand — only worth it for small tables. For the
+# partition's read of a row's code (`partition_read`) it governs only the
+# fits that have no fit-lifetime code operand: with one, the select reads
+# that operand at any width
 _ONEHOT_LOOKUP_MAX = 128
 
 
@@ -101,14 +104,28 @@ def _lookup_bool(table: jax.Array, idx: jax.Array, L: int) -> jax.Array:
     return (oh & table[None, :]).any(axis=1)
 
 
-def partition_read(n_features: int) -> str:
-    """How a partition step reads a row's split-feature code at this frame
-    width: ``"select"`` (dense one-hot over the feature axis) or
-    ``"gather"`` (per-row gather, frames wider than `_ONEHOT_LOOKUP_MAX`).
-    The ONE rule, shared by `_row_codes` and the driver's per-fit plan
+def partition_read(n_features: int, code_operand: str = "program") -> str:
+    """How a partition step reads a row's split-feature code:
+    ``"select"`` (dense one-hot over the feature axis) or ``"gather"``
+    (per-row gather from the row-major codes). The ONE rule, shared by
+    `_row_codes` and the tree fit's plan
     (`ops.histogram.record_fit_plan`), so the recorded read cannot diverge
-    from the one that runs."""
-    return "gather" if n_features > _ONEHOT_LOOKUP_MAX else "select"
+    from the one that runs.
+
+    `code_operand` is the fit's form of the Pallas kernel's code operand
+    (`ops.histogram.code_operand_form`). ``"fit"``: the tree program is
+    handed the feature-major float32 operand, and the select reads it at
+    ANY width. By bytes: the select streams F × N × 4 bytes a level, about
+    6 ps a row a feature on a v5e, where the gather costs about 20 ns a
+    row whatever F is — the select wins up to roughly 3,000 features, and
+    an operand that wide would not fit on the chip. ``"program"`` (no
+    operand: CPU ``segment`` fits, the mesh and blocks lanes, streamed
+    blocks, the scoring walk, lossguide): the select would widen the
+    codes feature-major inside every program, so frames wider than
+    `_ONEHOT_LOOKUP_MAX` keep the gather."""
+    if code_operand == "fit" or n_features <= _ONEHOT_LOOKUP_MAX:
+        return "select"
+    return "gather"
 
 
 def _row_codes(codes: jax.Array, rf: jax.Array, pack_bits: int = 0,
@@ -117,27 +134,28 @@ def _row_codes(codes: jax.Array, rf: jax.Array, pack_bits: int = 0,
     split on; the one place every partition step and training-time walk
     over packed codes asks for it.
 
-    Narrow frames select densely over the feature axis of the (F, N)
-    float32 feature-major codes the Pallas histogram kernel takes: every
-    level's select is one more streaming read of that buffer. Nothing
-    gathers into the code matrix: on the TPU a per-row gather costs ~20 ns
-    a row (228.6 ms at 11.5M rows) where the compare-select-sum is bound by
-    bytes. Given `operand` — the fit's `ops.histogram.build_code_operand`
-    array, padded past F features and N rows with -1 — the select reads
-    its first F × N and nothing is widened here; without it, packed codes
-    (`pack_bits` in {4, 5, 6}) are widened and laid feature-major in this
-    program (`ops.histogram.feature_major`).
-
-    Frames wider than `_ONEHOT_LOOKUP_MAX` keep the gather, from the
-    row-major codes (widened here when packed; `partition_read`)."""
+    The select reads densely over the feature axis of the (F, N) float32
+    feature-major codes the Pallas histogram kernel takes: every level's
+    select is one more streaming read of that buffer. On the TPU a per-row
+    gather costs ~20 ns a row (228.6 ms at 11.5M rows) where the
+    compare-select-sum is bound by bytes. Given `operand` — the fit's
+    `ops.histogram.build_code_operand` array, padded past F features and N
+    rows with -1 — the select reads its first F × N at every width, and
+    `codes` is not read at all; without it, packed codes (`pack_bits` in
+    {4, 5, 6}) are widened and laid feature-major in this program
+    (`ops.histogram.feature_major`), and frames wider than
+    `_ONEHOT_LOOKUP_MAX` keep the gather from the row-major codes (widened
+    here when packed; `partition_read`). The operand holds the same codes
+    as exact float32 integers and exactly one feature matches a row, so
+    both reads give the same code."""
     if pack_bits:
         F = codes.shape[1]
         N = packing.packed_nrows(codes.shape[0], pack_bits)
     else:
         N, F = codes.shape
-    read = partition_read(F)
+    read = partition_read(F, "program" if operand is None else "fit")
     record_partition_read(read)
-    if read == "select" and operand is not None:
+    if operand is not None:
         codes_fm = operand[:F, :N]
     else:
         if pack_bits:
@@ -399,17 +417,17 @@ def build_tree(
     program, and the partition step reads each row's selected-feature code
     from the same widened codes by a dense select over the feature axis
     (`_row_codes`; no gather into the code matrix at
-    F <= `_ONEHOT_LOOKUP_MAX`).
+    F <= `_ONEHOT_LOOKUP_MAX`, nor at any F given `operand`).
 
     `operand` is the fit-lifetime form of the same codes: the Pallas
     histogram kernel's feature-major float32 operand, built once a fit by
     `ops.histogram.build_code_operand` (`code_operand_form` says for which
-    fits). Given it, the kernel's levels and the select read it and this
-    program widens nothing for them; the gather read at
-    F > `_ONEHOT_LOOKUP_MAX` and a level that fell back to ``segment``
-    still read `codes`. The values are the same either way, so the tree is
-    bit for bit the same. One device only: the blocked reduction
-    (`n_shard_blocks`) refuses it.
+    fits). Given it, the kernel's levels and the partition's select read
+    it at every frame width (`partition_read`) and this program widens
+    nothing for them; only a level that fell back to ``segment`` still
+    reads `codes`. The values are the same either way, so the tree is bit
+    for bit the same as the one the gather read builds without it. One
+    device only: the blocked reduction (`n_shard_blocks`) refuses it.
 
     n_shard_blocks > 0 (ISSUE 12) makes every row reduction (histograms
     and final leaf totals) use the shard-invariant blocked fold of

@@ -186,16 +186,16 @@ def test_fused_scorer_page_compiles_for_v5e(one_chip):
             max_depth=depth).compile()
 
 
-def _flagship_step_cfg(npad):
+def _flagship_step_cfg(npad, n_features=F, nbins=NBINS):
     from h2o3_tpu.models import shared_tree
     from h2o3_tpu.ops import packing
 
     cfg = shared_tree._StepCfg(
-        npad=npad, K=1, F=F, nbins=NBINS, problem="binomial",
+        npad=npad, K=1, F=n_features, nbins=nbins, problem="binomial",
         dist="bernoulli", mode="gbm", max_depth=6, has_mtries=False,
         no_row_sampling=True, has_col_sampling=False, has_monotone=False,
         tweedie_power=1.5, quantile_alpha=0.5, hist_method="pallas_factored",
-        pack_bits=packing.pack_bits_for(NBINS, npad))
+        pack_bits=packing.pack_bits_for(nbins, npad))
     # as `_make_step_cfg` resolves it: one device, the kernel's levels
     return cfg._replace(
         code_operand=shared_tree._cfg_operand_form(cfg)["form"])
@@ -244,6 +244,49 @@ def test_tree_step_compiles_for_v5e(one_chip):
     assert gathered and max(gathered) <= 32, gathered
     assert "tree.partition/gather" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
+def test_wide_tree_step_selects_over_the_operand_for_v5e(one_chip):
+    """The per-tree program of a custom objective's round
+    (`single_tree_jit`) at the ranking cell's shape: 2,359,296 padded rows
+    of 136 full-width features, 256 bins. Its code argument is the pair of
+    the resident uint8 codes and the fit's `(136, N)` float32 operand, and
+    the partition reads a row's split-feature code by a select over the
+    operand at this width too (`tree.partition_read`): no gather is left
+    over the rows (at the parent, six `u8[N] gather`s of the row-major
+    codes, one a level), the codes are not a parameter of the executable,
+    and the temporaries are 92,767,744 bytes where the gathers' program
+    reserved 106,296,832."""
+    from h2o3_tpu.models import shared_tree
+    from h2o3_tpu.models import tree as treelib
+    from h2o3_tpu.parallel import mesh as cloudlib
+
+    npad, f, nbins = 2_359_296, 136, 256
+    cfg = _flagship_step_cfg(npad, f, nbins)
+    assert cfg.pack_bits == 0 and cfg.code_operand == "fit"
+    assert treelib.partition_read(f, cfg.code_operand) == "select"
+    _, single_jit = shared_tree._build_tree_step_fns(cfg, cloudlib.cloud())
+    f32, s = jnp.float32, one_chip
+    codes = (_sds((npad, f), jnp.uint8, s),
+             _sds(shared_tree._operand_shape(cfg), f32, s))
+    assert codes[1].shape == (f, npad)
+    col = _sds((npad,), f32, s)
+    compiled = single_jit.lower(
+        _sds((npad, 1), f32, s), codes, _sds((npad, 1), f32, s), col, col,
+        _sds((f, nbins - 2), f32, s), _sds((f,), f32, s), _sds((9,), f32, s),
+        _sds((2,), jnp.uint32, s), _sds((), jnp.int32, s), col,
+        col).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 6
+    gathered = [int(n) for n in re.findall(r"= \w+\[(\d+)\][^=]* gather\(",
+                                           text)]
+    assert npad not in gathered, gathered
+    assert "tree.partition/gather" not in text
+    # the resident uint8 codes are read by nothing, so they are no
+    # parameter of the executable
+    params = re.findall(r"= (\w+\[[\d,]*\])[^=]* parameter\(", text)
+    assert f"u8[{npad},{f}]" not in params, params
+    assert compiled.memory_analysis().temp_size_in_bytes < (128 << 20)
 
 
 @pytest.mark.parametrize("bits,nbins", [(4, 16), (5, NBINS), (6, 33)])

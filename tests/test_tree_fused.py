@@ -89,13 +89,19 @@ def test_unpack_device_equals_unpack_host(bits, B, N):
     assert "gather" not in text
 
 
-@pytest.mark.parametrize("F,read", [(1, "select"), (28, "select"),
-                                    (128, "select"), (129, "gather")])
-def test_partition_read_rule(F, read):
-    """The rule `_row_codes` and the per-fit plan share: dense select up
-    to `_ONEHOT_LOOKUP_MAX` features, gather beyond."""
+@pytest.mark.parametrize("F,form,read", [
+    (1, "program", "select"), (28, "program", "select"),
+    (128, "program", "select"), (129, "program", "gather"),
+    (28, "fit", "select"), (129, "fit", "select"), (136, "fit", "select"),
+])
+def test_partition_read_rule(F, form, read):
+    """The rule `_row_codes` and the per-fit plan share: a fit handed the
+    code operand (`form` "fit") selects over it at every width; without
+    it, dense select up to `_ONEHOT_LOOKUP_MAX` features, gather beyond."""
     assert treelib._ONEHOT_LOOKUP_MAX == 128
-    assert treelib.partition_read(F) == read
+    assert treelib.partition_read(F, form) == read
+    if form == "program":
+        assert treelib.partition_read(F) == read
 
 
 # -- build_tree: the parity matrix ------------------------------------------
@@ -143,9 +149,11 @@ def test_build_tree_code_operand_parity(bits, B, F, N):
     """`build_tree` handed the fit-lifetime operand against `build_tree`
     handed the resident codes alone, the Pallas kernel in interpret mode:
     the tree, the leaf indices, the gains and the covers are bit for bit
-    the same — through the dense select (F = 28) and the gather read
-    (F = 136), packed at every width and full width, at a row count the
-    operand pads (520 is no multiple of 8 x 128)."""
+    the same — at F = 28 the select over the operand against the select
+    over the codes widened in the program, at F = 136 the select over the
+    operand against the gather read from the row-major codes; packed at
+    every width and full width, at a row count the operand pads (520 is
+    no multiple of 8 x 128)."""
     from jax.experimental.pallas import tpu as pltpu
 
     codes, g, h, w, fm, edges, _ = _tree_data(N=N, F=F, B=B)
@@ -168,6 +176,8 @@ def test_build_tree_code_operand_parity(bits, B, F, N):
     assert np.array_equal(np.asarray(operand[:F, :N]), codes.T)
     assert np.all(np.asarray(operand[F:]) == -1)
     assert np.all(np.asarray(operand[:, N:]) == -1)
+    assert treelib.partition_read(F) == ("gather" if F > 128 else "select")
+    assert treelib.partition_read(F, form["form"]) == "select"
     assert _leaves_equal(base, got)
 
 
@@ -422,7 +432,7 @@ def test_cv_fold_reuse_parity_packed_vs_full_width(cloud1, _tree_env):
 
 # -- whole-fit parity: the code operand built once a fit · widened a tree ----
 
-def _fit_both_operand_forms(make_est):
+def _fit_both_operand_forms(make_est, X=_FIT_X, y=_FIT_Y, names=_FIT_NAMES):
     """Train `make_est()` twice with the Pallas kernel (H2O3_HIST_METHOD,
     which every tree estimator reads) in interpret mode:
     as the rule says (one device, the kernel's levels: the operand is built
@@ -442,7 +452,7 @@ def _fit_both_operand_forms(make_est):
                 if forced is not None:
                     mp.setattr(shared_tree, "_cfg_operand_form",
                                lambda cfg, _f=forced: dict(_f))
-                est = _train(make_est(), _FIT_X, _FIT_Y, _FIT_NAMES)
+                est = _train(make_est(), X, y, names)
             out.append((est, histogram.kernel_stats()["plans"][-1]))
     return out
 
@@ -493,6 +503,28 @@ def test_fit_parity_code_operand_fit_vs_program(cloud1, _tree_env, make_est):
     _assert_models_bitexact(a, b)
     counts = histogram.kernel_stats()["code_operand"]
     assert counts.get("fit", 0) > 0 and counts.get("program", 0) > 0
+
+
+@pytest.mark.parametrize("make_est", [_gbm_kernel, _xgb_custom_kernel])
+def test_wide_fit_selects_over_the_code_operand(cloud1, _tree_env, make_est):
+    """A frame wider than `_ONEHOT_LOOKUP_MAX`, whole fits through
+    `tree_jit` and `single_tree_jit`: handed the operand, the partition
+    selects over it, and the plan and the trace-time counter say
+    `select`; made to widen for itself, the program gathers, and says
+    `gather`. The forests are the same bit for bit."""
+    f = 136
+    X, y = make_classification(n=1024, f=f, seed=11)
+    names = [f"f{i}" for i in range(f)] + ["label"]
+    before = dict(histogram.kernel_stats()["partition_read"])
+    (a, plan_a), (b, plan_b) = _fit_both_operand_forms(make_est, X, y, names)
+    assert plan_a["code_operand"] == "fit"
+    assert plan_a["partition_read"] == "select"
+    assert plan_b["code_operand"] == "program"
+    assert plan_b["partition_read"] == "gather"
+    after = histogram.kernel_stats()["partition_read"]
+    for read in ("select", "gather"):
+        assert after.get(read, 0) > before.get(read, 0), (before, after)
+    _assert_models_bitexact(a, b)
 
 
 def test_cpu_fit_builds_no_code_operand(cloud1, _tree_env):
@@ -574,6 +606,23 @@ def test_partition_read_recorded_in_kernel_stats(cloud1, _tree_env):
         "test:wide", [("d0", 1)], 21, "segment",
         partition_read=treelib.partition_read(130))
     assert plan["partition_read"] == "gather"
+    # a wide fit handed the code operand selects over it, and says so:
+    # in its plan, and in the trace-time counter of the read that runs
+    levels = treelib.histogram_level_plan(3)
+    form = histogram.code_operand_form(levels, 256, "pallas_factored",
+                                       platform="tpu")["form"]
+    assert form == "fit"
+    plan = histogram.record_fit_plan(
+        "test:wide_operand", levels, 256, "pallas_factored",
+        platform="tpu", partition_read=treelib.partition_read(136, form),
+        code_operand=form)
+    assert plan["partition_read"] == "select"
+    assert plan["code_operand"] == "fit"
+    before = histogram.kernel_stats()["partition_read"]["select"]
+    got = treelib._row_codes(jnp.zeros((8, 136), jnp.uint8), rf,
+                             operand=jnp.zeros((136, 8), jnp.float32))
+    assert np.array_equal(np.asarray(got), np.zeros(8, np.int32))
+    assert histogram.kernel_stats()["partition_read"]["select"] == before + 1
     from h2o3_tpu.runtime import metrics_registry
 
     assert "h2o3_tree_partition_read_total" in metrics_registry.prometheus_text()

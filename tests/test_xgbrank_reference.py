@@ -140,7 +140,10 @@ def test_the_fit_says_what_it_ran(cloud1, bench, fitted):
     _, _, ref = bench
     data, est, frame, plan, result, prep, span = fitted
     assert treelib.partition_read(136) == "gather"
+    assert treelib.partition_read(136, "fit") == "select"
     assert treelib.partition_read(28) == "select"
+    # a CPU fit has no code operand (`segment` levels): it gathers
+    assert plan["code_operand"] == "program"
     assert plan["partition_read"] == "gather" and plan["nbins"] == 256
     rank = plan["rank"]
     assert rank["queries"] == 200 and rank["group_max"] == 250
