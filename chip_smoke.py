@@ -38,9 +38,23 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-from bench import make_higgs_like  # the flagship generator
-
 HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def make_higgs_like(n_rows: int, n_feat: int = 28, seed: int = 0):
+    """The flagship's synthetic stand-in: standard-normal features and a
+    label drawn from a logistic of a few of them."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n_rows, n_feat)).astype(np.float32)
+    logits = (
+        1.2 * X[:, 0]
+        - 0.8 * X[:, 1]
+        + 1.5 * X[:, 2] * X[:, 3]
+        + 0.7 * np.sin(3 * X[:, 4])
+        + 0.5 * (X[:, 5] ** 2 - 1)
+    )
+    y = (rng.random(n_rows) < 1 / (1 + np.exp(-logits))).astype(np.float32)
+    return X, y
 
 
 def higgs_frame(n_rows: int, seed: int, key: str | None = None):

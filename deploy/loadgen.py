@@ -19,7 +19,7 @@ with the serving histograms scraped at `GET /3/Metrics`.
 The standalone CLI is STDLIB-ONLY: it must run from a loadgen host with
 no jax/h2o3 installed, and must not import (and configure) jax as a side
 effect in the loadgen process. When the platform is already loaded
-in-process (bench.py, the in-process test servers), every request is
+in-process (the in-process test servers), every request is
 additionally folded into the central registry
 (`h2o3_loadgen_request_ms{mode=...}`) so a loadgen run is itself
 scrapable.
@@ -34,8 +34,7 @@ Usage:
 
 Importable: `run_load(...)` / `run_load_open(...)` return the stats dict
 (the smoke tests in tests/test_serving.py and tests/test_observability.py
-drive an in-process server through them; `BENCH_CONFIG=serving` in
-bench.py is the open-loop SLO lane).
+drive an in-process server through them).
 """
 
 from __future__ import annotations
@@ -501,8 +500,8 @@ def run_load_open(host: str, port: int, model: str, frame: str,
     Percentiles come from the shared fixed latency buckets
     (LATENCY_MS_BOUNDS — the same bounds the serving histograms use), so
     they are directly comparable with `GET /3/Metrics` and with every
-    other loadgen/bench report; `hist_*` fields carry the raw bucket
-    vector for the bench JSON.
+    other loadgen report; `hist_*` fields carry the raw bucket
+    vector.
 
     Leak canary (sustained mode): RSS + memory-ledger totals are sampled
     once per decile of the arrival schedule, and the report carries the
@@ -694,7 +693,7 @@ def run_concurrent_sweep(host: str, port: int, model: str, frame: str,
     `candidates`-way GBM grid sweep trains on the same accelerator; windows
     repeat until the sweep completes and fold into one histogram. The
     report carries split idle-vs-contended p50/p95/p99 plus the sweep's
-    wall time — the numbers the `BENCH_CONFIG=qos` lane embeds.
+    wall time (tests/test_qos.py's concurrent soak reads them).
 
     Requires the platform importable in this process (the sweep trains
     here); the plain closed/open CLI modes stay stdlib-only."""

@@ -20,13 +20,13 @@ import numpy as np
 
 def compile_cache_dir() -> str:
     """Resolve JAX's persistent compilation cache — the ONE place that
-    decides where it lives (package import, bench.py, chip_smoke.py and
-    tests/conftest.py all come through here). A JAX_COMPILATION_CACHE_DIR
+    decides where it lives (package import, benchmark/run.py, chip_smoke.py
+    and tests/conftest.py all come through here). A JAX_COMPILATION_CACHE_DIR
     in the environment wins: jax reads it itself and nothing is set in
     code. Otherwise the cache is `<checkout>/.jax_cache` — a fixed path,
     because the path is part of the cache key's lookup: a directory that
     moves never hits. Tree programs take seconds to compile; every process
-    of one checkout (test workers, bench children) shares the entries."""
+    of one checkout (test workers, spawned children) shares the entries."""
     import jax
 
     if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):

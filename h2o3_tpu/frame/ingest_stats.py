@@ -9,7 +9,7 @@ Job progress units). Readers:
 - `GET /3/Ingest/metrics` and the `ingest` section of `/3/Profiler`
   (via runtime/profiler.ingest_stats) serve `snapshot()`;
 - `runtime/phases.py` receives the same marks under ``ingest_<stage>``
-  keys, so bench.py's phase decomposition covers ingest next to
+  keys, so its phase decomposition covers ingest next to
   h2d/compile/compute.
 
 Phase bucketing: "coerce" books columns that resolve numeric/time (the

@@ -10,7 +10,7 @@ AstMerge.java`). Readers:
 - `GET /3/Munge/metrics` and the `munge` section of `/3/Profiler`
   (via runtime/profiler.munge_stats) serve `snapshot()`;
 - `runtime/phases.py` receives the same marks under ``munge_<op>`` keys,
-  so bench.py's phase decomposition covers munging next to ingest and
+  so its phase decomposition covers munging next to ingest and
   h2d/compile/compute.
 
 `path` tags how the op executed: "vectorized" (the columnar kernels),

@@ -128,7 +128,7 @@ def parse_csv(
     H2O3_PARSE_CHUNK_BYTES defaults; chunk count never changes the result
     (pinned bit-identical by tests/test_parse_parallel.py). Setting
     H2O3_INGEST_LEGACY=1 routes through the historical per-line tokenizer
-    (the bench.py comparator)."""
+    (the tests' reference)."""
     from . import chunked as _chunked
     from . import ingest_stats as _stats
 
@@ -240,8 +240,7 @@ def _tokenize_numpy(path: str, sep: str, header: bool, ncol: int) -> List[np.nda
     """LEGACY tokenizer: whole-file read + per-line split. The chunked
     pipeline (frame/chunked.py) replaced it as the default; it stays as the
     bit-exact reference the parallel path is pinned against
-    (tests/test_parse_parallel.py) and as bench.py's speedup comparator
-    (H2O3_INGEST_LEGACY=1)."""
+    (tests/test_parse_parallel.py, H2O3_INGEST_LEGACY=1)."""
     with open(path, "rb") as f:
         text = f.read().decode("utf-8", errors="replace")
     lines = text.splitlines()
@@ -254,8 +253,7 @@ def _tokenize_numpy(path: str, sep: str, header: bool, ncol: int) -> List[np.nda
 def _legacy_tokens_to_vec(col: np.ndarray, hint: Optional[str]) -> Vec:
     """The SEED coercion (pre-chunked-pipeline): per-element `float()`
     loops and object-array `np.unique` interning. Kept verbatim as the
-    other half of the H2O3_INGEST_LEGACY comparator — bench.py measures
-    the chunked pipeline against the seed's full tokenize+coerce path, and
+    other half of the H2O3_INGEST_LEGACY reference:
     tests/test_parse_parallel.py pins the new path bit-identical to it."""
     from .vec import _all_int, _maybe_f32
 

@@ -64,8 +64,7 @@ from ..runtime import persist as _persist
 from ..runtime import phases as _phases
 
 _TOTALS_LOCK = threading.Lock()
-# process-lifetime stream totals — the bench/loadgen record embed next to
-# the memory embeds (`streamed_bytes`, `resident_block_peak`)
+# process-lifetime stream totals (`streamed_bytes`, `resident_block_peak`)
 _TOTALS = {"streamed_bytes": 0, "resident_block_peak": 0,
            "spilled_bytes": 0, "restored_bytes": 0,
            "resident_host_peak": 0}
@@ -570,22 +569,8 @@ class BlockStore:
         import jax
 
         hb = self.fetch_host(b)
-
-        def _put():
-            return jax.device_put(hb)
-
-        t0 = time.perf_counter()
-        arr = _put()
-        if _phases.ENABLED:
-            # accounted transfer: a tiny D2H is the only reliable barrier
-            # over the host↔device link (see phases.accounted_h2d)
-            try:
-                np.asarray(arr.ravel()[:1])
-            except Exception:
-                jax.block_until_ready(arr)
-            _phases.add("h2d_stream", time.perf_counter() - t0, hb.nbytes)
-        else:
-            _phases.add("h2d_stream", 0.0, hb.nbytes)
+        arr = jax.device_put(hb)
+        _phases.add("h2d_stream", 0.0, hb.nbytes)
         return arr
 
     def get(self, b: int):

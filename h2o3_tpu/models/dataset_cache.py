@@ -48,8 +48,8 @@ new frames (new ids) naturally.
 Eviction: LRU over entries with both an entry cap
 (``H2O3_DATASET_CACHE_ENTRIES``, default 4) and a byte budget
 (``H2O3_DATASET_CACHE_MB``, default 1024, host+device bytes). Dead frames
-drop their entries via weakref callback. ``H2O3_DATASET_CACHE=0`` (or the
-bench comparator ``H2O3_TRAIN_LEGACY=1``) disables caching entirely.
+drop their entries via weakref callback. ``H2O3_DATASET_CACHE=0``
+disables caching entirely.
 
 Stats (hits/misses per layer — matrix, bins, device, blocks, std, targets —
 and evictions) feed ``GET /3/Training/metrics``.
@@ -76,11 +76,8 @@ _STATS = dict(matrix_hits=0, matrix_misses=0, bins_hits=0, bins_misses=0,
 
 
 def enabled() -> bool:
-    if os.environ.get("H2O3_DATASET_CACHE", "1") in ("0", "false", "no"):
-        return False
-    from ..runtime import trainpool
-
-    return not trainpool.legacy()
+    return os.environ.get("H2O3_DATASET_CACHE", "1") not in ("0", "false",
+                                                            "no")
 
 
 def _caps() -> Tuple[int, int]:

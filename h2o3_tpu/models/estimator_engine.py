@@ -48,7 +48,7 @@ import numpy as np
 
 def legacy() -> bool:
     """``H2O3_EST_LEGACY=1`` restores the host per-iteration estimator
-    paths as the bench/parity comparator (for GLM lambda search that is
+    paths as the parity comparator (for GLM lambda search that is
     the host IRLS loop — the pre-device-program shape)."""
     return os.environ.get("H2O3_EST_LEGACY", "").lower() in ("1", "true",
                                                             "yes")

@@ -7,9 +7,9 @@ Reference parity: `h2o-algos/src/main/java/hex/grid/GridSearch.java`,
 `h2o-py/h2o/grid/grid_search.py` (`H2OGridSearch(model, hyper_params,
 search_criteria)`, `get_grid(sort_by, decreasing)`).
 
-Models in a grid are independent → on a pod this is embarrassingly parallel
-across hosts; round 1 builds sequentially (each build already uses the full
-mesh), which matches the reference's default parallelism=1 sequential walk.
+Models in a grid are independent: candidates train through
+`runtime/trainpool.TrainPool`, and the reference's default parallelism=1
+runs them one at a time (each build already uses the full mesh).
 """
 
 from __future__ import annotations
@@ -250,15 +250,9 @@ class H2OGridSearch:
         par = max(int(parallelism if parallelism is not None
                       else self.parallelism), 1)
 
-        from ..runtime import trainpool as _tp
-
-        if _tp.legacy():
-            # H2O3_TRAIN_LEGACY=1: the seed sequential walk, verbatim — the
-            # bench.py vs_seed comparator (no pool, no artifact cache)
-            return self._train_sequential(combos, x, y, training_frame,
-                                          t0, budget, **kw)
-
         import threading
+
+        from ..runtime import trainpool as _tp
 
         ckpt_lock = threading.Lock()
 
@@ -298,31 +292,6 @@ class H2OGridSearch:
             elif rec.status == "failed":
                 # failed combos are recorded, the walk continues
                 self.failed.append({"params": combo, "error": rec.error})
-        return self
-
-    def _train_sequential(self, combos, x, y, training_frame, t0, budget,
-                          **kw):
-        """The seed-era sequential walk (H2O3_TRAIN_LEGACY comparator)."""
-        for combo in combos:
-            if budget and time.time() - t0 > budget:
-                break
-            parms = dict(self.base_parms)
-            parms.update(combo)
-            parms.pop("model_id", None)
-            try:
-                est = self.model_class(**parms)
-                est.train(x=x, y=y, training_frame=training_frame, **kw)
-                est._grid_combo = combo
-                self.models.append(est)
-            except Exception as e:
-                self.failed.append({"params": combo, "error": str(e)})
-                continue
-            if self.recovery_dir:
-                try:
-                    self._record_done(est, combo)
-                    self._save_state()
-                except (TypeError, OSError):
-                    pass
         return self
 
     def _record_done(self, est, combo) -> None:

@@ -1095,9 +1095,8 @@ class H2OEstimator:
         # reuse its codes via row-index slicing instead of two full
         # `Frame.take` copies + a per-fold re-bin/re-pack (LightGBM/XGBoost-
         # style CV over one quantized matrix). The fold frame shrinks to the
-        # response + any *_column parameters. H2O3_CV_REBIN=1 (or the bench
-        # comparator H2O3_TRAIN_LEGACY=1) restores the seed per-fold path,
-        # which stays bit-exact with earlier rounds.
+        # response + any *_column parameters. H2O3_CV_REBIN=1 restores the
+        # seed per-fold path, which stays bit-exact with earlier rounds.
         import os as _os
 
         from ..parallel import distdata
@@ -1105,7 +1104,6 @@ class H2OEstimator:
 
         reuse_bm = None
         if (_os.environ.get("H2O3_CV_REBIN", "") in ("", "0")
-                and not _trainpool.legacy()
                 and not distdata.multiprocess()
                 and self._cv_can_reuse()):
             reuse_bm = self._cv_reuse_source(model, train)

@@ -69,30 +69,16 @@ class _Phase:
         self._stage = _tracing.span(name, kind="fit")
         self.stage_span = self._stage.__enter__()
 
-    def mark(self, name, sync=None, then=None):
-        """Record a phase boundary into /3/Timeline (always); under
-        H2O3_PHASE_ACCOUNTING=1 additionally device-sync first, so the
-        recorded seconds are execution (not dispatch) time.
-        Boundaries also feed runtime.phases so bench.py can decompose
-        wall-clock into {h2d, compute, d2h, ...} buckets. `then` names the
-        ``fit.*`` span that begins at this boundary."""
+    def mark(self, name, then=None):
+        """Record a phase boundary into /3/Timeline. Boundaries also feed
+        runtime.phases, which decomposes wall-clock into {h2d, compute,
+        d2h, ...} buckets. `then` names the ``fit.*`` span that begins at
+        this boundary."""
         from ..runtime.timeline import Timeline
 
         _phases = _phases_acct
-        synced = _phases.ENABLED and sync is not None
-        if synced:
-            # fetch one element: on some backends
-            # block_until_ready can return before the computation lands —
-            # a tiny D2H is the only reliable barrier
-            import numpy as _np
-
-            try:
-                _np.asarray(sync.ravel()[:1])
-            except Exception:
-                jax.block_until_ready(sync)
         now = time.time()
-        Timeline.record("train_phase", name, secs=round(now - self.t, 4),
-                        synced=synced)
+        Timeline.record("train_phase", name, secs=round(now - self.t, 4))
         # compile/trace time inside this interval is already accounted by
         # the monitoring listener, and collective-fence waits by
         # mesh.collective_fence — subtract both so the compute bucket
@@ -1644,8 +1630,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
                 from ..runtime.log import Log
 
                 Log.warn("goss: this fit is not eligible for the "
-                         "out-of-core streamed path (see docs/perf.md); "
-                         "training unsampled in-core")
+                         "out-of-core streamed path (see "
+                         "docs/architecture.md); training unsampled in-core")
             return 0, None
         codes_bytes = (npad * F * resident_bits // 8 if resident_bits
                        else npad * F)
@@ -2127,11 +2113,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
             # built in-process (a CV fold after its parent, or a repeat
             # grid/AutoML candidate), the dummy warm execution is pure
             # waste — a full tree step on zeros competing with the sweep's
-            # real work. The legacy comparator keeps the seed behavior.
-            from ..runtime import trainpool as _tpool
-
-            if not _tpool.legacy() and cfg_early in \
-                    cloud.__dict__.get("_step_fns_cache", {}):
+            # real work.
+            if cfg_early in cloud.__dict__.get("_step_fns_cache", {}):
                 cfg_early = None
             code_dt = jnp.uint8 if nbins <= 256 else jnp.uint16
             # packed codes: the dummy matrix takes the packed shape so the
@@ -2499,7 +2482,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         if ooc_blocks and cfg.compact_cap:
             # the streamed level loop is dense-only; deep streamed fits
             # keep exactness by skipping active-node compaction (the
-            # in-core comparator must match — docs/perf.md)
+            # in-core comparator must match)
             cfg = cfg._replace(compact_cap=0)
         # ---- the histogram kernel's code operand, once a fit ---------------
         # The codes do not change during a fit, so where the plan runs the
@@ -2584,7 +2567,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
             # superseded by the device y_dev_v (slot 4); indices are stable
             valid_state = [codes_v, None, margins_v, n_v, y_dev_v, vmask_d]
 
-        _ph.mark("device_put", sync=codes_d, then="fit.iterate")
+        _ph.mark("device_put", then="fit.iterate")
         _ph.stage("iterate.setup")
         key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
         ntrees_target = max(int(tp["ntrees"]) - n_prior, 0)
@@ -2785,7 +2768,7 @@ class H2OSharedTreeEstimator(H2OEstimator):
         dev_bytes = 0
         # deep forests (heap 2^(d+1) nodes × 5 fields × K) can exceed HBM if
         # the whole run stays device-resident — flush to host past this
-        # budget. Generous by default (the bench chip has 16 GB): a flush
+        # budget. Generous by default (a v5e chip has 16 GB): a flush
         # costs minutes of D2H, an HBM-resident pack costs bytes
         _PACK_BUDGET = int(os.environ.get("H2O3_PACK_BUDGET_MB", 4096)) << 20
 
@@ -3015,12 +2998,8 @@ class H2OSharedTreeEstimator(H2OEstimator):
         _ph.stage("iterate.dispatch")
         while m < ntrees_target:
             # QoS chunk-boundary yield: while a serving dispatch is in
-            # flight the next chunk's programs hold back here. The wait
-            # lands inside the next chunk mark's interval (which books to
-            # "compute"), so it is compensated out of that bucket.
-            _qos.yield_point(
-                "tree_chunk",
-                compensate="compute" if _phases_acct.ENABLED else None)
+            # flight the next chunk's programs hold back here.
+            _qos.yield_point("tree_chunk")
             # in-process candidate-crash injection (kill-and-resume pins)
             # + supervisor heartbeat: liveness at every chunk boundary
             _rfaults.check("supervisor.fit_abort", detail=f"m={m}")
@@ -3146,10 +3125,6 @@ class H2OSharedTreeEstimator(H2OEstimator):
                     dart_scales.append(fn)
                 else:
                     dart_scales.append(1.0)
-            if _phases_acct.ENABLED:
-                # synced boundary: without it the compute bucket would time
-                # async dispatch, not execution, and overstate throughput
-                _ph.mark(f"chunk_{m}_{nsteps}trees", sync=margins)
             m += nsteps
             built = m
 

@@ -50,8 +50,8 @@ codes as 8-sublane f32 feature blocks, while Mosaic's int8 minimum tile is
 (32, 128) — a u8 packed operand would force a 32-feature block
 restructure (4× one-hot VMEM per step) or lane-strided unpacking of the
 interleaved row groups, neither validatable without a chip in the loop.
-See docs/perf.md appendix; ROADMAP items 1/3 stream the same packed
-representation and inherit whichever decode lands.
+ROADMAP items 1/3 stream the same packed representation and inherit
+whichever decode lands.
 """
 
 from __future__ import annotations

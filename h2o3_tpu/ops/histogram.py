@@ -37,7 +37,7 @@ VMEM fallback, ``segment`` on a CPU, ``onehot`` on other accelerators):
   matrix — what the dataset cache holds across fits and what the H2D
   upload moves — stays packed. True in-kernel sub-byte
   decode is blocked by Mosaic's (32, 128) int8 tile granularity at the
-  kernel's 8-feature block shape (see docs/perf.md).
+  kernel's 8-feature block shape (see ops/hist_pallas.py).
 
 The cross-host combine (ScoreBuildHistogram2.reduce / Rabit allreduce) is a
 single `lax.psum` over the ``hosts`` mesh axis, applied by the caller inside

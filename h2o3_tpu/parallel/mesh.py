@@ -170,7 +170,7 @@ def collective_fence(x) -> None:
 
     The blocked time is booked to the ``collective`` phase bucket
     (runtime/phases): on a CPU mesh it is the wait for collective-program
-    completion, so bench records decompose a sharded fit's wall into
+    completion, so runtime/phases decomposes a sharded fit's wall into
     {h2d, compute, collective, ...} instead of hiding the merge cost in
     compute."""
     import time as _time
@@ -297,7 +297,7 @@ _LANE_REG: dict = {}
 _F32_ZERO = np.float32(0.0)
 # Topology cached at init() so watchdog threads can map a lane to its
 # owning RANK without ever touching jax (a hung backend blocks any jax
-# call — the round-4 rc:124 failure mode the bench watchdog exists for).
+# call).
 _LANE_PROC: dict = {}              # lane -> owning process index
 _LANE_SELF: int = 0                # this process's index
 _LANE_EXPECT: int = 0              # lanes whose callbacks run IN this process
@@ -522,7 +522,7 @@ def lane_seq() -> int:
 
 def lane_last_waits() -> dict:
     """{lane: wait_ms} naming the suspect lane of a hung collective —
-    host-side dicts only (safe from the bench watchdog thread while the
+    host-side dicts only (safe from a watchdog thread while the
     backend hangs). A fence currently OPEN (some lanes arrived, the
     collective still waiting on the rest) takes priority: its partial
     arrivals are reported, so the lanes MISSING from the dict are exactly
@@ -545,7 +545,7 @@ def lane_ranks() -> dict:
 
 
 def lane_hang_report() -> dict:
-    """The bench/MULTICHIP watchdog's hung-collective attribution: which
+    """The supervisor watchdog's hung-collective attribution: which
     lanes arrived at the currently-open fence, which are missing, and the
     RANKS owning the missing lanes (cached topology — never a jax call).
 
@@ -589,8 +589,7 @@ def lane_records(since_seq: int = 0) -> list:
 
 def lane_summary(since_seq: int = 0) -> dict:
     """Fold the fences recorded after `since_seq` into one summary (the
-    per-fit skew embed: record_fit_plan tree fold, bench records, fit
-    trace events): fence count, skew p50/max, and the worst lane."""
+    per-fit skew embed: record_fit_plan tree fold, fit trace events): fence count, skew p50/max, and the worst lane."""
     recs = lane_records(since_seq)
     if not recs:
         return dict(fences=0)

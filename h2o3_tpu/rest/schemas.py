@@ -148,8 +148,7 @@ def memory_schema() -> Dict:
          " aggregation scraped as h2o3_memory_bytes{owner_kind,space}"),
         ("watermarks", "MemoryWatermarks",
          "high watermark of host/device/total attributed bytes plus the"
-         " top-3 owners captured at the combined peak (the bench-record"
-         " memory embed reads this)"),
+         " top-3 owners captured at the combined peak"),
         ("pressure", "MemoryPressure",
          "pressure in [0,1]: max(host bytes vs H2O3_MEM_BUDGET_MB or"
          " MemTotal, device bytes vs device capacity); serving admission"

@@ -2126,7 +2126,7 @@ class H2OApiServer:
 
     def start(self) -> "H2OApiServer":
         # a serving REST process always tracks XLA compiles/retraces — the
-        # /3/Metrics retrace counters must not depend on bench env flags
+        # /3/Metrics retrace counters must not depend on an earlier train()
         from ..runtime import phases
 
         phases.install_listener()

@@ -2,8 +2,8 @@
 
 The hardening layer (runtime/retry.py, trainpool candidate retries, serving
 failover) is only trustworthy if its failure paths are EXERCISED, not just
-written; this registry lets tests, the chaos bench (`BENCH_CONFIG=chaos`)
-and operators arm specific failure points without touching product code.
+written; this registry lets tests and operators arm specific failure
+points without touching product code.
 Every wired call site costs one dict lookup when nothing is armed (the
 `_ACTIVE` fast path), so production runs pay ~nothing.
 
@@ -33,8 +33,8 @@ Wired points (each named like the layer it lives in):
                             ``h2o3_qos_preempt_latency_ms``
 ``mesh.rank_kill``          HARD-EXITS this process (``os._exit``) inside a
                             mesh lane's collective-arrival callback — the
-                            rank-death injection of the pod chaos lane
-                            (``BENCH_CONFIG=pod_chaos``); ``after=N`` delays
+                            rank-death injection of the pod chaos drill
+                            (tests/test_supervisor.py); ``after=N`` delays
                             the kill to the N+1-th fence so checkpoints
                             exist before the death (parallel/mesh)
 ``supervisor.ckpt_corrupt`` truncates a fit checkpoint's serialized blob

@@ -162,8 +162,7 @@ def remove_peer(name: str) -> bool:
 
 def mark_down(name: str, reason: str = "") -> None:
     """Flip a replica's liveness to DOWN immediately — failure detection
-    (the training supervisor's hung-collective abort, the bench
-    watchdog's suspect attribution) must reach the fleet scrape NOW, not
+    (the training supervisor's hung-collective abort) must reach the fleet scrape NOW, not
     at the next failed scrape of the wedged rank. Sets the
     ``h2o3_fleet_peer_up`` series to 0 whether or not the name is a
     registered peer (a pod rank detected dead locally may only be

@@ -721,8 +721,7 @@ def pressure() -> float:
 
 
 def peak() -> Dict:
-    """High watermarks + the top-3 owners captured at the combined peak
-    (the bench-record memory embed)."""
+    """High watermarks + the top-3 owners captured at the combined peak."""
     refresh()
     return dict(host_bytes=_HWM["host"], device_bytes=_HWM["device"],
                 disk_bytes=_HWM["disk"], total_bytes=_HWM["total"],

@@ -12,7 +12,7 @@ store —
   ``_bucket{le=...}``/``_sum``/``_count`` histogram series);
 - ``snapshot()`` → the JSON fold in ``/3/Profiler``;
 - per-metric reads (``Counter.value()``, ``Histogram.percentile(q)``,
-  ``Counter.rate(window_s)``) for tests and the bench driver.
+  ``Counter.rate(window_s)``) for tests and operators.
 
 Semantics follow Prometheus, not the legacy snapshot modules: registry
 counters are MONOTONE for the life of the process (module-level ``reset()``

@@ -1,11 +1,11 @@
-"""Per-phase wall-clock and byte accounting for benchmarks.
+"""Per-phase wall-clock and byte accounting.
 
 Decomposes a training run's wall-clock into the phases that matter on an
 accelerator — {h2d_s, compile_s, deserialize_s,
 trace_s, compute_s (residual), bytes_h2d} — so "fast" is auditable per
 phase instead of one conflated number (the reference logs per-stage Timer
 lines, `water/util/Timer` + `water/H2O` timeline; here the decomposition
-feeds bench.py's JSON).
+feeds `/3/Training/metrics` and the per-candidate sinks).
 
 Two sources:
 - jax monitoring events (always cheap): `backend_compile_duration` →
@@ -16,18 +16,13 @@ Two sources:
   thread holds open; trace and lowering seconds tally on that span as the
   attr ``xla_trace_s``.
 - explicit instrumentation at the few fat host→device transfer points
-  (`accounted_h2d`). device_put is async, so
-  measuring real transfer time needs a one-element D2H barrier after the
-  put — that would serialize transfers a production run deliberately
-  overlaps, so the barrier only happens when accounting is enabled
-  (H2O3_PHASE_ACCOUNTING=1, set by bench.py). Byte counts are recorded
-  unconditionally.
+  (`accounted_h2d`), which records their bytes. device_put is async and a
+  production run overlaps its transfers, so no transfer is timed here.
 """
 
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from collections import OrderedDict, defaultdict, deque
@@ -216,8 +211,8 @@ def _request_span(duration: float, fun_name) -> None:
 
 
 def xla_counts() -> dict:
-    """Cumulative compile/trace/retrace/cache totals (bench JSON embed +
-    the warm-path counter pins)."""
+    """Cumulative compile/trace/retrace/cache totals (the benchmark's
+    compile counts + the warm-path counter pins)."""
     with _XLA_LOCK:
         return dict(_XLA_TOTALS)
 
@@ -249,11 +244,8 @@ def candidate_sink():
     finally:
         _TLS.sink = prev
 
-ENABLED = os.environ.get("H2O3_PHASE_ACCOUNTING", "").lower() not in (
-    "", "0", "false", "no")
-
-# phases the jax monitoring listener owns; accounted_h2d subtracts their
-# concurrent growth so first-call compilation isn't booked as transfer
+# phases the jax monitoring listener owns; a training-driver phase mark
+# subtracts their concurrent growth so compilation isn't booked as compute
 COMPILE_KEYS = ("compile", "trace", "deserialize")
 
 
@@ -300,37 +292,14 @@ def timed(phase: str, nbytes: int = 0):
 
 def accounted_h2d(thunk, nbytes: int):
     """Run `thunk()` (a host→device transfer, possibly fused with a small
-    on-device expand program) with H2D time/byte accounting.
-
-    When accounting is off the thunk runs untouched (only the byte count is
-    recorded); when on, a one-element fetch after it makes the recorded
-    seconds actual transfer time (a tiny D2H is the barrier that holds on
-    every backend). Compile time the call triggers (first-call jit of the
-    expand program) is already accounted by the monitoring listener and is
-    subtracted out.
-    """
-    if not ENABLED:
-        add("h2d", 0.0, nbytes)
-        return thunk()
-    import jax
-    import numpy as np
-
-    install_listener()
-    comp0 = totals(COMPILE_KEYS)
-    t0 = time.perf_counter()
-    out = thunk()
-    try:
-        np.asarray(out.ravel()[:1] if hasattr(out, "ravel") else out)
-    except Exception:
-        jax.block_until_ready(out)
-    elapsed = time.perf_counter() - t0 - (totals(COMPILE_KEYS) - comp0)
-    add("h2d", max(elapsed, 0.0), nbytes)
-    return out
+    on-device expand program) untouched and book its `nbytes` to ``h2d``."""
+    add("h2d", 0.0, nbytes)
+    return thunk()
 
 
 def add_mark(name: str, secs: float) -> None:
     """Fold a training-driver phase boundary (shared_tree._Phase.mark) into
-    the canonical phase buckets bench.py reports."""
+    the canonical phase buckets."""
     if name == "device_put":
         phase = "h2d"
     elif name.endswith("_D2H"):
@@ -398,9 +367,3 @@ def install_listener() -> None:
 
     monitoring.register_event_duration_secs_listener(_on)
     monitoring.register_event_listener(_on_event)
-
-
-if ENABLED:
-    # self-contained accounting: a user script that sets the env flag gets
-    # the compile/trace listener without having to know bench.py calls this
-    install_listener()

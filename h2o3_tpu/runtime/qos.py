@@ -50,8 +50,7 @@ Observability: ``h2o3_qos_yields{site}``, ``h2o3_qos_waits_ms{site}``,
 ``h2o3_qos_preempt_latency_ms`` registry families; waits booked into the
 ``qos_wait`` phase bucket (subtracted from the enclosing compute bucket at
 sites that would otherwise double-book); `stats()` is the /3/Profiler
-``qos`` fold; `gate_state()` names the class holding the gate (the bench
-watchdog's hang-attribution line).
+``qos`` fold; `gate_state()` names the class holding the gate.
 
 Fault points (runtime/faults.py, REST-armable, ``match=`` scoped):
 ``qos.starve`` (error="none") makes every yield see a closed gate —
@@ -75,7 +74,7 @@ __all__ = ["enabled", "serving_dispatch", "yield_point", "admission_gate",
            "gate_state", "totals", "stats", "reset", "train_min_share"]
 
 
-# -- knobs (read per call: tests and the bench flip env live) -----------------
+# -- knobs (read per call: tests flip env live) --------------------------------
 
 def enabled() -> bool:
     """``H2O3_QOS=1`` arms the gate; default off (free: one env read)."""
@@ -405,7 +404,7 @@ def admission_gate(label: str = "candidate") -> float:
 # -- observability ------------------------------------------------------------
 
 def gate_state() -> Dict:
-    """Who holds the gate right now — the bench watchdog's hang line:
+    """Who holds the gate right now:
     'serving' while any serving dispatch is in flight, 'training' while
     training yielded recently (it is between yields, i.e. inside its own
     dispatch burst), 'idle' otherwise."""
@@ -429,7 +428,7 @@ def gate_state() -> Dict:
 
 
 def totals() -> Dict:
-    """Process-cumulative QoS counters — the bench-record embed."""
+    """Process-cumulative QoS counters."""
     with _TLOCK:
         t = dict(_TOTALS)
     t["waits_ms"] = round(t["waits_ms"], 3)
@@ -453,9 +452,8 @@ def stats() -> Dict:
 
 
 def reset() -> None:
-    """Zero the cumulative counters and gate/throttle state (tests and
-    per-window bench measurement; registry families are monotone and
-    stay)."""
+    """Zero the cumulative counters and gate/throttle state (tests;
+    registry families are monotone and stay)."""
     global _SERVING_INFLIGHT, _LAST_SERVING_END, _LAST_SERVING_DETAIL
     with _TLOCK:
         _TOTALS.update(yields=0, waits_ms=0.0, serving_dispatches=0,

@@ -49,7 +49,7 @@ Fault points: ``supervisor.ckpt_corrupt`` (truncates the serialized blob
 before the atomic rename — restore must reject it), ``supervisor.fit_abort``
 (raises at a chunk boundary — the in-process kill-and-resume pin), and
 `parallel/mesh`'s ``mesh.rank_kill`` (hard-exits a rank at fence N — the
-``BENCH_CONFIG=pod_chaos`` lane). ``H2O3_CKPT=0`` is the escape hatch:
+pod chaos drill of tests/test_supervisor.py). ``H2O3_CKPT=0`` is the escape hatch:
 checkpointing fully off, bit-identical to pre-supervisor behavior.
 
 State surfaces at ``GET /3/Supervisor`` (rest/server.py) and the

@@ -50,10 +50,6 @@ wall·parallelism), CV fold reuse/rebin counters, and the hardening
 counters (retried / watchdog_cancelled / resumed + the shared retry-policy
 stats) — served at ``GET /3/Training/metrics`` (TrainingMetricsV3) and
 folded into ``/3/Profiler`` via `runtime/profiler.training_stats`.
-
-``H2O3_TRAIN_LEGACY=1`` is the bench comparator: callers bypass the pool
-(sequential seed loop), the dataset-artifact cache disables itself, and CV
-reverts to the per-fold re-bin path.
 """
 
 from __future__ import annotations
@@ -83,12 +79,6 @@ _CV = dict(reuse_folds=0, rebin_folds=0)
 _CANDIDATES: deque = deque(maxlen=int(os.environ.get(
     "H2O3_TRAIN_CANDIDATE_LOG", 64)))
 _LAST_POOL: Dict = {}
-
-
-def legacy() -> bool:
-    """The seed-comparator switch: sequential loops, no artifact cache,
-    per-fold re-binning (bench.py's vs_seed measurement)."""
-    return os.environ.get("H2O3_TRAIN_LEGACY", "") not in ("", "0")
 
 
 _TOTAL_FIELDS = ("pools", "submitted", "completed", "failed", "cancelled",
@@ -334,7 +324,7 @@ class TrainPool:
             else env_float("H2O3_TRAIN_CAND_DEADLINE_S", 0.0))
 
     def _effective_parallelism(self) -> int:
-        if self.parallelism <= 1 or legacy():
+        if self.parallelism <= 1:
             return 1
         from ..parallel import mesh as cloudlib
 

@@ -50,7 +50,7 @@ class ScoringEngine:
 
         # serving always tracks XLA compiles/retraces: the warm-cache
         # "zero new traces" invariant is a pinned counter (runtime/phases
-        # xla_counts), not a bench-only accounting mode
+        # xla_counts), whether or not a train() installed the listener
         phases.install_listener()
         self.config = config or ServingConfig.from_env()
         self.metrics = ServingMetrics()

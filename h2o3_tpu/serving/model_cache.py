@@ -114,7 +114,7 @@ class CompiledScorer:
 
         # the serving.scorer fault point stands in for a device/XLA runtime
         # failure of THIS executable — the quarantine/fallback tests and
-        # the chaos bench arm it (default off: one dict lookup)
+        # the chaos soak arm it (default off: one dict lookup)
         _faults.check("serving.scorer", self.model_key)
         out = self._fn(scored)
         device_s = time.perf_counter() - t0

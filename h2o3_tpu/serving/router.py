@@ -754,7 +754,7 @@ def get_router() -> Router:
 
 
 def peek_router() -> Optional[Router]:
-    """The router if one exists — profiler/bench readers must not
+    """The router if one exists — profiler readers must not
     instantiate a routing layer just to report that there isn't one."""
     return _router
 

@@ -32,10 +32,13 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def run_workers(n: int, body: str, extra_env=None, timeout=300):
+def spawn_workers(n: int, body: str, extra_env=None, rank_env=None,
+                  timeout=300):
     """Run `body` (python source, after the jax.distributed prelude) in n
-    local processes. Returns per-rank CompletedProcess; raises on any
-    nonzero exit with the failing rank's output in the message."""
+    local processes; per-rank (returncode, output). A rank may exit
+    nonzero (a rank kill can be the scenario under test); `rank_env` adds
+    variables to one rank's environment. A rank that outlives `timeout`
+    kills them all and fails the caller."""
     coord = f"127.0.0.1:{free_port()}"
     script = WORKER_PRELUDE.format(repo=REPO) + textwrap.dedent(body)
     env = dict(os.environ)
@@ -53,6 +56,7 @@ def run_workers(n: int, body: str, extra_env=None, timeout=300):
     for rank in range(n):
         e = dict(env)
         e["H2O3_TEST_RANK"] = str(rank)
+        e.update((rank_env or {}).get(rank, {}))
         procs.append(subprocess.Popen(
             [sys.executable, "-c", script], env=e,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
@@ -64,8 +68,15 @@ def run_workers(n: int, body: str, extra_env=None, timeout=300):
             for q in procs:
                 q.kill()
             raise AssertionError(f"worker {rank} timed out")
-        outs.append(out)
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, (
-            f"worker {rank} exited {p.returncode}:\n{out[-4000:]}")
+        outs.append((p.returncode, out))
     return outs
+
+
+def run_workers(n: int, body: str, extra_env=None, timeout=300):
+    """Run `body` (python source, after the jax.distributed prelude) in n
+    local processes. Returns per-rank output; raises on any nonzero exit
+    with the failing rank's output in the message."""
+    outs = spawn_workers(n, body, extra_env, timeout=timeout)
+    for rank, (rc, out) in enumerate(outs):
+        assert rc == 0, f"worker {rank} exited {rc}:\n{out[-4000:]}"
+    return [out for _, out in outs]

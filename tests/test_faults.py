@@ -1015,7 +1015,7 @@ def test_serving_metrics_expose_failover_section(cloud1):
 @pytest.mark.slow
 def test_chaos_smoke_loadgen_under_injected_faults(cloud1):
     """1% injected scorer device-faults under closed-loop load: p99 stays
-    finite and no hard errors escape (the BENCH_CONFIG=chaos acceptance)."""
+    finite and no hard errors escape."""
     import sys
 
     sys.path.insert(0, os.path.join(

@@ -413,18 +413,51 @@ def test_ingest_metrics_schema():
 
 
 # -- throughput smoke (tier-2) -----------------------------------------------
+def _write_ingest_csv(path: str, target_mb: float, seed: int = 0) -> int:
+    """Synthesize a mixed numeric/enum CSV of ~target_mb MB (16 numeric
+    columns with NA holes + 4 enum columns, quoted cells in one — the
+    HIGGS-like numeric-heavy shape) and return the row count. Built in
+    vectorized blocks so generation stays a small fraction of the parse
+    being measured."""
+    rng = np.random.default_rng(seed)
+    levels = np.asarray([f"lvl{i}" for i in range(40)])
+    n_num = 16
+    block = 50_000
+    rows = 0
+    with open(path, "w") as f:
+        f.write(",".join([f"n{i}" for i in range(n_num)]
+                         + ["e0", "e1", "e2", "e3"]) + "\n")
+        while f.tell() < target_mb * 1e6:
+            cols = []
+            for j in range(n_num):
+                c = rng.normal(scale=10.0 ** (j % 6), size=block) \
+                    .round(4).astype(str)
+                c[rng.random(block) < 0.03] = "NA"   # NA-token holes
+                cols.append(c)
+            cols.append(rng.integers(0, 7, block).astype(str))
+            cols.append(rng.choice(levels, block))
+            cols.append(np.char.add("city ",
+                                    rng.integers(0, 200, block).astype(str)))
+            # ~1% quoted cells carrying the separator — enough to exercise
+            # the RFC-4180 fallback without drowning the bulk fast path
+            e2 = np.char.add("tag", rng.integers(0, 9, block).astype(str))
+            qm = rng.random(block) < 0.01
+            e2 = np.where(qm, np.char.add(np.char.add('"q,', e2), '"'), e2)
+            cols.append(e2)
+            out = cols[0]
+            for c in cols[1:]:
+                out = np.char.add(np.char.add(out, ","), c)
+            f.write("\n".join(out.tolist()) + "\n")
+            rows += block
+    return rows
+
+
 @pytest.mark.slow
 def test_ingest_throughput_floor(tmp_path):
     """Parallel chunked parse must not regress vs 1-thread (ISSUE floor:
     parallel ≥ 1.0× single-thread; 10% scheduler-noise margin) and must
     beat the seed per-line tokenizer."""
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import _write_ingest_csv
-
-    p = str(tmp_path / "bench.csv")
+    p = str(tmp_path / "ingest.csv")
     _write_ingest_csv(p, 8)
     parse_csv(p)   # warm-up: page cache + numpy kernels
 

@@ -13,7 +13,7 @@ The full concurrent soak (live REST server + open-loop load + in-process
 grid sweep) lives in the slow lane (`test_qos_concurrent_soak_slow`):
 tier-1 already runs ~700 s of its 870 s budget, and the soak needs
 multi-second serving windows to produce meaningful percentiles — it is
-exercised by ``BENCH_CONFIG=qos`` and nightly ``-m slow`` runs instead.
+exercised by nightly ``-m slow`` runs instead.
 """
 
 import math
@@ -387,11 +387,14 @@ def test_qos_concurrent_soak_slow(tmp_path, monkeypatch):
 
     Slow-lane on purpose: tier-1 already consumes ~700 s of its 870 s
     budget and this needs multi-second load windows for stable
-    percentiles. BENCH_CONFIG=qos runs the same flow with assertions on
-    the p99 ratio; here we assert completion + gate activity only."""
+    percentiles. It asserts completion, gate activity and at most 1 %
+    hard errors, never a p99 ratio."""
+    import os
     import sys
 
-    sys.path.insert(0, "/root/repo/deploy")
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "deploy"))
     try:
         import loadgen
     finally:
@@ -419,4 +422,6 @@ def test_qos_concurrent_soak_slow(tmp_path, monkeypatch):
     assert stats["sweep"].get("done") == 2
     assert stats["completed"] > 0
     assert stats["contended"]["p99_ms"] > 0
+    assert stats["contended"]["errors"] <= 0.01 * max(
+        stats["contended"]["offered"], 1)
     assert qos.totals()["yields"] > 0

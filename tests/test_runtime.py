@@ -143,7 +143,7 @@ def test_dkv_stats_and_timeline_phases(cloud1):
 
 def test_phases_accounting_and_mark_mapping():
     """runtime.phases: byte/second accumulation, mark→bucket mapping, and
-    compile-time subtraction in accounted_h2d."""
+    accounted_h2d's byte booking."""
     import jax.numpy as jnp
 
     from h2o3_tpu.runtime import phases

@@ -9,7 +9,6 @@ which the tier-1 budget cannot absorb."""
 
 import json
 import os
-import sys
 import time
 
 import numpy as np
@@ -18,6 +17,7 @@ import pytest
 from h2o3_tpu.runtime import faults, supervisor, trainpool
 
 from conftest import make_classification
+from tests.multiproc_util import spawn_workers
 
 
 @pytest.fixture(autouse=True)
@@ -403,8 +403,61 @@ def test_t1_budget_parses_and_thresholds(tmp_path, monkeypatch, capsys):
 
 # -- slow lane: the multi-interpreter pod_chaos pin ---------------------------
 
+# one pod rank: a 20-tree GBM fit of the drill's CSV; rank 0 writes the
+# supervisor's record and, when the fit completed, the forest
+_POD_CHAOS_BODY = """
+import json, time
+import numpy as np
+import h2o3_tpu as h2o
+from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+from h2o3_tpu.runtime import supervisor
+h2o.init()
+fr = h2o.import_file({csv!r})
+fr["y"] = fr["y"].asfactor()
+g = H2OGradientBoostingEstimator(ntrees=20, max_depth=3, seed=5,
+                                 score_tree_interval=5)
+t0 = time.time()
+err = None
+try:
+    g.train(x=[f"x{{i}}" for i in range(6)], y="y", training_frame=fr)
+except BaseException as e:
+    err = f"{{type(e).__name__}}: {{e}}"
+snap = supervisor.snapshot()
+info = dict(rank=jax.process_index(), error=err, wall_s=time.time() - t0,
+            aborts=snap["totals"]["aborts"], last_abort=snap["last_abort"],
+            last_resume=snap["last_resume"],
+            resumes=snap["totals"]["resumes"])
+if jax.process_index() == 0:
+    with open({info!r}, "w") as f:
+        json.dump(info, f, default=str)
+    if err is None:
+        m = g.model
+        np.savez({out!r},
+                 feat=np.stack([np.asarray(t.feat) for t in m.forest]),
+                 bins=np.stack([np.asarray(t.bin) for t in m.forest]),
+                 thr=np.stack([np.asarray(t.thr) for t in m.forest]),
+                 val=np.stack([np.asarray(t.value) for t in m.forest]),
+                 ntrees=m.ntrees_built,
+                 sh_ll=np.asarray([ev.get("logloss")
+                                   for ev in m.scoring_history], np.float64),
+                 vi_gain=np.asarray([r[1] for r in m.varimp_table],
+                                    np.float64))
+print("rank", jax.process_index(), "done err=", err)
+"""
+
+
+def _pod_chaos_spawn(nproc, csv, out, info, extra_env=None, rank_env=None,
+                     timeout=600):
+    """An n-rank loopback pod running `_POD_CHAOS_BODY`; per-rank
+    (returncode, output). Rank death (rc 43) is the scenario, so a
+    nonzero exit is the caller's to judge."""
+    return spawn_workers(
+        nproc, _POD_CHAOS_BODY.format(csv=csv, out=out, info=info),
+        extra_env=extra_env, rank_env=rank_env, timeout=timeout)
+
+
 @pytest.mark.slow
-def test_pod_chaos_rank_kill_resume_bitidentical():
+def test_pod_chaos_rank_kill_resume_bitidentical(tmp_path):
     """The full ISSUE 20 acceptance drill — 2-process pod GBM fit, rank 1
     hard-killed mid-fit (mesh.rank_kill), survivor aborts within the
     fence deadline, degraded single-host resume bit-identical to an
@@ -412,13 +465,85 @@ def test_pod_chaos_rank_kill_resume_bitidentical():
     interpreter cold-compiles its own jit cache — minutes per run, far
     past the tier-1 budget; the in-process pin above covers the
     state-machine/checkpoint logic in tier-1."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import bench_pod_chaos
+    import csv as _csv
+    import shutil
 
-    name, detect_s, details = bench_pod_chaos()
-    assert name == "pod_chaos_detect_s"
-    assert details["bitexact"] is True
-    assert details["aborts"] >= 1 or details["abort_error"]
-    assert details["trees_retrained"] <= 20
+    import h2o3_tpu
+
+    deadline_s = 15.0
+    # the rank_kill point is checked at the ONE instrumented fence per
+    # scoring interval (ops/histogram ordered_axis_fold's event-loss tag),
+    # so a 20-tree fit at score_tree_interval=5 sees only ~4 arrivals per
+    # rank: after=2 lands the kill at the 3rd arrival (~tree 15), with the
+    # tree-5/10 checkpoints already committed
+    kill_after = 2
+    tmp = str(tmp_path)
+    ckpt_dir = os.path.join(tmp, "ckpt")
+    csv_p = os.path.join(tmp, "data.csv")
+    rng = np.random.default_rng(7)
+    Xc = rng.normal(size=(5000, 6))
+    yc = (Xc[:, 0] + 0.8 * Xc[:, 1] * Xc[:, 2]
+          + 0.3 * rng.normal(size=5000) > 0).astype(int)
+    with open(csv_p, "w", newline="") as f:
+        w = _csv.writer(f)
+        w.writerow([f"x{i}" for i in range(6)] + ["y"])
+        for i in range(5000):
+            w.writerow([f"{v:.6f}" for v in Xc[i]] + [int(yc[i])])
+
+    shared = {"H2O3_CKPT_DIR": ckpt_dir, "H2O3_CKPT_TREES": "5"}
+    # A: undisturbed 1-process forced-shard comparator (same S as the pod)
+    ref_out = os.path.join(tmp, "ref.npz")
+    res = _pod_chaos_spawn(1, csv_p, ref_out, os.path.join(tmp, "ref.json"),
+                           extra_env={"H2O3_TREE_SHARD": "1",
+                                      "H2O3_CKPT": "0"})
+    assert res[0][0] == 0 and os.path.exists(ref_out), \
+        f"comparator fit failed: {res[0][1][-2000:]}"
+    # B: 2-rank pod; rank 1 dies at its (kill_after+1)-th collective
+    # arrival; rank 0's fences run under the supervisor deadline. The
+    # doomed pod gets its OWN compilation cache — a fixed sub-directory of
+    # the resolved one, emptied first: os._exit mid-write would tear the
+    # shared persistent cache and the resume leg then segfaults
+    # deserializing the torn entry (observed once) — cache poisoning is a
+    # different failure than the one this drill pins
+    doomed_cache = os.path.join(h2o3_tpu.compile_cache_dir(),
+                                "pod_chaos_doomed")
+    shutil.rmtree(doomed_cache, ignore_errors=True)
+    info_p = os.path.join(tmp, "chaos.json")
+    t_kill = time.time()
+    res = _pod_chaos_spawn(
+        2, csv_p, os.path.join(tmp, "pod.npz"), info_p,
+        extra_env=dict(shared, H2O3_FENCE_DEADLINE_S=str(deadline_s),
+                       JAX_COMPILATION_CACHE_DIR=doomed_cache),
+        rank_env={1: {"H2O3_FAULT_MESH_RANK_KILL":
+                      f"error=crash,count=1,after={kill_after}"}},
+        timeout=max(deadline_s * 8, 240))
+    detect_wall = time.time() - t_kill
+    assert res[1][0] == 43, (
+        f"rank 1 should have been hard-killed (rc 43), got {res[1][0]}:"
+        f"\n{res[1][1][-2000:]}")
+    chaos = json.loads(open(info_p).read()) if os.path.exists(info_p) \
+        else {}
+    assert chaos.get("error"), (
+        "rank 0 completed despite a dead peer — the kill never landed:"
+        f"\n{res[0][1][-2000:]}")
+    assert os.path.isdir(ckpt_dir) and os.listdir(ckpt_dir), \
+        "no fit checkpoints were committed before the kill"
+    # C: degraded single-host resume on the SAME shard plan S — restores
+    # the rank-sharded snapshots (rank-ordered concat) and completes
+    res_out = os.path.join(tmp, "resumed.npz")
+    res_info = os.path.join(tmp, "resumed.json")
+    res = _pod_chaos_spawn(1, csv_p, res_out, res_info,
+                           extra_env=dict(shared, H2O3_TREE_SHARD="1"))
+    assert res[0][0] == 0 and os.path.exists(res_out), \
+        f"degraded resume failed: {res[0][1][-2000:]}"
+    rinfo = json.loads(open(res_info).read())
+    restored = int((rinfo.get("last_resume") or {}).get("restored") or 0)
+    assert restored > 0, f"resume did not restore a checkpoint: {rinfo}"
+    ref, got = np.load(ref_out), np.load(res_out)
+    assert int(got["ntrees"]) == int(ref["ntrees"])
+    for k in ("feat", "bins", "thr", "val", "vi_gain", "sh_ll"):
+        np.testing.assert_array_equal(got[k], ref[k], err_msg=k)
+    assert int(got["ntrees"]) - restored <= 20
+    detect_s = (chaos.get("last_abort") or {}).get("latency_s")
+    detect_s = float(detect_s if detect_s is not None else detect_wall)
     assert np.isfinite(detect_s) and detect_s > 0

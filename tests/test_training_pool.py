@@ -1,10 +1,8 @@
 """Parallel multi-model training engine (ISSUE 4): dataset-artifact cache
 hit/miss/eviction, CV fold reuse vs the H2O3_CV_REBIN=1 seed path,
-parallel-grid leaderboard determinism, per-job error isolation, the
-`GET /3/Training/metrics` REST surface, and a slow grid-throughput floor."""
+parallel-grid leaderboard determinism, per-job error isolation, and the
+`GET /3/Training/metrics` REST surface."""
 
-import os
-import threading
 import time
 
 import numpy as np
@@ -251,7 +249,7 @@ def test_cv_reuse_metric_parity_with_rebin(cloud1, monkeypatch):
 
 def test_cv_rebin_is_deterministic_seed_path(cloud1, monkeypatch):
     """parallelism=1 + H2O3_CV_REBIN=1 is the bit-exact seed path: two runs
-    (one with the artifact cache live, one fully legacy) agree exactly."""
+    (one with the artifact cache live, one with it off) agree exactly."""
     fr = _cls_frame(700, 4, seed=7)
     monkeypatch.setenv("H2O3_CV_REBIN", "1")
 
@@ -262,7 +260,7 @@ def test_cv_rebin_is_deterministic_seed_path(cloud1, monkeypatch):
         return g
 
     a = run()
-    monkeypatch.setenv("H2O3_TRAIN_LEGACY", "1")
+    monkeypatch.setenv("H2O3_DATASET_CACHE", "0")
     b = run()
     assert float(a.auc(xval=True)) == float(b.auc(xval=True))
     np.testing.assert_array_equal(a.model._cv_holdout_pred,
@@ -397,44 +395,3 @@ def test_training_metrics_rest_surface(cloud1):
         assert "training" in prof and prof["training"]["active"] is True
     finally:
         srv.stop()
-
-
-# -- throughput floor (slow lane) -------------------------------------------------
-@pytest.mark.slow
-def test_grid_throughput_floor_vs_seed(cloud1):
-    """The pooled path (artifact cache + CV reuse + parallelism) must beat
-    the sequential seed walk on a small GBM grid with CV. Conservative
-    floor for noisy CI hosts; the bench artifact (BENCH_CONFIG=grid) pins
-    the ≥2× acceptance on a quiet 2-core run."""
-    if (os.cpu_count() or 1) < 2:
-        pytest.skip("needs ≥2 cores for overlap")
-    fr = _cls_frame(4000, 8, seed=40)
-
-    def run(par, legacy):
-        prior = os.environ.get("H2O3_TRAIN_LEGACY")
-        if legacy:
-            os.environ["H2O3_TRAIN_LEGACY"] = "1"
-        else:
-            os.environ.pop("H2O3_TRAIN_LEGACY", None)
-        try:
-            dataset_cache.clear()
-            g = H2OGridSearch(
-                H2OGradientBoostingEstimator(ntrees=10, nfolds=3, seed=42),
-                {"max_depth": [3, 4], "learn_rate": [0.1, 0.2]},
-                parallelism=par)
-            t0 = time.perf_counter()
-            g.train(y="y", training_frame=fr)
-            wall = time.perf_counter() - t0
-            assert len(g.models) == 4, g.failed
-            return wall
-        finally:
-            if prior is None:
-                os.environ.pop("H2O3_TRAIN_LEGACY", None)
-            else:
-                os.environ["H2O3_TRAIN_LEGACY"] = prior
-
-    run(min(os.cpu_count() or 1, 4), legacy=False)   # warm compile caches
-    wall_new = run(min(os.cpu_count() or 1, 4), legacy=False)
-    wall_seed = run(1, legacy=True)
-    speedup = wall_seed / wall_new
-    assert speedup > 1.3, f"pooled grid only {speedup:.2f}x vs seed walk"

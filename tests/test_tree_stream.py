@@ -550,6 +550,27 @@ def test_goss_streams_fewer_bytes_and_is_deterministic(cloud1, _ooc_env):
     assert (g1.model._stream_stats["streamed_bytes"]
             < plain.model._stream_stats["streamed_bytes"])
     _assert_bitexact(g1, g2)
+    # oversubscribed, the device-resident blocks never outgrow the stream
+    # budget (floored at the two-block double buffer)
+    for est in (plain, g1):
+        st = est.model._stream_stats
+        per_block = st["streamed_bytes"] // max(st["blocks_uploaded"], 1)
+        assert 0 < st["resident_block_peak"] <= max(int(0.004 * 1e6),
+                                                    2 * per_block)
+
+
+def test_goss_on_disk_restores_fewer_spill_bytes(cloud1, _ooc_env):
+    """A spilled fit with sampling on reads fewer bytes back from disk
+    than the same fit without it: later trees restore only the sampled
+    rows' blocks."""
+    env = dict(_SPILL_ENV, H2O3_STREAM_BUDGET_MB="0.004")
+    params = dict(ntrees=6, max_depth=3, learn_rate=0.2)
+    plain = _fit(env, **params)
+    goss = _fit(env, goss=True, goss_start_tree=2, **params)
+    _assert_spilled_under_budget(plain.model._stream_stats)
+    _assert_spilled_under_budget(goss.model._stream_stats)
+    assert (goss.model._stream_stats["restored_bytes"]
+            < plain.model._stream_stats["restored_bytes"])
 
 
 def test_goss_validation_and_ineligible_fallback(cloud1, _ooc_env):
